@@ -1,0 +1,377 @@
+"""The chip's compiler, asked here without the chip.
+
+The TPU compiler is installed in the sandbox and compiles for a chip
+that is described, not attached (`v5e:2x2`). These tests compile — never
+run — the Pallas kernels and the lowerings only a TPU takes, at TPC-H
+SF1 shapes, so a refusal (a 64-bit op the X64 rewriter lacks, a kernel
+past VMEM, a program past 16 GB) or a compile too slow for a cold
+server start shows here at no chip time. The code under test asks
+`is_tpu()` and sees the CPU, so the gate is steered in the test (the
+cached flag in tidb_tpu.utils.backend), not through a program option.
+
+Rules this file keeps (on-chip-measurement guide, section 2): the
+topology is described inside a module-scoped fixture — never at import,
+never in conftest.py, never autouse — everything compiles in the test's
+own process, the persistent compile cache is off around the compiles,
+and all of it lives in this ONE file (another file could land on another
+xdist worker, whose fixture would then fail to load the library).
+
+Compile seconds for v5e:2x2 as measured in the sandbox are in CHANGES.md
+(PR 23). Tests whose compile passes a quarter of a minute are `slow`.
+"""
+
+import time
+
+import numpy as np
+import pytest
+
+LINEITEM_SF1 = 6_001_215
+ORDERS_SF1 = 1_500_000
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep it off around them
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_gates(monkeypatch):
+    """Steer every is_tpu() gate onto its TPU branch for one test."""
+    import tidb_tpu.utils.backend as backend
+
+    monkeypatch.setattr(backend, "_IS_TPU", True)
+
+
+def _sds(shape, dtype, sharding):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _widen(tree, rows: int, sharding):
+    """Shapes of a pytree of [n, ...] arrays, widened to `rows` rows."""
+    import jax
+
+    return jax.tree.map(
+        lambda x: _sds((rows,) + tuple(x.shape[1:]), x.dtype, sharding), tree
+    )
+
+
+def _batch(cols: dict, rows: int, sharding):
+    """A Batch of shapes: cols = {name: dtype}."""
+    from tidb_tpu.chunk import Batch, DevCol
+
+    return Batch(
+        {
+            n: DevCol(_sds((rows,), dt, sharding), _sds((rows,), np.bool_, sharding))
+            for n, dt in cols.items()
+        },
+        _sds((rows,), np.bool_, sharding),
+    )
+
+
+def _compile(fn, *args):
+    import jax
+
+    t0 = time.perf_counter()
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled, time.perf_counter() - t0
+
+
+def _fits_v5e(compiled):
+    ma = compiled.memory_analysis()
+    total = (
+        ma.temp_size_in_bytes + ma.argument_size_in_bytes + ma.output_size_in_bytes
+    )
+    assert total < 16 << 30, total
+    return ma
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernels, compiled (interpret=False)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("slots", [8, 128])
+def test_slot_sums_kernel_compiles(one_chip, slots):
+    """[8, 6,001,215] f32 lanes; slots=128 is the widest the gate in
+    aggregate._try_pallas_slot_sums admits ([A,128] out block and the
+    [1024,128] one-hot are the VMEM question)."""
+    from tidb_tpu.executor.pallas_kernels import slot_sums_f32
+
+    compiled, _s = _compile(
+        lambda v, c, s: slot_sums_f32(v, c, s, slots),
+        _sds((8, LINEITEM_SF1), np.float32, one_chip),
+        _sds((8, LINEITEM_SF1), np.bool_, one_chip),
+        _sds((LINEITEM_SF1,), np.int32, one_chip),
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits_v5e(compiled)
+
+
+def test_prefix_sum_kernel_compiles(one_chip):
+    """2**23: the dense-domain cap named in pallas_kernels.py."""
+    from tidb_tpu.executor.pallas_kernels import prefix_sum_i32
+
+    compiled, _s = _compile(prefix_sum_i32, _sds((2**23,), np.bool_, one_chip))
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits_v5e(compiled)
+
+
+# ---------------------------------------------------------------------------
+# the flagship fragment: scatter form (CPU's branch) and masked backend
+# ---------------------------------------------------------------------------
+
+
+def _q1_fragment(one_chip):
+    import __graft_entry__ as g
+    from tidb_tpu.chunk import pad_capacity
+
+    fn, (batch,) = g.entry()
+    return fn, _widen(batch, pad_capacity(LINEITEM_SF1), one_chip)
+
+
+def test_q1_fragment_compiles(one_chip):
+    fn, batch = _q1_fragment(one_chip)
+    compiled, _s = _compile(fn, batch)
+    _fits_v5e(compiled)
+
+
+def test_masked_backend_q1_shape_compiles(one_chip, tpu_gates, monkeypatch):
+    """Q1's real plan carries planner widths for its two dictionary
+    keys, so on a TPU the 4-bit dense domain reduces through
+    aggregate._masked_backend (scatter-free), not segment_sum."""
+    import tidb_tpu.executor.aggregate as A
+    from tidb_tpu.chunk import pad_capacity
+
+    used = []
+    real = A._masked_backend
+    monkeypatch.setattr(
+        A, "_masked_backend", lambda *a: used.append(1) or real(*a)
+    )
+    batch = _batch(
+        {"l_returnflag": np.int32, "l_linestatus": np.int32,
+         "l_quantity": np.int64, "l_extendedprice": np.int64},
+        pad_capacity(LINEITEM_SF1), one_chip,
+    )
+
+    def q1_agg(b):
+        return A.group_aggregate(
+            b,
+            [lambda x: x.cols["l_returnflag"], lambda x: x.cols["l_linestatus"]],
+            [
+                A.AggDesc("sum", lambda x: x.cols["l_quantity"], "sum_qty"),
+                A.AggDesc("sum", lambda x: x.cols["l_extendedprice"], "sum_base"),
+                A.AggDesc("count", None, "count_order"),
+            ],
+            16, key_names=["l_returnflag", "l_linestatus"],
+            key_widths=[(2, 0), (2, 0)],  # 3 and 2 dictionary codes
+        )
+
+    compiled, _s = _compile(q1_agg, batch)
+    assert used, "the masked backend did not engage"
+    _fits_v5e(compiled)
+
+
+# ---------------------------------------------------------------------------
+# sorted aggregation (Q18's SF1 shape: 6M rows -> 1.5M groups)
+# ---------------------------------------------------------------------------
+
+
+def _q18_aggregation(one_chip, key_widths):
+    from tidb_tpu.chunk import pad_capacity
+    from tidb_tpu.executor import AggDesc, group_aggregate
+
+    batch = _batch(
+        {"l_orderkey": np.int64, "l_quantity": np.int64},
+        pad_capacity(LINEITEM_SF1), one_chip,
+    )
+
+    def agg(b):
+        out, ngroups = group_aggregate(
+            b, [lambda x: x.cols["l_orderkey"]],
+            [AggDesc("sum", lambda x: x.cols["l_quantity"], "sum_qty")],
+            pad_capacity(ORDERS_SF1), key_names=["l_orderkey"],
+            key_widths=key_widths,
+        )
+        return out, ngroups
+
+    return agg, batch
+
+
+def _sort_signatures(fn, *args):
+    """[(operand dtypes, num_keys, is_stable)] of every sort traced."""
+    import jax
+
+    found = []
+
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "sort":
+                found.append((
+                    tuple(str(v.aval.dtype) for v in e.invars),
+                    e.params["num_keys"], e.params["is_stable"],
+                ))
+            for p in e.params.values():
+                for sub in p if isinstance(p, (list, tuple)) else [p]:
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def test_sorted_aggregation_q18_shape_compiles(one_chip, tpu_gates):
+    """The lowering that took 434 s in the seed (five int8 keys + row id,
+    stable). With planner widths the whole key — row validity, key
+    validity, 24 key bits, 23 row-id bits — is two uint32 limbs."""
+    # l_orderkey in [1, 6,000,000]: 24 bits hold value + bias + 1
+    agg, batch = _q18_aggregation(one_chip, [(24, 0)])
+    sorts = _sort_signatures(agg, batch)
+    assert sorts[0] == (("uint32", "uint32"), 2, False), sorts
+    assert all(not stable for _d, _k, stable in sorts), sorts
+    compiled, secs = _compile(agg, batch)
+    _fits_v5e(compiled)
+    assert secs < 150, f"sorted aggregation took {secs:.0f}s to compile"
+
+
+@pytest.mark.slow
+def test_sorted_aggregation_without_widths_compiles(one_chip, tpu_gates):
+    """No planner bounds: the int64 key keeps all 64 bits (3 limbs)."""
+    agg, batch = _q18_aggregation(one_chip, None)
+    assert _sort_signatures(agg, batch)[0][1] == 3
+    compiled, _s = _compile(agg, batch)
+    _fits_v5e(compiled)
+
+
+def test_segmented_scan_compiles(one_chip):
+    """min/max on the sorted path: the rolled doubling loop (the
+    unrolled lax.associative_scan had not compiled after 15 minutes)."""
+    import jax.numpy as jnp
+
+    from tidb_tpu.chunk import pad_capacity
+    from tidb_tpu.executor.sortops import _seg_scan
+
+    n = pad_capacity(LINEITEM_SF1)
+    compiled, secs = _compile(
+        lambda v, b: _seg_scan(v, b, jnp.maximum),
+        _sds((n,), np.int64, one_chip), _sds((n,), np.bool_, one_chip),
+    )
+    assert "while" in compiled.as_text()
+    assert secs < 60, f"segmented scan took {secs:.0f}s to compile"
+
+
+# ---------------------------------------------------------------------------
+# merge-probe / sorted-lookup joins (Q5's SF1 shapes) — slow: each
+# program holds three 3-limb sorts
+# ---------------------------------------------------------------------------
+
+
+def _q5_join_sides(one_chip):
+    from tidb_tpu.chunk import pad_capacity
+
+    orders = _batch(
+        {"o_orderkey": np.int64, "o_custkey": np.int64},
+        pad_capacity(ORDERS_SF1), one_chip,
+    )
+    lineitem = _batch(
+        {"l_orderkey": np.int64, "l_extendedprice": np.int64},
+        pad_capacity(LINEITEM_SF1), one_chip,
+    )
+    return orders, lineitem
+
+
+@pytest.mark.slow
+def test_merge_probe_join_q5_shape_compiles(one_chip, tpu_gates):
+    from tidb_tpu.chunk import pad_capacity
+    from tidb_tpu.executor.join import _use_merge_probe, equi_join
+
+    assert _use_merge_probe(pad_capacity(LINEITEM_SF1))
+    orders, lineitem = _q5_join_sides(one_chip)
+    compiled, _s = _compile(
+        lambda b, p: equi_join(
+            b, p, lambda x: x.cols["o_orderkey"], lambda x: x.cols["l_orderkey"],
+            pad_capacity(LINEITEM_SF1), "inner",
+        ),
+        orders, lineitem,
+    )
+    _fits_v5e(compiled)
+
+
+@pytest.mark.slow
+def test_sorted_unique_lookup_q5_shape_compiles(one_chip, tpu_gates):
+    from tidb_tpu.executor.join import lookup_build_rows
+
+    orders, lineitem = _q5_join_sides(one_chip)
+    compiled, _s = _compile(
+        lambda b, p: lookup_build_rows(
+            b, p, lambda x: x.cols["o_orderkey"], lambda x: x.cols["l_orderkey"],
+            build_bounds=(1, 6_000_000),  # past 2**16 rows a TPU sorts anyway
+        ),
+        orders, lineitem,
+    )
+    _fits_v5e(compiled)
+
+
+# ---------------------------------------------------------------------------
+# four devices: the mesh repartition join carries an all-to-all
+# ---------------------------------------------------------------------------
+
+
+def test_mesh_repartition_join_has_all_to_all(topo, tpu_gates):
+    """The hash-partition shuffle of the north star, compiled for the
+    2x2 v5e mesh. Small tiles: what is asserted is the collective, and
+    the sorts of a 4096-row tile compile in seconds."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tidb_tpu.parallel import partitioned_join
+    from tidb_tpu.parallel.mesh import AXIS, pmax, shard_map
+
+    n = len(topo.devices)
+    assert n == 4
+    mesh = Mesh(np.array(topo.devices), (AXIS,))
+    rows = NamedSharding(mesh, P(AXIS))
+    orders = _batch({"o_orderkey": np.int64, "o_custkey": np.int64}, 1024 * n, rows)
+    lineitem = _batch({"l_orderkey": np.int64, "l_qty": np.int64}, 4096 * n, rows)
+
+    def local(o, li):
+        out, total, dropped = partitioned_join(
+            li, o, lambda b: b.cols["l_orderkey"], lambda b: b.cols["o_orderkey"],
+            n, 2048, 4096, "inner",
+        )
+        # every mesh program ends by taking the max of its int64
+        # cardinality scalars over the axis: the TPU compiler lowers only
+        # SUM all-reduces of int64, which mesh.pmax works around
+        return out, pmax(total, AXIS), dropped
+
+    step = shard_map(
+        local, mesh=mesh, in_specs=(P(AXIS), P(AXIS)),
+        out_specs=(P(AXIS), P(), P()),
+    )
+    compiled, _s = _compile(step, orders, lineitem)
+    assert "all-to-all" in compiled.as_text()
+    assert len(jax.tree.leaves(compiled.input_shardings)[0].device_set) == n

@@ -1,0 +1,380 @@
+"""Numerics of the lowerings only a TPU takes, run here on the CPU.
+
+The gates ask `utils.backend.is_tpu()`; each test steers the cached flag
+and compares the TPU formulation with the default one (or with numpy)
+on the same batch, at the operator level — no planner, so no compiled
+plan cached under one gate setting can answer for the other. What the
+chip's compiler makes of the same code is tests/test_tpu_compile.py's
+business; what the chip answers is chip_smoke.py's.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from tidb_tpu.chunk import Batch, HostBlock, block_to_batch, column_from_values
+from tidb_tpu.dtypes import FLOAT64, INT64
+
+
+@pytest.fixture
+def tpu_gates(monkeypatch):
+    import tidb_tpu.utils.backend as backend
+
+    monkeypatch.setattr(backend, "_IS_TPU", True)
+
+
+def _mk(cols: dict, capacity: int) -> Batch:
+    return block_to_batch(
+        HostBlock.from_columns(
+            {k: column_from_values(v, t) for k, (v, t) in cols.items()}
+        ),
+        capacity,
+    )
+
+
+def _col(name):
+    return lambda b: b.cols[name]
+
+
+def _rows(batch: Batch, names) -> list:
+    """Valid rows as sorted tuples (None for NULL), floats rounded."""
+    rv = np.asarray(batch.row_valid)
+    out = []
+    for i in np.nonzero(rv)[0]:
+        row = []
+        for n in names:
+            c = batch.cols[n]
+            v = np.asarray(c.data)[i]
+            ok = bool(np.asarray(c.valid)[i])
+            row.append(None if not ok else "nan" if v != v else round(float(v), 6))
+        out.append(tuple(row))
+    return sorted(out, key=lambda r: tuple((x is None, str(x)) for x in r))
+
+
+# ---------------------------------------------------------------------------
+# key packing
+# ---------------------------------------------------------------------------
+
+
+def test_pack_lex_roundtrip_order_and_limbs():
+    from tidb_tpu.executor.sortops import bits_for, pack_lex, sort_lex, unpack_lex
+
+    rng = np.random.default_rng(1)
+    n = 5000
+    comps = [
+        (jnp.asarray(rng.integers(0, 2, n)), 1),
+        (jnp.asarray(rng.integers(0, 2**40, n)), 40),      # straddles limbs
+        (jnp.asarray(rng.integers(0, 3, n).astype(np.float64)), None),  # as is
+        (jnp.asarray(rng.integers(0, 2, n)), 1),
+        (jnp.asarray(rng.integers(0, 2**63, n, dtype=np.uint64)), 64),
+        (jnp.arange(n, dtype=jnp.int32), bits_for(n)),
+    ]
+    limbs, where = pack_lex(comps)
+    # 41 bits -> 2 limbs, the float itself, 1+64+13 bits -> 3 limbs
+    assert [str(x.dtype) for x in limbs] == ["uint32"] * 2 + ["float64"] + ["uint32"] * 3
+    for i, (a, _bits) in enumerate(comps):
+        assert (np.asarray(unpack_lex(limbs, where, i)) == np.asarray(a)).all(), i
+    sorted_ops, where = sort_lex(comps)
+    perm = np.asarray(unpack_lex(sorted_ops, where, len(comps) - 1)).astype(np.int64)
+    want = np.lexsort(tuple(np.asarray(a) for a, _b in comps[::-1]))
+    assert (perm == want).all()
+
+
+@pytest.mark.parametrize("dtype", [np.int8, np.int32, np.int64, np.uint16, np.bool_])
+def test_int_sort_bits_keep_order_and_invert(dtype):
+    from tidb_tpu.executor.sortops import int_from_sort_bits, int_sort_bits
+
+    rng = np.random.default_rng(2)
+    if dtype == np.bool_:
+        d = rng.random(500) < 0.5
+    else:
+        info = np.iinfo(dtype)
+        d = rng.integers(info.min, info.max, 500, dtype=dtype, endpoint=True)
+    u, bits = int_sort_bits(jnp.asarray(d))
+    assert bits == (1 if dtype == np.bool_ else np.dtype(dtype).itemsize * 8)
+    un = np.asarray(u).astype(np.uint64)
+    assert (np.argsort(un, kind="stable") == np.argsort(d, kind="stable")).all()
+    back = int_from_sort_bits(jnp.asarray(un), dtype)
+    assert (np.asarray(back) == d).all()
+
+
+@pytest.mark.parametrize("n", [1, 2, 7, 1000, 4096, 100001])
+def test_seg_scan_matches_associative_scan(n):
+    from tidb_tpu.executor.sortops import _seg_scan
+
+    rng = np.random.default_rng(n)
+    v = jnp.asarray(rng.integers(-1000, 1000, n))
+    b = jnp.asarray(rng.random(n) < 0.1)
+    for op in (jnp.maximum, jnp.minimum):
+        def combine(x, y, op=op):
+            return jnp.where(y[1], y[0], op(x[0], y[0])), x[1] | y[1]
+
+        want, _ = jax.lax.associative_scan(combine, (v, b))
+        assert (np.asarray(_seg_scan(v, b, op)) == np.asarray(want)).all()
+
+
+@pytest.mark.parametrize("side", ["left", "right"])
+def test_merge_searchsorted_matches_jnp(side):
+    from tidb_tpu.executor.sortops import merge_searchsorted
+
+    rng = np.random.default_rng(3)
+    keys = jnp.asarray(np.sort(rng.integers(-50, 50, 700)))
+    q = jnp.asarray(rng.integers(-60, 60, 900))
+    got = merge_searchsorted(keys, q, side)
+    assert (np.asarray(got) == np.asarray(jnp.searchsorted(keys, q, side=side))).all()
+
+
+# ---------------------------------------------------------------------------
+# sorted aggregation vs the hash / dense paths
+# ---------------------------------------------------------------------------
+
+
+def _agg_batch():
+    rng = np.random.default_rng(4)
+    n = 3000
+    k1 = [None if rng.random() < 0.05 else int(x) for x in rng.integers(-40, 40, n)]
+    k2 = [None if rng.random() < 0.05 else int(x) for x in rng.integers(0, 3, n)]
+    kf = [
+        None if r < 0.05 else (float("nan") if r < 0.08 else float(x) * 0.5)
+        for r, x in zip(rng.random(n), rng.integers(-4, 4, n))
+    ]
+    v = [None if rng.random() < 0.1 else int(x) for x in rng.integers(-10**6, 10**6, n)]
+    f = [float(x) for x in rng.normal(size=n)]
+    batch = _mk(
+        {"k1": (k1, INT64), "k2": (k2, INT64), "kf": (kf, FLOAT64),
+         "v": (v, INT64), "f": (f, FLOAT64)},
+        4096,
+    )
+    # some invalid rows in the middle of the tile
+    rv = np.asarray(batch.row_valid).copy()
+    rv[::17] = False
+    return Batch(batch.cols, jnp.asarray(rv))
+
+
+@pytest.mark.parametrize(
+    "keys,widths",
+    [
+        (["k1"], None),
+        (["k1"], [(8, 40)]),            # planner width: value + 40 + 1 < 2**8
+        (["k1", "k2"], [(8, 40), (3, 0)]),
+        (["k1", "k2"], [None, (3, 0)]),
+        (["kf"], None),                 # float key: NaN group, -0.0 == 0.0
+        (["k2", "kf", "k1"], None),
+    ],
+)
+def test_sorted_aggregation_matches_default_path(keys, widths, monkeypatch):
+    import tidb_tpu.utils.backend as backend
+    from tidb_tpu.executor import AggDesc, group_aggregate
+
+    batch = _agg_batch()
+    aggs = [
+        AggDesc("sum", _col("v"), "s"),
+        AggDesc("count", None, "c"),
+        AggDesc("count", _col("v"), "cv"),
+        AggDesc("min", _col("v"), "mn"),
+        AggDesc("max", _col("f"), "mx"),
+        AggDesc("avg", _col("v"), "av"),
+    ]
+
+    def run():
+        out, ng = jax.jit(
+            lambda b: group_aggregate(
+                b, [_col(k) for k in keys], aggs, 4096, key_names=keys,
+                key_widths=widths,
+            )
+        )(batch)
+        return _rows(out, keys + [a.out_name for a in aggs]), int(ng)
+
+    want, want_n = run()
+    monkeypatch.setattr(backend, "_IS_TPU", True)
+    got, got_n = run()
+    assert got_n == want_n and got == want
+
+
+def test_sorted_aggregation_reports_stale_widths(tpu_gates):
+    """A valid key outside its planner-baked width must surface as
+    WIDTH_STALE (the host recompiles), never as a wrong group."""
+    from tidb_tpu.executor import AggDesc, group_aggregate
+    from tidb_tpu.executor.aggregate import WIDTH_STALE
+
+    batch = _mk({"k": ([1, 2, 300, 2], INT64), "v": ([1, 1, 1, 1], INT64)}, 1024)
+    _out, ng = jax.jit(
+        lambda b: group_aggregate(
+            b, [_col("k")], [AggDesc("sum", _col("v"), "s")], 1024,
+            key_names=["k"], key_widths=[(8, 0)],  # holds 0..254
+        )
+    )(batch)
+    assert int(ng) >= WIDTH_STALE
+
+
+def test_small_dense_domain_takes_masked_backend(tpu_gates, monkeypatch):
+    import tidb_tpu.executor.aggregate as A
+
+    used = []
+    real = A._masked_backend
+    monkeypatch.setattr(A, "_masked_backend", lambda *a: used.append(1) or real(*a))
+    batch = _agg_batch()
+    aggs = [A.AggDesc("sum", _col("v"), "s"), A.AggDesc("count", None, "c")]
+    out, ng = jax.jit(
+        lambda b: A.group_aggregate(
+            b, [_col("k2")], aggs, 16, key_names=["k2"], key_widths=[(3, 0)]
+        )
+    )(batch)
+    assert used and int(ng) == 4  # 0, 1, 2 and NULL
+    k2 = np.asarray(batch.cols["k2"].data)
+    ok = np.asarray(batch.row_valid) & np.asarray(batch.cols["k2"].valid)
+    vv = np.asarray(batch.cols["v"].valid)
+    v = np.asarray(batch.cols["v"].data)
+    for key, s, c in _rows(out, ["k2", "s", "c"]):
+        m = (ok & (k2 == key)) if key is not None else (
+            np.asarray(batch.row_valid) & ~np.asarray(batch.cols["k2"].valid)
+        )
+        assert c == m.sum() and s == v[m & vv].sum()
+
+
+# ---------------------------------------------------------------------------
+# sorted join build + merge probe vs the default probe
+# ---------------------------------------------------------------------------
+
+
+def _join_sides():
+    rng = np.random.default_rng(5)
+    nb, npr = 600, 5000
+    bk = [None if rng.random() < 0.05 else int(x) for x in rng.integers(0, 400, nb)]
+    pk = [None if rng.random() < 0.05 else int(x) for x in rng.integers(-20, 450, npr)]
+    build = _mk({"bk": (bk, INT64), "bv": (list(range(nb)), INT64)}, 1024)
+    probe = _mk({"pk": (pk, INT64), "pv": (list(range(npr)), INT64)}, 8192)
+    return build, probe
+
+
+@pytest.mark.parametrize("join_type", ["inner", "left", "semi", "anti"])
+def test_merge_probe_join_matches_default(join_type, monkeypatch):
+    import tidb_tpu.utils.backend as backend
+    from tidb_tpu.executor.join import _use_merge_probe, equi_join
+
+    build, probe = _join_sides()
+    names = ["pk", "pv"] + (["bk", "bv"] if join_type in ("inner", "left") else [])
+
+    def run():
+        out, total = jax.jit(
+            lambda b, p: equi_join(b, p, _col("bk"), _col("pk"), 16384, join_type)
+        )(build, probe)
+        return _rows(out, names), int(total)
+
+    want = run()
+    monkeypatch.setattr(backend, "_IS_TPU", True)
+    assert _use_merge_probe(probe.capacity)
+    assert run() == want
+
+
+def test_sorted_unique_lookup_matches_dense(monkeypatch):
+    import tidb_tpu.executor.join as J
+    import tidb_tpu.utils.backend as backend
+
+    rng = np.random.default_rng(6)
+    bk = rng.permutation(3000)[:2000].tolist()  # unique build keys
+    build = _mk({"bk": (bk, INT64)}, 2048)
+    pk = [None if rng.random() < 0.05 else int(x) for x in rng.integers(-5, 3100, 5000)]
+    probe = _mk({"pk": (pk, INT64)}, 8192)
+
+    def run():
+        brow, matched, stale = jax.jit(
+            lambda b, p: J.lookup_build_rows(
+                b, p, _col("bk"), _col("pk"), build_bounds=(0, 2999)
+            )
+        )(build, probe)
+        m = np.asarray(matched)
+        return np.where(m, np.asarray(brow), -1), bool(stale)
+
+    want, want_stale = run()  # dense direct index on the CPU
+    monkeypatch.setattr(backend, "_IS_TPU", True)
+    monkeypatch.setattr(J, "_dense_span", lambda *a: None)  # as past 2**16 rows
+    got, got_stale = run()
+    assert not want_stale and not got_stale and (got == want).all()
+
+
+# ---------------------------------------------------------------------------
+# ORDER BY through the packed sort
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("descs", [(False, False), (True, False), (False, True), (True, True)])
+def test_sort_permutation_matches_numpy(descs):
+    from tidb_tpu.executor.sort import sort_permutation
+
+    rng = np.random.default_rng(7)
+    n = 2000
+    a = [None if rng.random() < 0.1 else int(x) for x in rng.integers(-5, 5, n)]
+    f = [None if rng.random() < 0.1 else float(x) for x in rng.integers(-3, 3, n) * 0.25]
+    batch = _mk({"a": (a, INT64), "f": (f, FLOAT64)}, 2048)
+    perm = np.asarray(
+        jax.jit(lambda b: sort_permutation(b, [_col("a"), _col("f")], list(descs)))(batch)
+    )[:n]
+
+    def key(i):
+        out = []
+        for v, desc in ((a[i], descs[0]), (f[i], descs[1])):
+            # MySQL: NULLs first ascending, last descending
+            out.append((v is None) if desc else (v is not None))
+            out.append(0 if v is None else (-v if desc else v))
+        return tuple(out) + (i,)
+
+    assert perm.tolist() == sorted(range(n), key=key)
+
+
+# ---------------------------------------------------------------------------
+# decimal column vs float literal: exact, not float-space
+# ---------------------------------------------------------------------------
+
+
+def test_decimal_vs_float_literal_compares_scaled_integers():
+    """`l_discount >= 0.05` in float space (col / 100.0 >= 0.05) leans on
+    float64 division being exact to the last bit; the TPU's emulated
+    float64 is not, and the v5e dropped every 0.05 row of Q6 (PR 23).
+    The predicate must compile to integer compares only."""
+    from tidb_tpu.dtypes import DECIMAL
+    from tidb_tpu.expression import ColumnRef, Func, Literal, bind_expr, compile_expr
+
+    types = {"d": DECIMAL(2)}
+    batch = _mk({"d": ([0.05, 0.06, 0.07, 0.04, None], DECIMAL(2))}, 1024)
+    pred = bind_expr(
+        Func(op="and", args=(
+            Func(op="ge", args=(ColumnRef(name="d"), Literal(value=0.05))),
+            Func(op="le", args=(ColumnRef(name="d"), Literal(value=0.07))),
+        )),
+        types,
+    )
+    fn = compile_expr(pred, {})
+    out = fn(batch)
+    keep = np.asarray(out.data & out.valid & batch.row_valid)
+    assert keep[:5].tolist() == [True, True, True, False, False]
+    prims = {e.primitive.name for e in jax.make_jaxpr(lambda b: fn(b).data)(batch).eqns}
+    assert "div" not in prims, prims
+
+
+# ---------------------------------------------------------------------------
+# mesh: a 64-bit max as two 32-bit all-reduces
+# ---------------------------------------------------------------------------
+
+
+def test_pmax_int64_matches_max():
+    """The TPU compiler lowers only SUM all-reduces of int64, so
+    mesh.pmax splits the word; the engine's overflow sentinel
+    (WIDTH_STALE = 2^60) must survive it."""
+    from jax.sharding import PartitionSpec as P
+
+    from tidb_tpu.parallel.mesh import make_mesh, pmax, shard_map
+
+    mesh = make_mesh(8)
+    f = jax.jit(
+        shard_map(lambda x: pmax(x[0], "d"), mesh=mesh, in_specs=P("d"), out_specs=P())
+    )
+    for vals in (
+        [5, -3, 1 << 60, (1 << 60) + 7, -(1 << 40), 2**31, 2**32 - 1, 0],
+        [-(i + 1) * (1 << 33) - 5 for i in range(8)],
+        [7] * 8,
+    ):
+        a = np.asarray(vals, dtype=np.int64)
+        assert int(f(jnp.asarray(a))) == a.max()

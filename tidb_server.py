@@ -18,6 +18,38 @@ import signal
 import sys
 
 
+def bootstrap(cfg, tpch_sf=None, seed: int = 0, status_port=None):
+    """Build the catalog and the MySQL server the way the binary does:
+    compile cache, snapshot load, config variables, optional TPC-H
+    bootstrap, watchdog. Returns (catalog, server); the caller runs
+    serve_forever() (main) or start_background() (chip_smoke.py)."""
+    from tidb_tpu.server import Server
+    from tidb_tpu.storage import Catalog
+    from tidb_tpu.utils.backend import enable_compile_cache
+
+    enable_compile_cache()
+    catalog = Catalog()
+    if cfg.path and os.path.exists(os.path.join(cfg.path, "manifest.json")):
+        from tidb_tpu.storage.persist import load_catalog
+
+        print(f"loading catalog from {cfg.path} ...", flush=True)
+        load_catalog(cfg.path, catalog)
+    cfg.apply_variables(catalog)
+    if tpch_sf:
+        from tidb_tpu.bench import load_tpch
+
+        print(f"generating TPC-H sf={tpch_sf} ...", flush=True)
+        load_tpch(catalog, sf=tpch_sf, seed=seed)
+
+    sp = status_port if status_port is not None else cfg.status_port
+    srv = Server(catalog, host=cfg.host, port=cfg.port, status_port=sp)
+    srv.stats_handle.interval_s = cfg.auto_analyze_interval_s
+    from tidb_tpu.utils.watchdog import ensure_watchdog
+
+    ensure_watchdog(catalog)  # memory alarm / expensive-query / mem-limit
+    return catalog, srv
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description="TPU-native MySQL-compatible SQL engine")
     ap.add_argument("--config", default=None, metavar="FILE",
@@ -34,34 +66,13 @@ def main() -> int:
                     help="bootstrap with TPC-H data at scale factor SF")
     args = ap.parse_args()
 
-    from tidb_tpu.server import Server
-    from tidb_tpu.storage import Catalog
     from tidb_tpu.utils.config import Config
 
     cfg = Config.from_toml(args.config) if args.config else Config()
     cfg = cfg.override(
         host=args.host, port=args.port, path=args.path, store=args.store
     )
-
-    catalog = Catalog()
-    if cfg.path and os.path.exists(os.path.join(cfg.path, "manifest.json")):
-        from tidb_tpu.storage.persist import load_catalog
-
-        print(f"loading catalog from {cfg.path} ...", flush=True)
-        load_catalog(cfg.path, catalog)
-    cfg.apply_variables(catalog)
-    if args.tpch:
-        from tidb_tpu.bench import load_tpch
-
-        print(f"generating TPC-H sf={args.tpch} ...", flush=True)
-        load_tpch(catalog, sf=args.tpch)
-
-    sp = args.status_port if args.status_port is not None else cfg.status_port
-    srv = Server(catalog, host=cfg.host, port=cfg.port, status_port=sp)
-    srv.stats_handle.interval_s = cfg.auto_analyze_interval_s
-    from tidb_tpu.utils.watchdog import ensure_watchdog
-
-    ensure_watchdog(catalog)  # memory alarm / expensive-query / mem-limit
+    catalog, srv = bootstrap(cfg, tpch_sf=args.tpch, status_port=args.status_port)
     print(
         f"tidb_tpu listening on {cfg.host}:{srv.port} (store={cfg.store})",
         flush=True,

@@ -299,20 +299,16 @@ def _packed_group_assign(
 def _prefix_sum(mask):
     """int32 inclusive prefix sum of a bool mask; routes through the
     Pallas streaming-scan kernel when opted in (TIDB_TPU_PALLAS=1 on
-    TPU, or interpret mode under TIDB_TPU_PALLAS_INTERPRET=1)."""
-    import os
+    TPU, or interpret mode under TIDB_TPU_PALLAS_INTERPRET=1). An
+    opted-in kernel that fails raises: the jnp form never answers in
+    its place."""
+    from tidb_tpu.executor.pallas_kernels import (
+        pallas_interpret, prefix_sum_i32,
+    )
 
-    try:
-        from tidb_tpu.executor.pallas_kernels import (
-            pallas_enabled, prefix_sum_i32,
-        )
-
-        if pallas_enabled():
-            interp = os.environ.get("TIDB_TPU_PALLAS_INTERPRET") == "1"
-            if interp or _is_tpu():
-                return prefix_sum_i32(mask, interpret=interp)
-    except Exception:
-        pass
+    interp = pallas_interpret()
+    if interp is not None:
+        return prefix_sum_i32(mask, interpret=interp)
     return jnp.cumsum(mask.astype(jnp.int32))
 
 
@@ -600,7 +596,8 @@ def group_aggregate(
 
         slots = _next_pow2(max(group_capacity, 16))
         out, ngroups = sort_group_aggregate(
-            batch, keys, aggs, arg_cols, slots, key_names, reps=reps
+            batch, keys, aggs, arg_cols, slots, key_names, reps=reps,
+            key_widths=key_widths,
         )
         return _mask_post(out, fold_distinct_overflow(ngroups))
 
@@ -763,25 +760,6 @@ def _pick_backend(seg, slots):
     return None
 
 
-def _sort_components(k: DevCol) -> list:
-    """Lexicographic sort components of one key column:
-    [~valid (int8), canonical data, (nan flag int8 for floats)].
-    Equal SQL values produce equal component tuples (NULL data zeroed,
-    -0.0 folded to +0.0, NaN zeroed and carried as a flag), so a
-    lexicographic sort puts every group's rows adjacent — the sort-based
-    analog of _key_components, with no hash at all."""
-    d = k.data
-    if jnp.issubdtype(d.dtype, jnp.floating):
-        dd = jnp.where(d == 0, jnp.zeros_like(d), d)
-        nanf = jnp.isnan(dd) & k.valid
-        dd = jnp.where(nanf | ~k.valid, jnp.zeros_like(dd), dd)
-        return [(~k.valid).astype(jnp.int8), dd, nanf.astype(jnp.int8)]
-    vbd = jnp.where(k.valid, d, jnp.zeros_like(d))
-    if vbd.dtype == jnp.bool_:
-        vbd = vbd.astype(jnp.int8)
-    return [(~k.valid).astype(jnp.int8), vbd]
-
-
 class _SortedReducer:
     """Reduction backend over a group-sorted permutation (sortops): sums
     and counts are cumulative-sum differences at segment ends; min/max are
@@ -878,25 +856,16 @@ def _try_pallas_slot_sums(aggs, arg_cols, seg, slots, srow_valid, reps):
     non-wide SUM/COUNT/AVG aggregates: stacks their (value, contrib)
     pairs and calls the Pallas kernel once. Returns {lane index ->
     (sum f32 [slots], count i64-ish)} keyed by agg index, or None when
-    disabled/unavailable (the jnp path runs as before). float32
-    accumulation: experimental, see pallas_kernels.py numerics note."""
-    import os
+    not opted in (the jnp path runs as before); an opted-in kernel that
+    fails to import, lower or run raises. float32 accumulation:
+    experimental, see pallas_kernels.py numerics note."""
+    from tidb_tpu.executor.pallas_kernels import (
+        pallas_interpret,
+        slot_sums_f32,
+    )
 
-    try:
-        from tidb_tpu.executor.pallas_kernels import (
-            pallas_enabled,
-            slot_sums_f32,
-        )
-
-        if not pallas_enabled() or slots > 128:
-            return None
-        # the kernel only lowers on TPU; interpret mode is the CPU/test
-        # escape hatch. A lowering failure inside the steady jitted plan
-        # would be uncatchable, so gate by backend up front.
-        interp = os.environ.get("TIDB_TPU_PALLAS_INTERPRET") == "1"
-        if not interp and not _is_tpu():
-            return None
-    except Exception:
+    interp = pallas_interpret()
+    if interp is None or slots > 128:
         return None
     lanes = []  # (agg index, kind: 'cnt'|'sum', values, contrib)
     for i, (a, col) in enumerate(zip(aggs, arg_cols)):
@@ -914,14 +883,11 @@ def _try_pallas_slot_sums(aggs, arg_cols, seg, slots, srow_valid, reps):
             lanes.append((i, "cnt", jnp.ones_like(seg, jnp.float32), contrib))
     if not lanes:
         return None
-    try:
-        vals = jnp.stack([v for _i, _k, v, _c in lanes])
-        contribs = jnp.stack([c for _i, _k, _v, c in lanes])
-        sums = slot_sums_f32(
-            vals, contribs, seg.astype(jnp.int32), slots, interpret=interp
-        )
-    except Exception:
-        return None  # pallas unavailable on this backend: jnp path
+    vals = jnp.stack([v for _i, _k, v, _c in lanes])
+    contribs = jnp.stack([c for _i, _k, _v, c in lanes])
+    sums = slot_sums_f32(
+        vals, contribs, seg.astype(jnp.int32), slots, interpret=interp
+    )
     out = {}
     for lane, (i, kind, _v, _c) in enumerate(lanes):
         out.setdefault(i, {})[kind] = sums[lane]

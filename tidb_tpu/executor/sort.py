@@ -14,7 +14,7 @@ last DESC) by key transforms, so one ascending lax.sort handles all.
 
 from __future__ import annotations
 
-from typing import Callable, List, Sequence
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -24,34 +24,38 @@ from tidb_tpu.chunk import Batch, DevCol
 ExprFn = Callable[[Batch], DevCol]
 
 
-def _directional_operands(batch: Batch, key_fns, descs) -> List[jax.Array]:
-    """Build ascending-sort operands implementing direction + null order.
-    Invalid rows always sink to the end."""
-    ops: List[jax.Array] = [~batch.row_valid]
+def _directional_comps(batch: Batch, key_fns, descs) -> list:
+    """(unsigned image, bits) sort components implementing direction +
+    null order for sortops.sort_lex. Invalid rows always sink to the
+    end."""
+    from tidb_tpu.executor.sortops import int_sort_bits
+
+    comps = [(~batch.row_valid, 1)]
     for fn, desc in zip(key_fns, descs):
         k = fn(batch)
         valid = k.valid & batch.row_valid
-        # MySQL: NULLs sort first ascending, last descending. Ascending
-        # lax.sort puts False before True, so NULL rows need null_key False
-        # for ASC (valid) and True for DESC (~valid).
-        null_key = ~valid if desc else valid
-        data = k.data
+        # MySQL: NULLs sort first ascending, last descending. An
+        # ascending sort puts 0 before 1, so NULL rows need null_key 0
+        # for ASC (valid) and 1 for DESC (~valid).
+        comps.append((~valid if desc else valid, 1))
+        data = jnp.where(valid, k.data, jnp.zeros_like(k.data))
         if jnp.issubdtype(data.dtype, jnp.floating):
-            dirdata = -data if desc else data
-        elif data.dtype == jnp.bool_:
-            dirdata = data ^ desc
+            # a float sorts as its own operand (NaNs last either way)
+            comps.append((-data if desc else data, None))
         else:
-            dirdata = -data.astype(jnp.int64) if desc else data
-        ops.append(null_key)
-        ops.append(jnp.where(valid, dirdata, jnp.zeros_like(dirdata)))
-    return ops
+            u, bits = int_sort_bits(data)
+            comps.append((~u if desc and bits > 1 else u ^ desc, bits))
+    return comps
 
 
 def sort_permutation(batch: Batch, key_fns, descs) -> jax.Array:
-    cap = batch.capacity
-    ops = _directional_operands(batch, key_fns, descs)
-    out = jax.lax.sort(ops + [jnp.arange(cap, dtype=jnp.int32)], num_keys=len(ops))
-    return out[-1]
+    """Row permutation of ORDER BY: one packed unstable sort whose last
+    component is the row id, so ties keep row order (sortops module
+    docstring: compile time follows key limbs)."""
+    from tidb_tpu.executor.sortops import sort_rows
+
+    comps = _directional_comps(batch, key_fns, descs)
+    return sort_rows(comps, batch.capacity)[2]
 
 
 def order_by(batch: Batch, key_fns, descs) -> Batch:

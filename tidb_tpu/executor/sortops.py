@@ -27,11 +27,22 @@ device hash tables:
 Both keep every op regular (sorts, scans, small gathers), report true
 cardinalities for the host's capacity-discovery protocol, and compile to
 a single fused XLA program like the rest of the engine.
+
+Every sort here is UNSTABLE over PACKED keys (pack_lex). The v5e
+compiler's time for one lax.sort grows about quadratically with the
+number of 32-bit key limbs its comparator reads and hardly at all with
+the length (measured for v5e:2x2, 6,291,456 rows: one i32 key 7 s, one
+i64 key 27 s, i32+i32 27 s, i64+i32 59 s, i64+i64 105 s; an int8 flag
+costs a whole limb, and is_stable=True adds a hidden iota key — the
+seed's aggregation sort of five int8 keys + row id took 524 s). So key
+components are bit-packed into as few words as hold them, and the row
+id rides as the last packed component, which makes the order total and
+stability moot.
 """
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence, Tuple
+from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +50,123 @@ import jax.numpy as jnp
 from tidb_tpu.chunk import Batch, DevCol
 
 _I64_MAX = jnp.iinfo(jnp.int64).max
+
+
+def bits_for(n: int) -> int:
+    """Bits that hold every value in [0, n)."""
+    return max(int(n - 1).bit_length(), 1)
+
+
+def pack_lex(comps):
+    """Pack lexicographic sort components into key operands.
+
+    comps: [(array, bits)] most-significant first. An array with a
+    `bits` holds unsigned values below 2**bits (any int/bool dtype,
+    bits <= 64); bits=None marks an operand that sorts as it is (a
+    float: the v5e compiler cannot bitcast 64-bit floats to integers).
+    Each run of packable components forms one bit string, cut into
+    ceil(total/32) uint32 limbs (a component may straddle limbs; spare
+    bits pad the top of the first). Returns (operands most-significant
+    first, where): where[i] places component i for unpack_lex."""
+    operands: list = []
+    where: list = [None] * len(comps)
+
+    def flush(run):
+        total = sum(bits for _i, _a, bits in run)
+        nlimbs = -(-total // 32)
+        off = total
+        offs = []
+        for i, _a, bits in run:
+            off -= bits
+            offs.append(off)
+            where[i] = (len(operands), nlimbs, off, bits)
+        for j in range(nlimbs - 1, -1, -1):  # j = limb index from the end
+            lo = 32 * j
+            word = None
+            for (_i, arr, bits), off in zip(run, offs):
+                if off >= lo + 32 or off + bits <= lo:
+                    continue
+                v = arr.astype(jnp.uint64)
+                v = (
+                    v << jnp.uint64(off - lo) if off >= lo
+                    else v >> jnp.uint64(lo - off)
+                )
+                part = (v & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
+                word = part if word is None else word | part
+            operands.append(word)
+
+    run: list = []
+    for i, (arr, bits) in enumerate(comps):
+        if bits is None:
+            if run:
+                flush(run)
+                run = []
+            where[i] = (len(operands), 1, 0, None)
+            operands.append(arr)
+        else:
+            run.append((i, arr, bits))
+    if run:
+        flush(run)
+    return operands, where
+
+
+def unpack_lex(operands, where, i):
+    """Component i of (sorted) pack_lex operands: uint64 for a packed
+    component, the operand itself for a bits=None one."""
+    first, nlimbs, off, bits = where[i]
+    if bits is None:
+        return operands[first]
+    out = None
+    for j in range(nlimbs):
+        lo = 32 * j
+        if off >= lo + 32 or off + bits <= lo:
+            continue
+        v = operands[first + nlimbs - 1 - j].astype(jnp.uint64)
+        v = v >> jnp.uint64(off - lo) if off >= lo else v << jnp.uint64(lo - off)
+        out = v if out is None else out | v
+    return out & jnp.uint64((1 << bits) - 1)
+
+
+def sort_lex(comps):
+    """Unstable ascending sort of packed components; pass a unique last
+    component (the row id) for a total order. Returns the sorted
+    operands and the `where` table for unpack_lex."""
+    operands, where = pack_lex(comps)
+    out = jax.lax.sort(operands, num_keys=len(operands), is_stable=False)
+    return list(out), where
+
+
+def sort_rows(comps, cap: int):
+    """sort_lex with the row id appended as the last component: a total
+    order in which ties keep row order. Returns (sorted operands,
+    where, perm) — perm[j] is the original row now at position j."""
+    comps = list(comps) + [(jnp.arange(cap, dtype=jnp.int32), bits_for(cap))]
+    ops, where = sort_lex(comps)
+    return ops, where, unpack_lex(ops, where, len(comps) - 1).astype(jnp.int32)
+
+
+def int_sort_bits(d: jax.Array):
+    """(unsigned order-preserving image of an integer/bool array, bits):
+    the value less its dtype's minimum."""
+    if d.dtype == jnp.bool_:
+        return d, 1
+    nbits = d.dtype.itemsize * 8
+    if jnp.issubdtype(d.dtype, jnp.unsignedinteger):
+        return d, nbits
+    u = jax.lax.bitcast_convert_type(d, jnp.dtype(f"uint{nbits}"))
+    return u ^ u.dtype.type(1 << (nbits - 1)), nbits
+
+
+def int_from_sort_bits(u: jax.Array, dtype):
+    """Inverse of int_sort_bits for a uint64-held image."""
+    dtype = jnp.dtype(dtype)
+    if dtype == jnp.bool_:
+        return u != 0
+    nbits = dtype.itemsize * 8
+    if jnp.issubdtype(dtype, jnp.unsignedinteger):
+        return u.astype(dtype)
+    w = u.astype(jnp.dtype(f"uint{nbits}"))
+    return jax.lax.bitcast_convert_type(w ^ w.dtype.type(1 << (nbits - 1)), dtype)
 
 
 def merge_searchsorted(
@@ -58,20 +186,17 @@ def merge_searchsorted(
     tq = 0 if side == "left" else 1
     tk = 1 - tq
     keys = jnp.concatenate([sorted_keys, queries])
-    tags = jnp.concatenate(
+    # (tag, query id) share one word behind the key: equal (key, tag)
+    # non-query entries are identical, so the unstable order is moot
+    tagq = jnp.concatenate(
         [
-            jnp.full(n, tk, dtype=jnp.int32),
-            jnp.full(m, tq, dtype=jnp.int32),
+            jnp.full(n, tk << 31, dtype=jnp.uint32),
+            jnp.uint32(tq << 31) | jnp.arange(m, dtype=jnp.uint32),
         ]
     )
-    qid = jnp.concatenate(
-        [
-            jnp.zeros(n, dtype=jnp.int32),  # ignored: tag marks non-query
-            jnp.arange(m, dtype=jnp.int32),
-        ]
-    )
-    _sk, st, sq = jax.lax.sort([keys, tags, qid], num_keys=2)
-    is_q = st == tq
+    _sk, stq = jax.lax.sort([keys, tagq], num_keys=2, is_stable=False)
+    is_q = (stq >> jnp.uint32(31)) == tq
+    sq = (stq & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
     nq_incl = jnp.cumsum(is_q.astype(jnp.int32))
     res = jnp.arange(n + m, dtype=jnp.int32) - (nq_incl - 1)
     packed = jnp.where(
@@ -79,7 +204,7 @@ def merge_searchsorted(
         (sq.astype(jnp.int64) << 32) | res.astype(jnp.int64),
         _I64_MAX,
     )
-    back = jax.lax.sort([packed], num_keys=1)[0][:m]
+    back = jax.lax.sort([packed], num_keys=1, is_stable=False)[0][:m]
     return (back & jnp.int64(0xFFFFFFFF)).astype(queries.dtype)
 
 
@@ -102,17 +227,62 @@ def run_ends(sorted_keys: jax.Array) -> jax.Array:
 
 def _seg_scan(vals: jax.Array, boundary: jax.Array, op) -> jax.Array:
     """Inclusive segmented scan: runs of rows between boundary flags are
-    scanned independently. Standard segmented-scan semiring over
-    (value, started-a-new-segment) pairs; associative, so lax's log-depth
-    associative_scan applies."""
+    scanned independently. The standard segmented-scan semiring over
+    (value, started-a-new-segment) pairs, stepped by doubling strides in
+    ONE rolled loop: lax.associative_scan unrolls its 2*log2(n) levels
+    into the program, and the v5e compiler had not finished that at
+    6,291,456 rows after 15 minutes; this loop compiles in 3 s."""
+    n = vals.shape[0]
+    idx = jnp.arange(n, dtype=jnp.int32)
 
-    def combine(a, b):
-        av, ab = a
-        bv, bb = b
-        return jnp.where(bb, bv, op(av, bv)), ab | bb
+    def step(k, st):
+        v, f = st
+        d = jnp.int32(1) << k
+        ok = idx >= d
+        pv, pf = jnp.roll(v, d), jnp.roll(f, d)
+        return jnp.where(ok & ~f, op(pv, v), v), f | (ok & pf)
 
-    v, _b = jax.lax.associative_scan(combine, (vals, boundary))
+    v, _f = jax.lax.fori_loop(0, bits_for(n), step, (vals, boundary))
     return v
+
+
+def _key_comps(k: DevCol, width):
+    """Sort components of one key column, NULLs last: [(~valid, 1 bit),
+    (data image, bits), (nan flag, 1 bit for floats)], a decoder
+    (`get(j)` = the column's j-th sorted component -> the column's
+    dtype), and a per-row mask of values outside a planner-baked width.
+    Equal SQL values give equal component tuples (NULL data zeroed,
+    -0.0 folded to +0.0, NaN zeroed and carried as a flag). `width` = (w, b) from the planner
+    (values + b + 1 fit w bits, aggregate._pack_keys' convention) packs
+    integer data tight; without it the dtype's own width is used. A
+    float sorts as its own operand."""
+    d = k.data
+    inv = (~k.valid, 1)
+    if jnp.issubdtype(d.dtype, jnp.floating):
+        dd = jnp.where(d == 0, jnp.zeros_like(d), d)
+        nanf = jnp.isnan(dd) & k.valid
+        dd = jnp.where(nanf | ~k.valid, jnp.zeros_like(dd), dd)
+        return (
+            [inv, (dd, None), (nanf, 1)],
+            lambda get: jnp.where(get(2) != 0, jnp.nan, get(1)),
+            None,
+        )
+    if width is not None and d.dtype != jnp.bool_:
+        w, b = width
+        v = d.astype(jnp.int64) + b
+        bad = k.valid & ((v < 0) | (v > (1 << w) - 2))
+        v = jnp.where(k.valid & ~bad, v, 0)
+        return (
+            [inv, (v, w)],
+            lambda get: (get(1).astype(jnp.int64) - b).astype(d.dtype),
+            bad,
+        )
+    vd = jnp.where(k.valid, d, jnp.zeros_like(d))
+    return (
+        [inv, int_sort_bits(vd)],
+        lambda get: int_from_sort_bits(get(1), d.dtype),
+        None,
+    )
 
 
 def sort_group_aggregate(
@@ -123,35 +293,55 @@ def sort_group_aggregate(
     slots: int,
     key_names: Sequence[str],
     reps=None,
+    key_widths=None,
 ) -> Tuple[Batch, jax.Array]:
     """Keyed aggregation by lexicographic sort, replacing the claim-loop
     hash table on TPU (see module docstring). Returns (group batch with
     capacity `slots`, true group count) under the same overflow protocol
     as group_aggregate: a count above `slots` makes the host bump the
     capacity knob and re-jit; results in the returned batch are correct
-    whenever the count fits.
+    whenever the count fits. A valid key outside its planner-baked
+    width (`key_widths`) reports aggregate.WIDTH_STALE instead, and the
+    host recompiles against fresh bounds.
 
-    Groups come out in ascending key order (NULLs first) — a stable,
+    Groups come out in ascending key order (NULLs last) — a stable,
     mesh-friendly order that downstream distributed merges rely on.
     DISTINCT rep masks (`reps`, in original row order) are permuted
     through the sort like every other contribution mask.
     """
-    from tidb_tpu.executor.aggregate import _run_sorted_aggs, _sort_components
+    from tidb_tpu.executor.aggregate import WIDTH_STALE, _run_sorted_aggs
 
     cap = batch.capacity
-    comps: List[jax.Array] = [(~batch.row_valid).astype(jnp.int8)]
-    for k in keys:
-        comps.extend(_sort_components(k))
-    rowid = jnp.arange(cap, dtype=jnp.int32)
-    sorted_all = jax.lax.sort(comps + [rowid], num_keys=len(comps) + 1)
-    s_comps, perm = sorted_all[:-1], sorted_all[-1]
-    valid_s = s_comps[0] == 0  # invalid rows sort last (first key)
+    widths = key_widths if key_widths is not None else [None] * len(keys)
+    comps: list = [(~batch.row_valid, 1)]
+    key_at: list = []  # per key: (index of its first component, decoder)
+    stale = jnp.zeros((), dtype=bool)
+    for k, width in zip(keys, widths):
+        kc, decode, bad = _key_comps(k, width)
+        key_at.append((len(comps), decode))
+        comps.extend(kc)
+        if bad is not None:
+            stale = stale | jnp.any(batch.row_valid & bad)
+    rowid_at = len(comps)
+    sorted_ops, where, perm = sort_rows(comps, cap)
+    valid_s = unpack_lex(sorted_ops, where, 0) == 0  # invalid rows sort last
 
-    first = jnp.zeros(cap, dtype=bool).at[0].set(True)
-    diff = jnp.zeros(cap, dtype=bool)
-    for c in s_comps[1:]:
-        diff = diff | jnp.concatenate([jnp.ones(1, dtype=bool), c[1:] != c[:-1]])
-    boundary = valid_s & (first | diff)
+    # a new group starts where any key bit differs from the row before:
+    # compare whole operands, the row id's bits (the tail of the last
+    # packed run) shifted out
+    rid_first, rid_limbs, _off, rid_bits = where[rowid_at]
+    diff = jnp.zeros(cap, dtype=bool).at[0].set(True)
+    for oi, op in enumerate(sorted_ops):
+        if oi >= rid_first:
+            tail = rid_bits - 32 * (rid_first + rid_limbs - 1 - oi)
+            if tail >= 32:
+                continue  # nothing but row-id bits in this limb
+            if tail > 0:
+                op = op >> jnp.uint32(tail)
+        diff = diff | jnp.concatenate(
+            [jnp.ones(1, dtype=bool), op[1:] != op[:-1]]
+        )
+    boundary = valid_s & diff
     ngroups = jnp.sum(boundary.astype(jnp.int64))
     nvalid = jnp.sum(valid_s.astype(jnp.int32))
 
@@ -163,7 +353,7 @@ def sort_group_aggregate(
         spos = jnp.concatenate(
             [spos, jnp.full(slots - cap, cap, dtype=jnp.int32)]
         )
-    starts = jax.lax.sort([spos], num_keys=1)[0][:slots]
+    starts = jax.lax.sort([spos], num_keys=1, is_stable=False)[0][:slots]
     ends = jnp.minimum(
         jnp.concatenate([starts[1:], jnp.full(1, cap, dtype=jnp.int32)]),
         nvalid,
@@ -172,19 +362,15 @@ def sort_group_aggregate(
     starts_c = jnp.minimum(starts, cap - 1)
 
     # key output columns: component values at segment starts
+    at_starts = [op[starts_c] for op in sorted_ops]
     out_cols = {}
-    ci = 1
-    for name, k in zip(key_names, keys):
-        ncomp = len(_sort_components(k))
-        kvalid_s = s_comps[ci] == 0  # first component is ~valid
-        kdata_s = s_comps[ci + 1]
-        ci += ncomp
-        kd = kdata_s[starts_c].astype(k.data.dtype)
-        kv = kvalid_s[starts_c] & group_valid
+    for name, (ci, decode) in zip(key_names, key_at):
+        kv = (unpack_lex(at_starts, where, ci) == 0) & group_valid
+        kd = decode(lambda j, ci=ci: unpack_lex(at_starts, where, ci + j))
         out_cols[name] = DevCol(jnp.where(group_valid, kd, jnp.zeros_like(kd)), kv)
 
     out = _run_sorted_aggs(
         batch, aggs, arg_cols, perm, valid_s, boundary,
         starts_c, ends, group_valid, out_cols, reps=reps,
     )
-    return out, ngroups
+    return out, jnp.where(stale, jnp.int64(WIDTH_STALE), ngroups)

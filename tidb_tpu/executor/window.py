@@ -146,9 +146,7 @@ def window_op(
         .at[jnp.where(srow_valid, pgc, cap)]
         .max(idx64, mode="drop")[pgc]
     )
-    peer_start = jax.lax.associative_scan(
-        jnp.maximum, jnp.where(peer_change, idx64, 0)
-    )
+    peer_start = jax.lax.cummax(jnp.where(peer_change, idx64, 0))
     aux = {
         "last_idx": last_idx, "peer_last": peer_last,
         "peer_start": peer_start,
@@ -344,16 +342,12 @@ def _compute(
         if d.running:
             op = jnp.minimum if d.func == "min" else jnp.maximum
 
-            # segmented scan: (value, segment-start flag) pairs reset the
-            # accumulator at every partition boundary
-            def comb(a, b):
-                av, af = a
-                bv, bf = b
-                return jnp.where(bf, bv, op(av, bv)), af | bf
+            # segmented scan: the accumulator resets at every partition
+            # boundary
+            from tidb_tpu.executor.sortops import _seg_scan
 
             seg_start = first_idx[seg] == jnp.arange(cap, dtype=jnp.int32)
-            scanned, _ = jax.lax.associative_scan(comb, (masked, seg_start))
-            run = scanned
+            run = _seg_scan(masked, seg_start, op)
             cnt = jnp.cumsum(valid.astype(jnp.int64))
             cnt = cnt - jnp.where(first_idx[seg] > 0, cnt[jnp.clip(first_idx[seg] - 1, 0, cap - 1)], 0)
         else:

@@ -1974,8 +1974,41 @@ def _is_string_col(e: Expr) -> bool:
     return e.type is not None and e.type.kind == Kind.STRING
 
 
+def _as_decimal_literal(lit, other_t):
+    """A plain float literal compared with a DECIMAL/INT operand, when
+    it is a short exact decimal (0.05, 24.5): the same literal typed
+    DECIMAL(k), so the comparison runs on exact scaled integers (what
+    the literal is in MySQL). The float-space form (col / 10^s vs 0.05)
+    leans on float64 division being exact to the last bit, and the
+    TPU's emulated float64 is not: `l_discount >= 0.05` dropped every
+    0.05 row on the v5e (PR 23). None = keep the float comparison."""
+    import dataclasses
+    from decimal import Decimal
+
+    from tidb_tpu.dtypes import DECIMAL
+
+    if not isinstance(lit, Literal) or lit.param_slot is not None:
+        return None
+    if (
+        lit.type is None or lit.type.kind != Kind.FLOAT or other_t is None
+        or other_t.kind not in (Kind.DECIMAL, Kind.INT)
+    ):
+        return None
+    v = lit.value
+    if not isinstance(v, float) or v != v or abs(v) >= 1e15:
+        return None
+    k = max(-Decimal(repr(v)).as_tuple().exponent, 0)
+    s = other_t.scale if other_t.kind == Kind.DECIMAL else 0
+    if k > s + 4:  # the column would rescale by 10^(k-s): keep it small
+        return None
+    return dataclasses.replace(lit, type=DECIMAL(k))
+
+
 def _compile_binary(e: Func, dicts: DictContext) -> _CompiledExpr:
     op, (ea, eb) = e.op, e.args
+    if op in COMPARE or op == "nulleq":
+        ea = _as_decimal_literal(ea, eb.type) or ea
+        eb = _as_decimal_literal(eb, ea.type) or eb
     # string comparisons: column vs literal -> integer code compare.
     if (op in COMPARE or op == "nulleq") and _is_string_col(ea) and isinstance(eb, Literal):
         return _compile_strcmp(e, dicts, flipped=False)

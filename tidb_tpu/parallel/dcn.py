@@ -1034,7 +1034,7 @@ class DCNFragmentScheduler:
     def _classify_reply(
         self, resp, suspects, errs, cancelled, release=None
     ) -> bool:
-        """THE worker-reply taxonomy, shared by fragment, sampling and
+        """THE worker-reply classification, shared by fragment, sampling and
         DAG-stage dispatch: True = ok (the caller lands the result); a
         deliberate abort (``cancelled`` — fleet cancel / propagated
         deadline: neither an engine error nor a death suspect, PR 10's

@@ -143,9 +143,14 @@ def exchange_by_target(
     B = bucket_capacity
     cap = batch.capacity
 
-    sorted_t, perm = jax.lax.sort(
-        [target.astype(jnp.int32), jnp.arange(cap, dtype=jnp.int32)], num_keys=1
+    from tidb_tpu.executor.sortops import bits_for, sort_rows, unpack_lex
+
+    # (destination, row id) packed into one key word: rows keep their
+    # order inside a bucket (sortops: compile time follows key limbs)
+    ops, where, perm = sort_rows(
+        [(jnp.clip(target, 0, n), bits_for(n + 1))], cap
     )
+    sorted_t = unpack_lex(ops, where, 0).astype(jnp.int32)
     start = jnp.searchsorted(sorted_t, jnp.arange(n + 1, dtype=jnp.int32))
     slot = jnp.arange(cap, dtype=jnp.int32) - start[jnp.clip(sorted_t, 0, n)]
     fits = (slot < B) & (sorted_t < n)

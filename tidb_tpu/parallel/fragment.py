@@ -27,6 +27,7 @@ from tidb_tpu.chunk import Batch, DevCol
 from tidb_tpu.executor.aggregate import AggDesc, group_aggregate
 from tidb_tpu.executor.join import equi_join
 from tidb_tpu.parallel.exchange import broadcast_gather, hash_repartition
+from tidb_tpu.parallel.mesh import pmax
 
 ExprFn = Callable[[Batch], DevCol]
 
@@ -191,7 +192,7 @@ def distributed_group_aggregate(
         )
         return (
             Batch(dict(fin.cols), fin.row_valid),
-            jax.lax.pmax(ng, axis),
+            pmax(ng, axis),
             jnp.zeros((), jnp.int64),
             jnp.zeros((), jnp.int64),
         )
@@ -236,11 +237,11 @@ def distributed_group_aggregate(
 
     # pmax (not psum) for the scalar case: the broadcast made every shard
     # compute the same single group; pmax also proves replication to jax.
-    total_groups = jax.lax.psum(ng, axis) if key_fns else jax.lax.pmax(ng, axis)
+    total_groups = jax.lax.psum(ng, axis) if key_fns else pmax(ng, axis)
     # a partial-stage overflow anywhere (part_ng above the partial output
     # tile, hence above the capacity knob) must surface to the host even
     # though the final stage fit
-    total_groups = jnp.maximum(total_groups, jax.lax.pmax(part_ng, axis))
+    total_groups = jnp.maximum(total_groups, pmax(part_ng, axis))
     return Batch(cols, fin.row_valid), total_groups, dropped, need
 
 

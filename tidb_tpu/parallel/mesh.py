@@ -14,6 +14,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -22,44 +23,44 @@ from tidb_tpu.chunk import Batch
 AXIS = "d"
 
 
-# -- jax API compat ---------------------------------------------------------
-# `jax.shard_map` / `jax.sharding.reshard` are the modern spellings; the
-# pinned jax (0.4.x) only has the experimental/constraint forms. One
-# shim here so every SPMD call site (planner/physical.py, tests) works
-# on both — without it the whole mesh mode dies with AttributeError.
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # jax<0.5: experimental form, whose replication checker predates
-    # rules for `while` (the aggregation claim loop) — disable it; the
-    # engine's out_specs declare the replication contract explicitly
-    import functools as _functools
-
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f=None, **kw):
-        kw.setdefault("check_rep", False)
-        if f is None:
-            return _functools.partial(shard_map, **kw)
-        return _shard_map_exp(f, **kw)
+shard_map = jax.shard_map
 
 
 def reshard(a, sharding):
-    """jax.sharding.reshard(a, s) on new jax; on old jax a sharding
-    constraint under tracing and a device_put eagerly."""
-    if hasattr(jax.sharding, "reshard"):
-        return jax.sharding.reshard(a, sharding)
-    from jax import core as _core
+    """Pin `a` to `sharding`. The mesh axis is Auto (make_mesh), where
+    the partitioner owns layouts and a sharding constraint is how a
+    program states one; jax.sharding.reshard speaks for Explicit axes
+    and leaves an Auto-mode output wherever the partitioner put it —
+    across processes that is a result no host can fetch."""
+    return jax.lax.with_sharding_constraint(a, sharding)
 
-    if isinstance(a, _core.Tracer):
-        return jax.lax.with_sharding_constraint(a, sharding)
-    return jax.device_put(a, sharding)
+
+def pmax(v, axis: str = AXIS):
+    """lax.pmax that the TPU compiler also takes for 64-bit integers.
+    Its X64 rewriter lowers only SUM all-reduces of int64 ("Supported
+    lowering only of Sum all reduce"), and the engine's cardinality
+    scalars are int64 (WIDTH_STALE is 2^60), so a 64-bit max goes as two
+    32-bit ones: the high words, then the low words of the shards that
+    hold the high maximum."""
+    if v.dtype != jnp.int64:
+        return jax.lax.pmax(v, axis)
+    hi = (v >> 32).astype(jnp.int32)
+    lo = (v & 0xFFFFFFFF).astype(jnp.uint32)
+    mhi = jax.lax.pmax(hi, axis)
+    mlo = jax.lax.pmax(jnp.where(hi == mhi, lo, jnp.uint32(0)), axis)
+    return (mhi.astype(jnp.int64) << 32) | mlo.astype(jnp.int64)
 
 
 def make_mesh(n_devices: Optional[int] = None) -> Mesh:
     devs = jax.devices()
     n = n_devices or len(devs)
-    return jax.make_mesh((n,), (AXIS,), devices=devs[:n])
+    # Auto, not jax 0.9's default Explicit: the engine reshapes and
+    # concatenates sharded operands and leaves their layout to the
+    # partitioner
+    return jax.make_mesh(
+        (n,), (AXIS,), devices=devs[:n],
+        axis_types=(jax.sharding.AxisType.Auto,),
+    )
 
 
 def init_multihost(
@@ -91,17 +92,6 @@ def init_multihost(
                 flags
                 + f" --xla_force_host_platform_device_count={local_device_count}"
             ).strip()
-    try:
-        # CPU dryruns need an inter-process collectives transport; jax
-        # 0.4.x defaults to 'none' ("Multiprocess computations aren't
-        # implemented on the CPU backend"). Newer jax picks gloo itself
-        # and drops the knob — hence best-effort.
-        import os
-
-        if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=num_processes,

@@ -2037,7 +2037,7 @@ class ShuffleWorker:
                     t.flush()
         except WaitInterrupted:
             # a shipper failed while we were waiting: surface ITS
-            # error with the same taxonomy as the in-try raises (a
+            # error with the same classification as the in-try raises (a
             # raise from an except clause skips sibling handlers)
             for th in shippers:
                 th.join(timeout=30)

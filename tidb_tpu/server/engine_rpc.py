@@ -894,7 +894,7 @@ class EngineServer:
         merged quantile cut. A lost reply (shuffle/sample-lost) is a
         transport suspect the coordinator verifies like any dispatch
         loss; retryable failures (a held StageInput missing after a
-        worker restart) reply with the suspect taxonomy of
+        worker restart) reply with the suspect classification of
         _shuffle_task so the whole DAG retries on the survivor set."""
         from tidb_tpu.parallel.shuffle import ShuffleAbort
         from tidb_tpu.utils import sqlkiller as _sk
@@ -945,7 +945,7 @@ class EngineServer:
         """AQE skew/cardinality probe round (ShuffleWorker.run_probe,
         parallel/aqe.py): produce-and-cache every side of a hash
         stage, reply each side's exact per-partition row histogram +
-        hottest keys. Taxonomy mirrors _shuffle_sample: a lost reply
+        hottest keys. Classification mirrors _shuffle_sample: a lost reply
         (aqe/probe-lost) is a transport suspect the coordinator
         verifies; retryable failures carry the suspect list."""
         from tidb_tpu.parallel.shuffle import ShuffleAbort

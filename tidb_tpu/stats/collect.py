@@ -62,7 +62,7 @@ def _column_stats_kernel(data, valid, row_valid):
     count = jnp.sum(ok.astype(jnp.int64))
     big = jnp.iinfo(jnp.int64).max if not jnp.issubdtype(data.dtype, jnp.floating) else jnp.inf
     key = jnp.where(ok, data.astype(jnp.float64) if jnp.issubdtype(data.dtype, jnp.floating) else data.astype(jnp.int64), big)
-    s = jax.lax.sort([key])[0]
+    s = jax.lax.sort([key], is_stable=False)[0]
     # distinct change flags among valid prefix
     idx = jnp.arange(cap)
     is_valid_pos = idx < count
@@ -84,7 +84,10 @@ def _column_stats_kernel(data, valid, row_valid):
         .at[seg.astype(jnp.int32)]
         .min(jnp.arange(cap, dtype=jnp.int32), mode="drop")[:cap]
     )
-    topf, topi = jax.lax.top_k(freq, N_TOPN)
+    # counts fit int32 (cap < 2^31): the v5e compiler's time for a
+    # top_k/sort follows the key's 32-bit limbs (executor/sortops.py)
+    topf, topi = jax.lax.top_k(freq.astype(jnp.int32), N_TOPN)
+    topf = topf.astype(jnp.int64)
     top_vals = s[first_idx[topi]]
     mn = s[0]
     mx = s[jnp.clip(count - 1, 0, cap - 1)]

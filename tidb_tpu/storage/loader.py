@@ -68,14 +68,17 @@ def load_rows_python(table, lines: List[str], sep: str) -> int:
 
 
 def load_file(table, path: str, sep: str = "\t") -> int:
-    """Load a delimited file; uses the native splitter when available."""
-    try:
-        from tidb_tpu.storage.native import native_load  # C++ fast path
+    """Load a delimited file; uses the native splitter when available
+    (None = no compiler / unsupported column kinds: the Python loader is
+    the defined behaviour). A native parse rejection falls through to the
+    Python parser, which reports the row; a failed build raises."""
+    from tidb_tpu.storage.native import native_load  # C++ fast path
 
+    try:
         res = native_load(table, path, sep)
-        if res is not None:
-            return res
-    except Exception:
-        pass
+    except ValueError:
+        res = None
+    if res is not None:
+        return res
     with open(path, "r", encoding="utf-8", errors="replace") as f:
         return load_rows_python(table, f.readlines(), sep)

@@ -1,8 +1,12 @@
 """ctypes bindings for the native loader (native/loader.cpp).
 
-Builds the shared library on demand with g++ (no pybind11 in the image;
-ctypes avoids any build-time Python dependency). Arrays are wrapped as
-numpy views over the C++ vectors and copied once into HostColumns.
+The shared library is not tracked: it is built on first use with g++
+from native/loader.cpp (no pybind11 in the image; ctypes avoids any
+build-time Python dependency), without -march flags, so a checkout
+builds what it runs. Without a compiler the Python loader is the
+defined behaviour (native_load returns None); a compiler that is there
+and fails is an error. Arrays are wrapped as numpy views over the C++
+vectors and copied once into HostColumns.
 """
 
 from __future__ import annotations
@@ -24,7 +28,7 @@ _SO = os.path.join(_HERE, "_native.so")
 _SRC = os.path.join(os.path.dirname(os.path.dirname(_HERE)), "native", "loader.cpp")
 _lock = racecheck.make_lock("storage.native")
 _lib = None
-_build_failed = False
+_no_compiler = False
 
 _TYPECODE = {
     Kind.INT: 0,
@@ -37,16 +41,13 @@ _TYPECODE = {
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _build_failed
+    global _lib, _no_compiler
     with _lock:
         if _lib is not None:
             return _lib
-        if _build_failed:
+        if _no_compiler:
             return None
-        if not os.path.exists(_SO) or (
-            os.path.exists(_SRC)
-            and os.path.getmtime(_SRC) > os.path.getmtime(_SO)
-        ):
+        if not os.path.exists(_SO):
             try:
                 # lock-blocking-ok: the lazy one-shot native build
                 # deliberately holds the module lock so racing loaders
@@ -61,9 +62,14 @@ def _load() -> Optional[ctypes.CDLL]:
                     capture_output=True,
                     timeout=120,
                 )
-            except Exception:
-                _build_failed = True
+            except FileNotFoundError:
+                _no_compiler = True  # no g++: the Python loader answers
                 return None
+            except subprocess.CalledProcessError as e:
+                raise RuntimeError(
+                    "building native/loader.cpp failed:\n"
+                    + e.stderr.decode(errors="replace")[-2000:]
+                ) from e
         lib = ctypes.CDLL(_SO)
         lib.tt_parse_file.restype = ctypes.c_void_p
         lib.tt_parse_file.argtypes = [

