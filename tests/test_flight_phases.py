@@ -56,3 +56,31 @@ def test_runtime_rejects_undeclared_phase():
     with pytest.raises(ValueError, match="undeclared flight phase"):
         f.note_phase("no-such-phase", 0.1)
     f.discard()
+
+
+def test_lint_holds_span_sites_to_the_same_rules(tmp_path):
+    """FLIGHT.span("name") sites count as charging the phase of that
+    name, and SPANS is checked like PHASES: undeclared sites and dead
+    declarations are both violations."""
+    obs = tmp_path / "tidb_tpu" / "obs"
+    obs.mkdir(parents=True)
+    (obs / "flight.py").write_text(
+        'PHASES = (\n    "plan",\n)\n'
+        'SPANS = (\n    "plan",\n    "inputs",\n    "dead-span",\n)\n'
+        'FLIGHT = None\n'
+    )
+    (tmp_path / "tidb_tpu" / "engine.py").write_text(
+        'from tidb_tpu.obs.flight import FLIGHT as _FLIGHT\n'
+        'with _FLIGHT.span("plan"):\n    pass\n'
+        'with _FLIGHT.span("inputs"):\n    pass\n'
+        'with _FLIGHT.span("typo-span"):\n    pass\n'
+    )
+    proc = subprocess.run(
+        [sys.executable, LINT, str(tmp_path)],
+        capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode == 1
+    assert "typo-span" in proc.stdout      # undeclared span site
+    assert "dead-span" in proc.stdout      # declared, never opened
+    assert "'plan'" not in proc.stdout     # the span site charges the phase
+    assert "'inputs'" not in proc.stdout

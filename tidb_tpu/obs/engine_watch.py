@@ -364,8 +364,6 @@ def watched_jit(fn, sig=None, **jit_kwargs):
     and timeline compile events. Returns a plain callable (every call
     site is call-only; the jit object stays an implementation detail).
     """
-    import time as _time
-
     import jax
 
     from tidb_tpu.obs.flight import FLIGHT
@@ -378,21 +376,25 @@ def watched_jit(fn, sig=None, **jit_kwargs):
             return fn(*a, **k)
         _TLS.fresh_trace = True
         ENGINE_WATCH.note_trace(watch_sig)
-        t0 = _time.perf_counter()
-        # Top SQL live-phase marker: tracing runs synchronously on the
-        # statement's thread, so samples landing here attribute to
-        # compile — restored to the enclosing phase on exit
-        prev_phase = FLIGHT.set_live_phase("compile")
+        # tracing runs synchronously on the statement's thread: the
+        # span charges the compile phase and marks it for the Top SQL
+        # sampler, restoring the enclosing phase on exit
+        span = FLIGHT.span("compile")
         try:
-            return fn(*a, **k)
+            with span:
+                return fn(*a, **k)
         finally:
-            FLIGHT.restore_live_phase(prev_phase)
-            dt = _time.perf_counter() - t0
             # the SAME wall the flight recorder's compile phase
             # charges — the timeline compile event must not absorb
             # the first call's device execution (wrapper reads it)
-            _TLS.trace_wall = dt
-            FLIGHT.note_phase("compile", dt)
+            _TLS.trace_wall = span.seconds
+
+    # XLA names the module after the traced function: the kind of the
+    # signature (steady, discover, stream-*) tells a statement's program
+    # from ANALYZE's kernels on the device trace. The name is part of
+    # the persistent compile cache's key.
+    if isinstance(sig, tuple) and sig and isinstance(sig[0], str):
+        traced.__name__ = traced.__qualname__ = sig[0].replace("-", "_")
 
     jitted = jax.jit(traced, **jit_kwargs)
 

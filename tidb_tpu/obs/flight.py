@@ -26,11 +26,22 @@ Accounting model (mirrors obs/engine_watch.py):
   slow_query (phase timeline + captured plan text), and the
   tidbtpu_flight_* metric family.
 
-Phase names are a DECLARED registry (``PHASES``), the failpoint-SITES
-pattern: ``note_phase`` rejects undeclared names at runtime and
-scripts/check_flight_phases.py cross-checks the declaration against
-the literal call sites (tier-1 via tests/test_flight_phases.py), so a
-typo'd phase can neither silently fork the breakdown nor rot unused.
+A boundary is timed by ONE call: ``with FLIGHT.span(name)`` records the
+span on the flight (path, offset from the statement's root span,
+seconds), charges the declared phase of the same name, marks
+``live_phase`` for the Top SQL sampler, feeds the session's ``TRACE``
+tracer, and enters a ``jax.profiler.TraceAnnotation`` named
+``tidbtpu/<span path>`` that carries the flight's ``qid`` — so under a
+profiler session the statement's spans lie on the device trace's clock.
+``FLIGHT.background(name)`` does the same for one tick of a background
+loop; a finishing flight copies the ticks that ran beside it.
+
+Phase and span names are DECLARED registries (``PHASES``, ``SPANS``),
+the failpoint-SITES pattern: ``note_phase`` and ``span`` reject
+undeclared names at runtime and scripts/check_flight_phases.py
+cross-checks the declarations against the literal call sites (tier-1
+via tests/test_flight_phases.py), so a typo'd name can neither
+silently fork the breakdown nor rot unused.
 
 ``LINKS`` is the sibling registry for per-peer DCN link health
 (information_schema.cluster_links, the /links endpoint): RTT and clock
@@ -48,6 +59,8 @@ import itertools
 import threading
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from tidb_tpu.obs import profiler
 from tidb_tpu.obs.timeline import TIMELINE
@@ -81,6 +94,40 @@ PHASES = (
 )
 
 _PHASE_SET = frozenset(PHASES)
+
+#: every span ``FLIGHT.span`` may open. A served statement nests them as
+#: stmt (server.py: command packet read -> answer written) > session
+#: (Session.execute) > parse | plan | execute > compile | inputs |
+#: dispatch | device-wait | fetch, then final-merge and observe under
+#: session and wire/write under stmt. A span named like a phase charges
+#: that phase; the others cut time the phases lump together or leave out.
+SPANS = (
+    "stmt",
+    "session",
+    "parse",
+    "plan",
+    "execute",
+    "compile",
+    "inputs",
+    "dispatch",
+    "device-wait",
+    "fetch",
+    "final-merge",
+    "observe",
+    "wire/write",
+)
+
+_SPAN_SET = frozenset(SPANS)
+
+#: the spans a statement's tree may hang from: opened with nothing above
+#: them they draw the ``qid`` the statement's flight and annotations
+#: carry. Any other span opened with nothing above it (a compile on a
+#: worker's or ANALYZE's thread, the prepared fast path run without a
+#: server) is annotated with qid 0 and kept nowhere.
+_ROOTS = frozenset(("stmt", "session"))
+
+#: background ticks remembered for the flights that finish after them
+_TICK_RING = 512
 
 
 def _c_queries():
@@ -122,7 +169,7 @@ class QueryFlight:
         "jit_compilations", "retraces", "h2d_bytes", "d2h_bytes",
         "device_mem_peak_bytes", "compile_flops",
         "compile_bytes_accessed", "compile_output_bytes", "live_phase",
-        "est_rows", "act_rows",
+        "est_rows", "act_rows", "spans", "served_s", "background",
     )
 
     def __init__(self, qid: int, conn_id: int, sql: str):
@@ -163,9 +210,21 @@ class QueryFlight:
         #: Top SQL sampler (obs/profiler.py) reads it from another
         #: thread to attribute a sampled instant. note_phase charges
         #: walls at their END, which a sampler cannot use; this marker
-        #: is set at the few wall STARTS (plan/compile/dispatch/
-        #: final-merge) via FLIGHT.set_live_phase.
+        #: is set where a phase-named span opens (plan/compile/
+        #: final-merge) and, for the fleet phases that keep note_phase,
+        #: via FLIGHT.set_live_phase.
         self.live_phase = "execute"
+        #: closed spans as (path, start offset from the root span's
+        #: start, seconds), in closing order: the thread's trip owns
+        #: the list and ``begin`` hands it over, so it holds what closed
+        #: before the flight began (parse) and keeps growing after the
+        #: flight is in the ring (observe, session, wire/write, the root)
+        self.spans: List[tuple] = []
+        #: the root span's seconds (0.0 until it has closed)
+        self.served_s = 0.0
+        #: [name, overlap seconds] of the background ticks that ran
+        #: between the root span's start and this flight's finish
+        self.background: List[list] = []
 
     def phase_row(self, name: str) -> list:
         row = self.phases.get(name)
@@ -184,6 +243,125 @@ class QueryFlight:
         ]
 
 
+class _Trip:
+    """One thread's open span tree: a root span and what nests in it.
+    The root opens before its flight begins (the server has read the
+    packet, the session parses) and closes after it finished, so the
+    trip, not the flight, owns the clock's zero, the ``qid`` the
+    annotations carry and the ONE list of closed spans. The flight that
+    begins inside takes the qid and a reference to the list, and keeps
+    both: nothing is copied at ``begin`` or after ``finish``."""
+
+    __slots__ = (
+        "qid", "t0", "stack", "rows", "flight", "tracer", "tracer_depth",
+    )
+
+    def __init__(self, qid: int, flight: Optional[QueryFlight]):
+        self.qid = qid
+        self.t0 = 0.0
+        self.stack: List["_Span"] = []
+        #: the flight that began inside this trip (or was open when it
+        #: started), and the list every closing span is appended to
+        self.flight = flight
+        self.rows: List[tuple] = flight.spans if flight is not None else []
+        self.tracer = None
+        self.tracer_depth = 0
+
+
+class _Span:
+    """``with FLIGHT.span(name) as sp``: see the module docstring.
+    ``sp.seconds`` holds its wall once it has closed."""
+
+    __slots__ = (
+        "name", "path", "seconds", "_fr", "_trip", "_ann", "_t0",
+        "_compile0", "_prev",
+    )
+
+    def __init__(self, fr: "FlightRecorder", name: str):
+        self._fr = fr
+        self.name = name
+        self.seconds = 0.0
+
+    def __enter__(self) -> "_Span":
+        fr, name = self._fr, self.name
+        tls = fr._tls
+        trip = getattr(tls, "trip", None)
+        if trip is None:
+            rec = getattr(tls, "rec", None)
+            if rec is not None:
+                qid = rec.qid
+            else:
+                qid = next(fr._qid) if name in _ROOTS else 0
+            trip = tls.trip = _Trip(qid, rec)
+        self._trip = trip
+        stack = trip.stack
+        self.path = stack[-1].path + "/" + name if stack else name
+        self._prev = fr.set_live_phase(name) if name in _PHASE_SET else None
+        # execute's wall contains any jit traces watched_jit charges to
+        # compile: taken out at close so the two phases stay additive
+        self._compile0 = (
+            fr.phase_seconds("compile") if name == "execute" else 0.0
+        )
+        self._ann = TraceAnnotation("tidbtpu/" + self.path, qid=trip.qid)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        if not stack:
+            trip.t0 = self._t0
+        stack.append(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        fr, trip, name = self._fr, self._trip, self.name
+        stack = trip.stack
+        stack.pop()
+        self.seconds = seconds = t1 - self._t0
+        trip.rows.append((self.path, self._t0 - trip.t0, seconds))
+        if name in _PHASE_SET:
+            fr.restore_live_phase(self._prev)
+            # with no flight open yet (parse) only the counter moves:
+            # ``begin`` charges the flight from the trip's rows
+            fr.note_phase(
+                name,
+                seconds - (fr.phase_seconds("compile") - self._compile0)
+                if name == "execute" else seconds,
+            )
+        tracer = trip.tracer
+        if tracer is not None and tracer.enabled:
+            tracer.add(
+                name, self._t0, seconds, len(stack) + 1 - trip.tracer_depth
+            )
+        if not stack:
+            if trip.flight is not None:
+                trip.flight.served_s = seconds
+            fr._tls.trip = None
+
+
+class _Tick:
+    """``with FLIGHT.background(name)``: one tick of a background loop,
+    annotated like a span and remembered for the flights it overlaps."""
+
+    __slots__ = ("name", "t0", "_fr", "_ann")
+
+    def __init__(self, fr: "FlightRecorder", name: str):
+        self._fr = fr
+        self.name = name
+
+    def __enter__(self) -> "_Tick":
+        self._ann = TraceAnnotation("tidbtpu/background/" + self.name)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        self._fr._ticks_open[threading.get_ident()] = self
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._fr._ticks_open.pop(threading.get_ident(), None)
+        self._fr._ticks.append((self.name, self.t0, t1))
+
+
 class FlightRecorder:
     """Always-on per-statement recorder: thread-local current flight,
     finished flights in a bounded ring (oldest evicted). All note_*
@@ -195,6 +373,10 @@ class FlightRecorder:
         self._lock = racecheck.make_lock("flight.ring")
         self._recent = collections.deque(maxlen=capacity)
         self._qid = itertools.count(1)
+        #: finished background ticks (name, start, end on perf_counter)
+        #: in closing order, and the open ones by thread
+        self._ticks = collections.deque(maxlen=_TICK_RING)
+        self._ticks_open: Dict[int, _Tick] = {}
 
     def set_ring_capacity(self, capacity: int) -> None:
         """Resize the finished-flight ring (newest kept). Load
@@ -209,8 +391,25 @@ class FlightRecorder:
 
     # -- statement scope ----------------------------------------------
     def begin(self, sql: str, conn_id: int = 0) -> QueryFlight:
-        rec = QueryFlight(next(self._qid), int(conn_id), str(sql)[:2048])
+        trip = getattr(self._tls, "trip", None)
+        if trip is not None and trip.flight is None and trip.qid:
+            # the statement's own tree: its qid, and its rows so far
+            rec = QueryFlight(trip.qid, int(conn_id), str(sql)[:2048])
+            rec.spans = trip.rows
+        else:
+            rec = QueryFlight(next(self._qid), int(conn_id), str(sql)[:2048])
+            if trip is not None:
+                # a batch's next statement: later spans are its own
+                trip.qid, trip.rows = rec.qid, rec.spans
+        if trip is not None:
+            trip.flight = rec
         self._tls.rec = rec
+        for path, _at, seconds in rec.spans:
+            # what closed before the flight existed (parse) is its own;
+            # the phase counter moved when the span closed
+            name = path.rpartition("/")[2]
+            if name in _PHASE_SET:
+                self._charge(rec, name, seconds)
         # Top SQL attribution (obs/profiler.py): register this thread
         # as a statement context — two dict writes; the digest is
         # computed lazily by the SAMPLER thread, never here, so the
@@ -235,6 +434,11 @@ class FlightRecorder:
         if rec is None:
             return None
         rec.duration_s = float(duration_s)
+        t1 = time.perf_counter()
+        trip = getattr(self._tls, "trip", None)
+        rec.background = self._ticks_beside(
+            trip.t0 if trip is not None else t1 - rec.duration_s, t1
+        )
         _c_queries().inc()
         _h_query_seconds().observe(rec.duration_s)
         with self._lock:
@@ -261,6 +465,52 @@ class FlightRecorder:
         per-digest means)."""
         self._tls.rec = None
         profiler.end_task()
+
+    # -- spans ---------------------------------------------------------
+    def span(self, name: str) -> _Span:
+        """One boundary, one call: a context manager that times the
+        block as the DECLARED span ``name`` nested in the span open on
+        this thread (module docstring). Undeclared names raise, as
+        ``note_phase`` does."""
+        if name not in _SPAN_SET:
+            raise ValueError(
+                f"undeclared flight span {name!r} (declare it in "
+                "tidb_tpu/obs/flight.py SPANS)"
+            )
+        return _Span(self, name)
+
+    def background(self, name: str) -> _Tick:
+        """A context manager around one tick of the background loop
+        ``name`` (its thread's name)."""
+        return _Tick(self, name)
+
+    def trace_into(self, tracer) -> None:
+        """``TRACE``: the spans this thread closes from here on also
+        land in ``tracer`` (None detaches), depths counted from the
+        span open now."""
+        trip = getattr(self._tls, "trip", None)
+        if trip is not None:
+            trip.tracer = tracer
+            trip.tracer_depth = len(trip.stack)
+
+    def _ticks_beside(self, t0: float, t1: float) -> List[list]:
+        """[name, seconds] of the background ticks inside (t0, t1)."""
+        if not self._ticks and not self._ticks_open:
+            return []
+        beside: Dict[str, float] = {}
+        for tick in list(self._ticks_open.values()):
+            if tick.t0 < t1:
+                beside[tick.name] = (
+                    beside.get(tick.name, 0.0) + t1 - max(tick.t0, t0)
+                )
+        for name, start, end in reversed(list(self._ticks)):
+            if end <= t0:
+                break  # closing order: every earlier tick ended before
+            if start < t1:
+                beside[name] = (
+                    beside.get(name, 0.0) + min(end, t1) - max(start, t0)
+                )
+        return [[name, seconds] for name, seconds in beside.items()]
 
     def set_live_phase(self, name: str) -> Optional[str]:
         """Mark the phase the current flight's thread is ENTERING
@@ -300,8 +550,15 @@ class FlightRecorder:
             )
         _c_phase_seconds().labels(phase=name).inc(max(float(seconds), 0.0))
         rec = self.current()
-        if rec is None:
-            return
+        if rec is not None:
+            self._charge(rec, name, seconds, nbytes, retries)
+
+    @staticmethod
+    def _charge(
+        rec: QueryFlight, name: str, seconds: float, nbytes: int = 0,
+        retries: int = 0,
+    ) -> None:
+        """The flight's half of ``note_phase``."""
         row = rec.phase_row(name)
         row[0] += max(float(seconds), 0.0)
         row[1] += int(nbytes)
@@ -427,6 +684,12 @@ class FlightRecorder:
                 "compile_bytes_accessed": r.compile_bytes_accessed,
                 "compile_output_bytes": r.compile_output_bytes,
                 "plan_captured": bool(r.plan_text),
+                "spans": [
+                    {"name": p, "start_s": at, "seconds": sec}
+                    for p, at, sec in list(r.spans)
+                ],
+                "served_s": r.served_s,
+                "background": [list(b) for b in r.background],
             }
             for r in recs
         ]

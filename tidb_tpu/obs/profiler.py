@@ -928,9 +928,12 @@ class TopSqlProfiler:
         # loops on ITS OWN stop event (captured at start): retune
         # replaces self._stop for the next thread — the heartbeat
         # loop's rationale in parallel/dcn.py
+        from tidb_tpu.obs.flight import FLIGHT
+
         while not stop.wait(interval_s):
             try:
-                self.sample_once()
+                with FLIGHT.background("obs-topsql-sampler"):
+                    self.sample_once()
             except Exception:
                 pass  # the profiler must never take the engine down
 

@@ -461,9 +461,12 @@ class TsdbSampler:
         # loops on ITS OWN stop event (captured at start): retune
         # replaces self._stop for the next thread — see the heartbeat
         # loop's rationale in parallel/dcn.py
+        from tidb_tpu.obs.flight import FLIGHT
+
         while not stop.wait(interval_s):
             try:
-                self.sample_once()
+                with FLIGHT.background("obs-tsdb-sampler"):
+                    self.sample_once()
             except Exception:
                 pass
 

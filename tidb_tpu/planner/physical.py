@@ -45,6 +45,8 @@ from tidb_tpu.executor import (
 from tidb_tpu.executor.aggregate import WIDTH_STALE as _WIDTH_STALE
 from tidb_tpu.expression import compile_expr
 from tidb_tpu.expression.expr import ColumnRef, Expr
+from tidb_tpu.obs.engine_watch import ENGINE_WATCH, watched_jit
+from tidb_tpu.obs.flight import FLIGHT
 from tidb_tpu.planner import logical as L
 from tidb_tpu.storage import scan_table
 from tidb_tpu.utils import racecheck
@@ -169,19 +171,16 @@ class CompiledQuery:
     staged_sites: List[Tuple[int, str]] = dataclasses.field(
         default_factory=list
     )
-    # steady state:
-    jitted: Optional[Callable] = None
+    # steady state: the last discovered caps, a warm-start hint for
+    # discovery
     caps: Optional[Dict[int, int]] = None
-    input_shape_key: Optional[tuple] = None
     # the CONSISTENT steady snapshot: (jitted, caps, input_shape_key)
     # published as ONE atomic tuple after the post-discovery
     # verification run passes. Concurrent executors sharing this cq
-    # (the cross-session plan cache) read the tuple, never the three
-    # loose fields above — a reader pairing thread A's program with
-    # thread B's caps could accept a silently-truncated output (the
-    # program's true cardinalities are checked against the caps IT was
-    # compiled for). The loose fields stay as a warm-start hint for
-    # discovery and for the profiling scripts.
+    # (the cross-session plan cache) read the tuple, never the loose
+    # hint above — a reader pairing thread A's program with thread B's
+    # caps could accept a silently-truncated output (the program's true
+    # cardinalities are checked against the caps IT was compiled for).
     steady: Optional[tuple] = None
     # set when a post-shrink steady run overflowed (e.g. a probe chain no
     # longer fit the smaller hash table): discovery stops shrinking caps
@@ -1017,10 +1016,12 @@ class PlanCompiler:
                 self._tag = tag
                 return fn, dicts
         nid = self.fresh_id()
-        self.node_labels.append((nid, self._depth, _node_label(plan)))
+        label = _node_label(plan)
+        self.node_labels.append((nid, self._depth, label))
         self._depth += 1
         fn, dicts = self._build_node(plan)
         self._depth -= 1
+        fn = _scoped(scope_name(label, nid), fn)
         if self.instrument:
             fn = self._wrap(nid, fn)
         if fp is not None:
@@ -2469,8 +2470,6 @@ class PhysicalExecutor:
         for nid, cap in caps.items():
             ws += 2 * cap * cq.widths.get(nid, 64)
         self.last_working_set = ws
-        from tidb_tpu.obs.engine_watch import ENGINE_WATCH
-
         ENGINE_WATCH.note_device_mem(ws)
         if not quota:
             return
@@ -2550,8 +2549,6 @@ class PhysicalExecutor:
             self._admit(cq, inputs, caps)
             frozen = dict(caps)
             if jit:
-                from tidb_tpu.obs.engine_watch import watched_jit
-
                 jitted = watched_jit(
                     self._make_program(cq, frozen), sig=("discover", cq.sig)
                 )
@@ -2559,8 +2556,13 @@ class PhysicalExecutor:
                 # eager single-device path (EXPLAIN ANALYZE instrumentation)
                 fn = cq.fn
                 jitted = lambda i, _p, _f=fn, _c=frozen: _f(i, _c)
-            out, needs = jitted(inputs, self._params())
-            needs_host = jax.device_get(needs)
+            with FLIGHT.span("dispatch"):
+                out, needs = jitted(inputs, self._params())
+                _enqueue_fetch(needs)
+            with FLIGHT.span("device-wait"):
+                jax.block_until_ready((needs, out))
+            with FLIGHT.span("fetch"):
+                needs_host = jax.device_get(needs)
             bumped = False
             for nid, true_n in needs_host.items():
                 n = int(true_n)
@@ -2644,8 +2646,6 @@ class PhysicalExecutor:
                         self._cache[key] = cq
                 # flight recorder: plan-cache outcome + plan digest for
                 # the statements_summary attribution (obs/flight.py)
-                from tidb_tpu.obs.flight import FLIGHT
-
                 FLIGHT.note_plan_cache(cq is not None, key=key)
                 if cq is not None:
                     self._cache.move_to_end(key)
@@ -2717,10 +2717,11 @@ class PhysicalExecutor:
         self, cq: CompiledQuery, pins, staged=None
     ) -> Tuple[Batch, Dicts]:
         resolved = {}
-        inputs = self._fetch_inputs(
-            cq, mesh=self.mesh, pins=pins, resolved=resolved,
-            staged=staged,
-        )
+        with FLIGHT.span("inputs"):
+            inputs = self._fetch_inputs(
+                cq, mesh=self.mesh, pins=pins, resolved=resolved,
+                staged=staged,
+            )
         # compile-time NULL-free assumptions: columns whose validity mask
         # was folded away must still be NULL-free at the fetched version
         # (host-side O(1) after the table's per-version cache warms)
@@ -2738,8 +2739,6 @@ class PhysicalExecutor:
                 raise StaleWidthsError()
         shape_key = tuple(sorted((nid, b.capacity) for nid, b in inputs.items()))
 
-        from tidb_tpu.obs.engine_watch import ENGINE_WATCH, watched_jit
-
         # the steady snapshot is read as ONE tuple: under the shared
         # cross-session plan cache, another executor may republish it
         # concurrently, and a (program, caps) pair from two different
@@ -2747,19 +2746,15 @@ class PhysicalExecutor:
         st = cq.steady
         if st is not None and st[2] == shape_key:
             st_jitted, st_caps, _sk = st
-            out, needs = st_jitted(inputs, self._params())
-            # ONE device->host round trip: output batch + cardinality
-            # scalars together. Also warms each array's host-value cache so
-            # the session's materialization re-reads are free.
-            needs_host = jax.device_get((needs, out))[0]
-            ENGINE_WATCH.d2h_batch(out)
+            out, needs_host = _launch_and_fetch(
+                st_jitted, inputs, self._params()
+            )
             if not _overflowed(needs_host, st_caps):
                 return out, cq.out_dicts
             # data grew past a tile: rediscover (drop the snapshot only
             # if it is still the one that overflowed)
             if cq.steady is st:
                 cq.steady = None
-                cq.jitted = None
 
         for _attempt in range(8):
             out, caps = self._discover(cq, inputs)
@@ -2777,14 +2772,11 @@ class PhysicalExecutor:
             )
             # compile + run the steady program now so every later run is a
             # single launch + single fetch
-            out, needs = jitted(inputs, self._params())
-            needs_host = jax.device_get((needs, out))[0]
-            ENGINE_WATCH.d2h_batch(out)
+            out, needs_host = _launch_and_fetch(
+                jitted, inputs, self._params()
+            )
             if not _overflowed(needs_host, full_caps):
                 # verified: publish the consistent snapshot atomically
-                # (plus the loose fields for the profiling scripts)
-                cq.jitted = jitted
-                cq.input_shape_key = shape_key
                 cq.steady = (jitted, full_caps, shape_key)
                 return out, cq.out_dicts
             # the post-shrink steady run overflowed: stop shrinking this
@@ -3053,6 +3045,36 @@ def _steady_step(program, out_cap, inputs, params=None, mesh=None):
     return out, needs
 
 
+def _enqueue_fetch(tree) -> None:
+    """Queue the device->host copies of ``tree`` behind the program
+    that produces it, as ``jax.device_get`` does on entry. Waiting for
+    the program first and asking for the copies afterwards puts a host
+    round trip between the program's end and the transfer's start:
+    1 ms a statement, 1 % of the SF10 scan cell's rate (PERF.md, PR 27)."""
+    for leaf in jax.tree_util.tree_leaves(tree):
+        leaf.copy_to_host_async()
+
+
+def _launch_and_fetch(jitted, inputs, params):
+    """One launch, one fetch, each boundary its own span: ``dispatch``
+    until the jitted call returns (the enqueue, of the program and of
+    the copies of what it will produce; a first call traces and
+    compiles inside it), ``device-wait`` until the program has
+    finished, ``fetch`` for the ONE device->host round trip of output
+    batch + cardinality scalars together, which also warms each
+    array's host-value cache so the session's materialization re-reads
+    are free. Returns (out, needs on the host)."""
+    with FLIGHT.span("dispatch"):
+        out, needs = jitted(inputs, params)
+        _enqueue_fetch((needs, out))
+    with FLIGHT.span("device-wait"):
+        jax.block_until_ready((needs, out))
+    with FLIGHT.span("fetch"):
+        needs_host = jax.device_get((needs, out))[0]
+        ENGINE_WATCH.d2h_batch(out)
+    return out, needs_host
+
+
 def _overflowed(needs_host: Dict[int, np.ndarray], caps: Dict[int, int]) -> bool:
     for nid, true_n in needs_host.items():
         cap = caps.get(nid, 0)
@@ -3089,6 +3111,22 @@ def _join_default(inputs, cq) -> int:
 # ---------------------------------------------------------------------------
 # shared helpers (also used by PlanCompiler)
 # ---------------------------------------------------------------------------
+
+
+def scope_name(label: str, nid: int) -> str:
+    """The name an operator's HLO ops carry on the device (``op_name``
+    holds the stack of them, innermost last): what EXPLAIN prints for
+    the node, cut to a length a trace viewer shows and kept free of the
+    stack's own separator, then ``#`` and the node id."""
+    return f"{label[:64].replace('/', '|')}#{nid}"
+
+
+def _scoped(scope: str, fn: PlanFn) -> PlanFn:
+    def scoped(inputs, caps):
+        with jax.named_scope(scope):
+            return fn(inputs, caps)
+
+    return scoped
 
 
 def _node_label(plan: L.LogicalPlan) -> str:

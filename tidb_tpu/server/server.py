@@ -14,6 +14,7 @@ import socketserver
 import threading
 from typing import Optional
 
+from tidb_tpu.obs.flight import FLIGHT
 from tidb_tpu.server import protocol as P
 from tidb_tpu.session import Result, Session
 from tidb_tpu.storage import Catalog
@@ -179,9 +180,13 @@ class Server:
                 elif cmd == COM_QUERY:
                     from tidb_tpu.utils.failpoint import inject
 
-                    inject("server/dispatch-query")
-                    sql = payload.decode("utf-8", "replace")
-                    self._run_query(io, sess, sql)
+                    # the served statement's root span: the command
+                    # packet is read, until the answer's last packet
+                    # is written (obs/flight.py SPANS)
+                    with FLIGHT.span("stmt"):
+                        inject("server/dispatch-query")
+                        sql = payload.decode("utf-8", "replace")
+                        self._run_query(io, sess, sql)
                 elif cmd == COM_FIELD_LIST:
                     io.write_packet(P.eof_packet())
                 elif cmd == COM_STMT_PREPARE:
@@ -203,37 +208,43 @@ class Server:
                 elif cmd == COM_STMT_EXECUTE:
                     import struct as _st
 
-                    sid = _st.unpack_from("<I", payload, 0)[0]
-                    if sid not in stmts:
-                        io.write_packet(P.err_packet(1243, "unknown stmt"))
-                        continue
-                    sql, nparams, ptypes = stmts[sid][:3]
-                    _sid, params, ptypes = P.parse_stmt_execute(
-                        payload, nparams, ptypes
-                    )
-                    stmts[sid][2] = ptypes
-                    r = sess.execute_prepared(f"__c{sid}", params)
-                    # CURSOR_TYPE_READ_ONLY: buffer the resultset and
-                    # answer column defs only; rows stream through
-                    # COM_STMT_FETCH (reference conn_stmt.go:153
-                    # useCursor — JDBC setFetchSize & BI tools)
-                    flags = payload[4] if len(payload) > 4 else 0
-                    if (flags & P.CURSOR_TYPE_READ_ONLY) and r.columns:
-                        types = (
-                            getattr(r, "types", None)
-                            or [None] * len(r.columns)
+                    # a root span like COM_QUERY's: the spans below it
+                    # (session, or the prepared fast path's launch) hang
+                    # from one statement's tree
+                    with FLIGHT.span("stmt"):
+                        sid = _st.unpack_from("<I", payload, 0)[0]
+                        if sid not in stmts:
+                            io.write_packet(
+                                P.err_packet(1243, "unknown stmt")
+                            )
+                            continue
+                        sql, nparams, ptypes = stmts[sid][:3]
+                        _sid, params, ptypes = P.parse_stmt_execute(
+                            payload, nparams, ptypes
                         )
-                        while len(stmts[sid]) < 4:
-                            stmts[sid].append(None)
-                        stmts[sid][3] = [list(r.rows), types, 0]
-                        io.write_packet(P.lenenc_int(len(r.columns)))
-                        for name, t in zip(r.columns, types):
-                            io.write_packet(P.column_def(name, t))
-                        io.write_packet(
-                            P.eof_packet(P.SERVER_STATUS_CURSOR_EXISTS)
-                        )
-                    else:
-                        self._write_result(io, r, binary=True, sess=sess)
+                        stmts[sid][2] = ptypes
+                        r = sess.execute_prepared(f"__c{sid}", params)
+                        # CURSOR_TYPE_READ_ONLY: buffer the resultset and
+                        # answer column defs only; rows stream through
+                        # COM_STMT_FETCH (reference conn_stmt.go:153
+                        # useCursor — JDBC setFetchSize & BI tools)
+                        flags = payload[4] if len(payload) > 4 else 0
+                        if (flags & P.CURSOR_TYPE_READ_ONLY) and r.columns:
+                            types = (
+                                getattr(r, "types", None)
+                                or [None] * len(r.columns)
+                            )
+                            while len(stmts[sid]) < 4:
+                                stmts[sid].append(None)
+                            stmts[sid][3] = [list(r.rows), types, 0]
+                            io.write_packet(P.lenenc_int(len(r.columns)))
+                            for name, t in zip(r.columns, types):
+                                io.write_packet(P.column_def(name, t))
+                            io.write_packet(
+                                P.eof_packet(P.SERVER_STATUS_CURSOR_EXISTS)
+                            )
+                        else:
+                            self._write_result(io, r, binary=True, sess=sess)
                 elif cmd == COM_STMT_FETCH:
                     import struct as _st
 
@@ -302,6 +313,10 @@ class Server:
     def _write_result(
         self, io: P.PacketIO, r, binary: bool = False, sess=None
     ) -> None:
+        with FLIGHT.span("wire/write"):
+            self._write_packets(io, r, binary, sess)
+
+    def _write_packets(self, io: P.PacketIO, r, binary, sess) -> None:
         if not r.columns:
             io.write_packet(
                 P.ok_packet(

@@ -1314,40 +1314,36 @@ class Session(DDLMixin):
             raise ConnectionError(
                 f"connection {self.conn_id} was killed"
             )
-        t_parse = time.perf_counter()
-        stmts = parse(sql)
-        parse_s = time.perf_counter() - t_parse
-        if getattr(self, "_stmt_depth", 0) == 0:
-            # the parse wall belongs to the first statement's flight
-            # (the batch parses once); _execute_stmt charges+clears it
-            self._pending_parse_s = parse_s
-        else:
-            # nested execute (prepared-statement rebind): the current
-            # statement's flight is already open — charge it directly
-            # instead of leaking the wall to the NEXT top-level flight
-            from tidb_tpu.obs.flight import FLIGHT as _FLIGHT
+        from tidb_tpu.obs.flight import FLIGHT
 
-            _FLIGHT.note_phase("parse", parse_s)
-        res = Result([], [])
-        for s in stmts:
-            if len(stmts) == 1:
-                # per-statement text; multi-statement batches fall back
-                # to AST-type digests rather than mis-attributing the
-                # whole batch text to each statement
+        # one span from entry to return: the batch parses once, before
+        # its first statement's flight begins, and FLIGHT.begin adopts
+        # what closed before it (a nested execute, the prepared-
+        # statement rebind, charges the flight that is already open)
+        with FLIGHT.span("session"):
+            with FLIGHT.span("parse"):
+                stmts = parse(sql)
+            res = Result([], [])
+            for s in stmts:
+                if len(stmts) == 1:
+                    # per-statement text; multi-statement batches fall
+                    # back to AST-type digests rather than
+                    # mis-attributing the whole batch text to each
+                    try:
+                        s._source_sql = sql
+                    except Exception:
+                        pass
                 try:
-                    s._source_sql = sql
+                    res = self._execute_stmt(s)
                 except Exception:
-                    pass
-            try:
-                res = self._execute_stmt(s)
-            except Exception:
-                from tidb_tpu.utils.metrics import REGISTRY
+                    from tidb_tpu.utils.metrics import REGISTRY
 
-                REGISTRY.counter(
-                    "tidbtpu_session_statement_errors_total", "failed statements"
-                ).inc()
-                raise
-        return res
+                    REGISTRY.counter(
+                        "tidbtpu_session_statement_errors_total",
+                        "failed statements",
+                    ).inc()
+                    raise
+            return res
 
     # test-kit style helpers (reference pkg/testkit/testkit.go:144,167)
     def must_exec(self, sql: str) -> Result:
@@ -1385,14 +1381,10 @@ class Session(DDLMixin):
 
             ENGINE_WATCH.begin_query(self._current_stmt[0])
             # flight recorder: always-on per-statement phase timeline
-            # (obs/flight.py); the batch's parse wall charges here
+            # (obs/flight.py); begin adopts the batch's parse span
             from tidb_tpu.obs.flight import FLIGHT
 
             FLIGHT.begin(self._current_stmt[0], self.conn_id)
-            parse_s = getattr(self, "_pending_parse_s", 0.0)
-            if parse_s:
-                self._pending_parse_s = 0.0
-                FLIGHT.note_phase("parse", parse_s)
             from tidb_tpu.utils import sqlkiller as _sk
 
             # host-side blocking builtins (SLEEP) poll this session's
@@ -3125,12 +3117,16 @@ class Session(DDLMixin):
             self.deallocate(s.name)
             r = Result([], [])
         elif isinstance(s, ast.Trace):
+            from tidb_tpu.obs.flight import FLIGHT
+
+            # the inner statement's FLIGHT.span calls feed the tracer
             self.tracer.enabled = True
             self.tracer.reset()
+            FLIGHT.trace_into(self.tracer)
             try:
-                with self.tracer.span("execute"):
-                    self._execute_stmt(s.stmt)
+                self._execute_stmt(s.stmt)
             finally:
+                FLIGHT.trace_into(None)
                 self.tracer.enabled = False
             r = Result(["operation", "startTS", "duration"], self.tracer.rows())
         elif isinstance(s, ast.TxnControl):
@@ -3163,75 +3159,80 @@ class Session(DDLMixin):
             sql_digest,
         )
 
-        REGISTRY.counter(
-            "tidbtpu_session_statements_total", "statements executed"
-        ).inc()
-        REGISTRY.histogram(
-            "tidbtpu_session_query_duration_seconds", "statement latency"
-        ).observe(elapsed_s)
-        sql = getattr(s, "_source_sql", None) or type(s).__name__
-        FLIGHT.note_engine(ENGINE_WATCH.current())
-        if result is not None:
-            FLIGHT.note_rows_sent(len(result.rows))
-        flight = FLIGHT.finish(elapsed_s)
-        digest = sql_digest(sql)  # computed ONCE for both stores
-        STMT_SUMMARY.record(sql, elapsed_s, flight=flight, digest=digest)
-        # Top SQL digest->text meta (obs/profiler.py): the sampler
-        # attributes by 16-hex id; this makes top_sql rows readable.
-        # Only while the profiler runs — the meta map must not grow
-        # on an unprofiled fleet.
-        from tidb_tpu.obs import profiler as _topsql
+        with FLIGHT.span("observe"):
+            REGISTRY.counter(
+                "tidbtpu_session_statements_total", "statements executed"
+            ).inc()
+            REGISTRY.histogram(
+                "tidbtpu_session_query_duration_seconds", "statement latency"
+            ).observe(elapsed_s)
+            sql = getattr(s, "_source_sql", None) or type(s).__name__
+            FLIGHT.note_engine(ENGINE_WATCH.current())
+            if result is not None:
+                FLIGHT.note_rows_sent(len(result.rows))
+            flight = FLIGHT.finish(elapsed_s)
+            digest = sql_digest(sql)  # computed ONCE for both stores
+            STMT_SUMMARY.record(sql, elapsed_s, flight=flight, digest=digest)
+            # Top SQL digest->text meta (obs/profiler.py): the sampler
+            # attributes by 16-hex id; this makes top_sql rows readable.
+            # Only while the profiler runs — the meta map must not grow
+            # on an unprofiled fleet.
+            from tidb_tpu.obs import profiler as _topsql
 
-        if _topsql.TOPSQL.running():
-            _topsql.note_statement_text(
-                _topsql.digest_of(digest), digest
-            )
-        # metric time-series tier: passive tick — with no background
-        # sampler armed, history still accretes at statement cadence
-        # (bounded by the sampler's passive interval; a no-op when the
-        # tidb_tpu_tsdb_sample_interval_s thread owns the cadence)
-        from tidb_tpu.obs.tsdb import SAMPLER
+            if _topsql.TOPSQL.running():
+                _topsql.note_statement_text(
+                    _topsql.digest_of(digest), digest
+                )
+            # metric time-series tier: passive tick — with no background
+            # sampler armed, history still accretes at statement cadence
+            # (bounded by the sampler's passive interval; a no-op when the
+            # tidb_tpu_tsdb_sample_interval_s thread owns the cadence)
+            from tidb_tpu.obs.tsdb import SAMPLER
 
-        try:
-            SAMPLER.maybe_sample()
-        except Exception:
-            pass  # sampling must never fail the statement
-        # slow log: threshold from the sysvar registry (no hardcoded
-        # fallback — SYSVAR_DEFS owns the default), gated on the
-        # slow_query_log on/off switch like the reference
-        try:
-            if not bool(self.vars.get("slow_query_log")):
+            try:
+                SAMPLER.maybe_sample()
+            except Exception:
+                pass  # sampling must never fail the statement
+            # slow log: threshold from the sysvar registry (no hardcoded
+            # fallback — SYSVAR_DEFS owns the default), gated on the
+            # slow_query_log on/off switch like the reference
+            try:
+                if not bool(self.vars.get("slow_query_log")):
+                    return
+                thresh_ms = int(self.vars.get("tidb_slow_log_threshold"))
+            except Exception:
                 return
-            thresh_ms = int(self.vars.get("tidb_slow_log_threshold"))
-        except Exception:
-            return
-        if elapsed_s * 1000.0 < thresh_ms:  # 0 = log everything
-            return
-        phases = ""
-        plan_text = ""
-        if flight is not None:
-            phases = " ".join(
-                f"{p}={sec * 1e3:.3f}ms" for p, sec, _b, _r
-                in flight.timeline()
-            )
-            # tidb_record_plan_in_slow_log gates EVERY capture path,
-            # including the instrumented lines an EXPLAIN ANALYZE
-            # already stashed on the flight
-            if self._record_plan_in_slow_log():
-                plan_text = flight.plan_text or self._capture_slow_plan(s)
-            flight.plan_text = plan_text
-            if plan_text:
-                from tidb_tpu.obs.flight import _c_slow_captures
+            if elapsed_s * 1000.0 < thresh_ms:  # 0 = log everything
+                return
+            phases = ""
+            plan_text = ""
+            if flight is not None:
+                # after the phases, the background ticks that ran
+                # beside the statement (obs/flight.py FLIGHT.background)
+                phases = " ".join(
+                    [f"{p}={sec * 1e3:.3f}ms" for p, sec, _b, _r
+                     in flight.timeline()]
+                    + [f"beside:{name}={sec * 1e3:.3f}ms"
+                       for name, sec in flight.background]
+                )
+                # tidb_record_plan_in_slow_log gates EVERY capture path,
+                # including the instrumented lines an EXPLAIN ANALYZE
+                # already stashed on the flight
+                if self._record_plan_in_slow_log():
+                    plan_text = flight.plan_text or self._capture_slow_plan(s)
+                flight.plan_text = plan_text
+                if plan_text:
+                    from tidb_tpu.obs.flight import _c_slow_captures
 
-                _c_slow_captures().inc()
-        SLOW_LOG.record(
-            sql, elapsed_s,
-            digest=digest,
-            conn_id=self.conn_id,
-            phases=phases,
-            plan=plan_text,
-            log_file=self._slow_log_file(),
-        )
+                    _c_slow_captures().inc()
+            SLOW_LOG.record(
+                sql, elapsed_s,
+                digest=digest,
+                conn_id=self.conn_id,
+                phases=phases,
+                plan=plan_text,
+                log_file=self._slow_log_file(),
+            )
 
     def _record_plan_in_slow_log(self) -> bool:
         try:
@@ -4100,12 +4101,8 @@ class Session(DDLMixin):
         try:
             # spans mirror the reference's (session.ExecuteStmt ->
             # Compiler.Compile -> distsql.Select, pkg/util/tracing/util.go:21)
-            t_plan = time.perf_counter()
-            FLIGHT.set_live_phase("plan")
-            with self.tracer.span("session.plan"):
+            with FLIGHT.span("plan"):
                 plan = build_query(s, self.catalog, self.db, self._scalar_subquery, ctes)
-            FLIGHT.set_live_phase("execute")
-            FLIGHT.note_phase("plan", time.perf_counter() - t_plan)
             self._last_plan = plan  # prepared-statement plan capture
             # _source_sql is set only for single-statement texts: a
             # batch's statements would otherwise share one fallback
@@ -4116,32 +4113,13 @@ class Session(DDLMixin):
             )
             if routed is not None:
                 return routed
-            # the execute wall contains any jit traces watched_jit
-            # charges to "compile" — subtract them so the two phases
-            # stay additive (a first-run statement must not read as
-            # simultaneously compile-bound AND execute-bound)
-            t_exec = time.perf_counter()
-            c0 = FLIGHT.phase_seconds("compile")
-            with self.tracer.span("executor.run"):
+            with FLIGHT.span("execute"):
                 hs = self._try_host_sorted(plan)
                 if hs is not None:
-                    FLIGHT.note_phase(
-                        "execute",
-                        (time.perf_counter() - t_exec)
-                        - (FLIGHT.phase_seconds("compile") - c0),
-                    )
                     return hs
                 batch, dicts = self.executor.run(plan)
-            FLIGHT.note_phase(
-                "execute",
-                (time.perf_counter() - t_exec)
-                - (FLIGHT.phase_seconds("compile") - c0),
-            )
-            t_mat = time.perf_counter()
-            FLIGHT.set_live_phase("final-merge")
-            with self.tracer.span("session.materialize"):
+            with FLIGHT.span("final-merge"):
                 rows = materialize_rows(batch, list(plan.schema), dicts)
-            FLIGHT.note_phase("final-merge", time.perf_counter() - t_mat)
             names = [c.name for c in plan.schema]
             return Result(names, rows, types=[c.type for c in plan.schema])
         finally:

@@ -95,8 +95,11 @@ class StatsHandle:
         self._stop.clear()  # restartable after stop()
 
         def loop():
+            from tidb_tpu.obs.flight import FLIGHT
+
             while not self._stop.wait(self.interval_s):
-                self.tick()
+                with FLIGHT.background("stats-auto-analyze"):
+                    self.tick()
 
         self._thread = threading.Thread(
             target=loop, name="stats-auto-analyze", daemon=True

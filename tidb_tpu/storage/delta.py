@@ -1376,9 +1376,12 @@ class DeltaCompactor:
         self._stop.clear()
 
         def loop():
+            from tidb_tpu.obs.flight import FLIGHT
+
             while not self._stop.wait(self.interval_s):
                 try:
-                    self.tick()
+                    with FLIGHT.background("delta-compactor"):
+                        self.tick()
                 except Exception:
                     continue  # compaction must never kill the daemon
 

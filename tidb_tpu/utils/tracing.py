@@ -5,6 +5,11 @@ session.ExecuteStmt, Compiler.Compile, distsql.Select, rendered by
 TRACE SELECT, pkg/executor/trace.go). Here: a per-session Tracer records
 (name, start, duration, depth); the session opens spans around parse /
 plan / execute / materialize, and `TRACE <select>` returns them as rows.
+
+Since PR 27 the session's boundaries are timed once, by
+``FLIGHT.span`` (obs/flight.py), which feeds the session's Tracer
+through ``add`` while ``TRACE`` has it enabled; ``span`` stays for the
+fleet's worker-side tracers (server/engine_rpc.py, parallel/shuffle.py).
 """
 
 from __future__ import annotations
@@ -59,6 +64,11 @@ class Tracer:
                 Span(name, start - self._t0, time.perf_counter() - start, depth)
             )
 
+    def add(self, name: str, start: float, dur_s: float, depth: int) -> None:
+        """One closed span timed elsewhere (obs/flight.py FLIGHT.span):
+        ``start`` is on ``perf_counter``, like the zero ``reset`` took."""
+        self.spans.append(Span(name, start - self._t0, dur_s, max(depth, 1)))
+
     def add_remote(
         self, spans, label: str, base_s: float = 0.0,
         base_depth: int = 1,
@@ -111,12 +121,10 @@ class Tracer:
         return out
 
     def totals_by_name(self) -> dict:
-        """Total duration per span name. The cross-check surface
-        between the two timing systems: a TRACE'd statement's
-        session.plan/executor.run span totals and the flight
-        recorder's plan/execute phase charges (obs/flight.py) cover
-        the same walls, so they must agree — tests/test_observability
-        asserts it."""
+        """Total duration per span name. A TRACE'd statement's plan /
+        execute rows and the flight recorder's phase charges
+        (obs/flight.py) come from the same FLIGHT.span call, so they
+        agree by construction — tests/test_observability asserts it."""
         out: dict = {}
         for s in self.spans:
             out[s.name] = out.get(s.name, 0.0) + s.dur_s

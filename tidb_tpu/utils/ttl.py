@@ -93,8 +93,11 @@ class TTLWorker:
         self._stop.clear()
 
         def loop():
+            from tidb_tpu.obs.flight import FLIGHT
+
             while not self._stop.wait(self.interval_s):
-                self.tick()
+                with FLIGHT.background("ttl-worker"):
+                    self.tick()
 
         self._thread = threading.Thread(
             target=loop, name="ttl-worker", daemon=True

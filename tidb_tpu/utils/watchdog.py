@@ -90,11 +90,13 @@ class InstanceWatchdog(threading.Thread):
         return gvar(self.catalog, name, default)
 
     def run(self) -> None:  # pragma: no cover - loop plumbing
+        from tidb_tpu.obs.flight import FLIGHT
         from tidb_tpu.utils.failpoint import FailpointError
 
         while not self.stop_flag.wait(self.interval):
             try:
-                self.sample()
+                with FLIGHT.background("watchdog-instance"):
+                    self.sample()
             except FailpointError:
                 raise  # injected faults must be observable in tests
             except Exception:
