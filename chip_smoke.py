@@ -93,6 +93,9 @@ class LoweringCounter:
         "masked_backend": ("tidb_tpu.executor.aggregate", "_masked_backend"),
         "merge_probe": ("tidb_tpu.executor.sortops", "merge_searchsorted"),
         "sorted_join_build": ("tidb_tpu.executor.join", "_sort_build"),
+        # one per unique-build join that emits a smaller tile than it
+        # probes, and one for the steady program's own output
+        "gather_compact": ("tidb_tpu.executor.sortops", "compaction_index"),
     }
 
     def __init__(self):

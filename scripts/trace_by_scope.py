@@ -13,7 +13,9 @@ Prints, for the window the device ops span:
 
 - the modules launched, by name (``jit_<kind>``, obs/engine_watch.py);
 - self time by innermost ``label#nid`` with each scope's largest ops
-  and the source line that emitted them, and who owns the custom
+  and the source line that emitted them, how much of a scope lies in
+  a named sub-scope an operator opens (``SUB_SCOPES``: a join's
+  ``/compact``, executor/join.py), and who owns the custom
   fusions (``hlo_category`` "custom fusion": XLA's scatter-shaped
   kCustom) and the ``custom-call``s;
 - how many module launches lie outside the ``execute/dispatch`` start
@@ -37,6 +39,8 @@ import re
 import sys
 
 SCOPE = re.compile(r"([^/]*#\d+)(?=/|$)")
+#: ``jax.named_scope``s operators open inside their own scope
+SUB_SCOPES = ("compact",)
 
 
 def load(path: str):
@@ -127,11 +131,16 @@ def main(argv=None) -> int:
           dict(collections.Counter(re.sub(r"\(.*", "", m[0]) for m in modules)))
 
     by_scope = collections.defaultdict(collections.Counter)
+    by_sub = collections.defaultdict(collections.Counter)
     custom = {"custom fusion": collections.Counter(),
               "custom-call": collections.Counter()}
     for (name, _s, _t, st), own in self_times(ops):
-        found = SCOPE.findall(str(st.get("tf_op", "")))
+        tf_op = str(st.get("tf_op", ""))
+        found = SCOPE.findall(tf_op)
         scope = found[-1] if found else "(no scope)"
+        for sub in SUB_SCOPES:
+            if f"{scope}/{sub}/" in tf_op:
+                by_sub[scope][sub] += own
         short = re.sub(r"\{[^{}]*\}", "", name).lstrip("%").split(" ")[0]
         where = str(st.get("source", "")).rsplit("/tidb_tpu/", 1)[-1]
         by_scope[scope][f"{short} [{st.get('hlo_category', '')}] {where}"] += own
@@ -144,6 +153,8 @@ def main(argv=None) -> int:
     for scope, per_op in sorted(by_scope.items(), key=lambda kv: -sum(kv[1].values())):
         own = sum(per_op.values())
         print(f"  {own / 1e9:9.4f} s {100 * own / total:5.1f} %  {scope}")
+        for sub, ns in by_sub[scope].most_common():
+            print(f"      {ns / 1e9:9.4f}  of it under /{sub}")
         for op, ns in per_op.most_common(args.top):
             print(f"      {ns / 1e9:9.4f}  {op}")
     for kind, owners in custom.items():

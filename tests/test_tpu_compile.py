@@ -336,6 +336,41 @@ def test_sorted_unique_lookup_q5_shape_compiles(one_chip, tpu_gates):
     _fits_v5e(compiled)
 
 
+@pytest.mark.slow
+def test_unique_join_compaction_q5_shape_compiles(one_chip, tpu_gates):
+    """Q5's largest join at SF1: 6,291,456 probe rows into the
+    discovered 2,097,152-row tile, four output columns. 55 s here (the
+    lookup's three-limb sorts; the compaction adds one single-limb
+    sort). The compiled program holds no scatter: the index is a sort,
+    the columns move by gathers of the output tile's rows."""
+    from tidb_tpu.chunk import Batch, pad_capacity
+    from tidb_tpu.executor.join import equi_join
+
+    orders = _batch(
+        {"o_orderkey": np.int64, "o_custkey": np.int64},
+        pad_capacity(ORDERS_SF1), one_chip,
+    )
+    lineitem = _batch(
+        {n: np.int64 for n in
+         ("l_orderkey", "l_suppkey", "l_extendedprice", "l_discount")},
+        pad_capacity(LINEITEM_SF1), one_chip,
+    )
+    keep = ("l_suppkey", "l_extendedprice", "l_discount", "o_custkey")
+
+    def join(b, p):
+        out, total = equi_join(
+            b, p, lambda x: x.cols["o_orderkey"], lambda x: x.cols["l_orderkey"],
+            1 << 21, "inner", build_unique=True,
+        )
+        return Batch({n: out.cols[n] for n in keep}, out.row_valid), total
+
+    compiled, _s = _compile(join, orders, lineitem)
+    _fits_v5e(compiled)
+    text = compiled.as_text()
+    assert "scatter" not in text
+    assert text.count("/compact/gather") >= 3 * len(keep)
+
+
 # ---------------------------------------------------------------------------
 # four devices: the mesh repartition join carries an all-to-all
 # ---------------------------------------------------------------------------

@@ -296,6 +296,158 @@ def test_sorted_unique_lookup_matches_dense(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# unique-build join into an output tile smaller than the probe tile:
+# one compaction index, then gathers (PR 28)
+# ---------------------------------------------------------------------------
+
+_PCAP, _OCAP = 4096, 1024
+
+
+def _unique_join_sides(join_type, fill):
+    """(build, probe, expected rows in probe order). NULL keys on both
+    sides, NULL values, invalid probe rows scattered through the tile,
+    a 64-bit and a bool column a side; the probe's row_valid is thinned
+    until the join emits exactly the count `fill` asks for."""
+    from tidb_tpu.dtypes import BOOL
+
+    rng = np.random.default_rng(28)
+    nb, npr = 2000, 4000
+    bk = rng.permutation(3000)[:nb].tolist()
+    for i in rng.choice(nb, 20, replace=False):
+        bk[i] = None
+    bv = [None if rng.random() < 0.1 else int(x)
+          for x in rng.integers(-(1 << 62), 1 << 62, nb)]
+    bf = [None if rng.random() < 0.1 else bool(x) for x in rng.integers(0, 2, nb)]
+    pk = [None if rng.random() < 0.05 else int(x)
+          for x in rng.integers(-5, 3100, npr)]
+    pv = [None if rng.random() < 0.1 else int(x)
+          for x in rng.integers(-(1 << 62), 1 << 62, npr)]
+    pb = [None if rng.random() < 0.1 else bool(x) for x in rng.integers(0, 2, npr)]
+    by_key = {k: i for i, k in enumerate(bk) if k is not None}
+
+    def emits(i):
+        return join_type == "left" or by_key.get(pk[i]) is not None
+
+    alive = rng.random(npr) < 0.9
+    want_n = {"under": 700, "full": _OCAP, "over": 1500}[fill]
+    emitting = [i for i in range(npr) if alive[i] and emits(i)]
+    assert len(emitting) >= want_n, (join_type, len(emitting))
+    for i in rng.permutation(emitting)[: len(emitting) - want_n]:
+        alive[i] = False
+
+    expected = []
+    for i in np.nonzero(alive)[0]:
+        j = by_key.get(pk[i])
+        if j is None and join_type == "inner":
+            continue
+        build_vals = (None, None, None) if j is None else (bk[j], bv[j], bf[j])
+        expected.append((pk[i], pv[i], pb[i]) + build_vals)
+    assert len(expected) == want_n
+
+    build = _mk({"bk": (bk, INT64), "bv": (bv, INT64), "bf": (bf, BOOL)}, 2048)
+    probe = _mk({"pk": (pk, INT64), "pv": (pv, INT64), "pb": (pb, BOOL)}, _PCAP)
+    rv = np.zeros(_PCAP, dtype=bool)
+    rv[:npr] = alive
+    return build, Batch(probe.cols, jnp.asarray(rv)), expected
+
+
+@pytest.mark.parametrize("fill", ["under", "full", "over"])
+@pytest.mark.parametrize("lookup", ["dense", "sorted"])
+@pytest.mark.parametrize("join_type", ["inner", "left"])
+def test_unique_join_compacts_like_a_plain_join(join_type, lookup, fill, monkeypatch):
+    """Same rows, same (probe) order, same validity as a loop over the
+    probe rows; an overflowing tile holds the first out_capacity rows
+    and reports the true total; no column is valid past the rows."""
+    import tidb_tpu.utils.backend as backend
+    from tidb_tpu.executor.join import equi_join
+
+    build, probe, expected = _unique_join_sides(join_type, fill)
+    if lookup == "sorted":  # what the chip takes past 2**16 build rows
+        monkeypatch.setattr(backend, "_IS_TPU", True)
+    bounds = (0, 2999) if lookup == "dense" else None
+    out, total = jax.jit(
+        lambda b, p: equi_join(
+            b, p, _col("bk"), _col("pk"), _OCAP, join_type,
+            build_bounds=bounds, build_unique=True,
+        )
+    )(build, probe)
+    assert out.capacity == _OCAP and int(total) == len(expected)
+    n = min(len(expected), _OCAP)
+    assert np.asarray(out.row_valid).tolist() == [True] * n + [False] * (_OCAP - n)
+    names = ["pk", "pv", "pb", "bk", "bv", "bf"]
+    data = {c: np.asarray(out.cols[c].data) for c in names}
+    valid = {c: np.asarray(out.cols[c].valid) for c in names}
+    assert data["pv"].dtype == np.int64 and data["pb"].dtype == np.bool_
+    got = [
+        tuple(data[c][j].item() if valid[c][j] else None for c in names)
+        for j in range(n)
+    ]
+    assert got == expected[:n]
+    for c in names:
+        assert not valid[c][n:].any(), c
+
+
+def _lowered(fn, *args):
+    """(StableHLO op histogram, text with each op's scope stack)."""
+    import collections
+    import re
+
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    return collections.Counter(re.findall(r"stablehlo\.(\w+)", text)), text
+
+
+def test_unique_join_compaction_holds_no_scatter(tpu_gates):
+    """The branch pays per OUTPUT row: one sort for the index, gathers
+    for the columns. A scatter pays per probe row and the v5e runs it
+    serially (2.46 s of Q5's 3.17 s at SF1, PERF.md PR 28). Sorted
+    lookup, as the chip takes at Q5's sizes: the dense table build is a
+    scatter of its own, per BUILD row."""
+    from tidb_tpu.executor.join import equi_join
+    from tidb_tpu.utils.metrics import REGISTRY
+
+    def join(out_capacity):
+        return lambda b, p: equi_join(
+            b, p, _col("bk"), _col("pk"), out_capacity, "inner", build_unique=True
+        )
+
+    build, probe, _expected = _unique_join_sides("inner", "under")
+    engaged = REGISTRY.counter("tidbtpu_executor_join_compactions_total")
+    before = engaged.value
+    ops, text = _lowered(join(_OCAP), build, probe)
+    assert "scatter" not in ops
+    assert ops["gather"] >= 12 and "/compact/" in text  # six (data, valid) pairs
+    assert engaged.value == before + 1  # once per traced program
+    # the probe tile as it stands: nothing to compact, nothing counted
+    _lowered(join(_PCAP), build, probe)
+    assert engaged.value == before + 1
+
+
+def test_compact_impl_lowers_as_before_the_shared_index():
+    """planner/physical.py:_compact_impl ends every steady program; it
+    now takes its permutation from sortops.compaction_index and must
+    lower to the ops it lowered to when it held the sort itself."""
+    from tidb_tpu.chunk import DevCol
+    from tidb_tpu.executor.sortops import sort_rows, unpack_lex
+    from tidb_tpu.planner.physical import _compact_impl
+
+    def before(batch, out_cap):
+        ops, where, perm = sort_rows([(~batch.row_valid, 1)], batch.capacity)
+        perm = perm[:out_cap]
+        cols = {
+            n: DevCol(c.data[perm], c.valid[perm]) for n, c in batch.cols.items()
+        }
+        return Batch(cols, unpack_lex(ops, where, 0)[:out_cap] == 0)
+
+    _build, probe, _expected = _unique_join_sides("inner", "under")
+    was, _ = _lowered(lambda b: before(b, 256), probe)
+    now, _ = _lowered(lambda b: _compact_impl(b, 256), probe)
+    assert now == was and now["sort"] == 1 and "scatter" not in now
+    got, want = jax.jit(lambda b: _compact_impl(b, 256))(probe), before(probe, 256)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert (np.asarray(a) == np.asarray(b)).all()
+
+
+# ---------------------------------------------------------------------------
 # ORDER BY through the packed sort
 # ---------------------------------------------------------------------------
 

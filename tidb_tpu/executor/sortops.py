@@ -145,6 +145,18 @@ def sort_rows(comps, cap: int):
     return ops, where, unpack_lex(ops, where, len(comps) - 1).astype(jnp.int32)
 
 
+def compaction_index(valid: jax.Array, out_cap: int):
+    """(sel, filled): sel[j] is the row of the j-th True of `valid`, in
+    row order, for j < out_cap; filled[j] is False where `valid` holds
+    fewer than j + 1 Trues (sel[j] then names some invalid row). The
+    engine's one way to compact a tile: ONE single-limb sort (validity
+    bit + row id), after which every column moves by a gather of
+    out_cap rows - a scatter pays per INPUT row and the v5e runs it
+    serially (69 ns a row against a gather's 7-8, PERF.md PR 28)."""
+    ops, where, perm = sort_rows([(~valid, 1)], valid.shape[0])
+    return perm[:out_cap], unpack_lex(ops, where, 0)[:out_cap] == 0
+
+
 def int_sort_bits(d: jax.Array):
     """(unsigned order-preserving image of an integer/bool array, bits):
     the value less its dtype's minimum."""

@@ -3168,14 +3168,13 @@ def _count_valid(row_valid: jax.Array) -> jax.Array:
 def _compact_impl(batch: Batch, out_cap: int) -> Batch:
     """Stable-partition valid rows to the front and slice to out_cap —
     runs on device so only pad_capacity(true rows) transfers to host."""
-    from tidb_tpu.executor.sortops import sort_rows, unpack_lex
+    from tidb_tpu.executor.sortops import compaction_index
 
-    ops, where, perm = sort_rows([(~batch.row_valid, 1)], batch.capacity)
-    perm = perm[:out_cap]
+    sel, filled = compaction_index(batch.row_valid, out_cap)
     cols = {
-        n: DevCol(c.data[perm], c.valid[perm]) for n, c in batch.cols.items()
+        n: DevCol(c.data[sel], c.valid[sel]) for n, c in batch.cols.items()
     }
-    return Batch(cols, unpack_lex(ops, where, 0)[:out_cap] == 0)
+    return Batch(cols, filled)
 
 
 def _bound_pred_cols(e):
