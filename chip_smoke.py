@@ -12,10 +12,11 @@ acknowledged and must be read back by Q6. Before that, both Pallas
 kernels run compiled against their references (information only).
 
 `--mesh 4` (four chips, run by hand) runs ONLY the multi-chip path and
-what it is compared with: Q1, Q18, Q5 on a `Session(mesh_devices=4)`
-against a one-device `Session` in the same process and the oracle,
-shows that scanned columns hold one shard on each of four devices, and
-that the compiled repartition join contains an all-to-all.
+what it is compared with: Q1, Q18, Q5 over a socket of the server that
+`tidb_server.bootstrap` builds with `mesh_devices=4`, against a
+one-device server on the same catalog and the oracle, shows that
+scanned columns hold one shard on each of four devices, and that the
+compiled repartition join contains an all-to-all.
 
 Every line of output is one JSON object; the last is
 {"ok": true, "device": {"platform", "kind", "count"}}. Any failed check
@@ -375,28 +376,36 @@ def serve_phase(args, meter: CompileMeter, lowerings: LoweringCounter) -> None:
 
 
 def mesh_phase(args, meter: CompileMeter) -> None:
-    """Four chips: Q1/Q18/Q5 on a mesh Session vs a one-device Session
-    vs the oracle; shard placement; all-to-all in the compiled join."""
+    """Four chips: Q1/Q18/Q5 over a socket of the server that
+    `tidb_server.bootstrap` builds with `mesh_devices`, against a
+    one-device server on the same catalog and the oracle; shard
+    placement; all-to-all in the compiled join."""
     import jax
 
     import bench
+    import tidb_server
     import tidb_tpu.obs.engine_watch as EW
-    from tidb_tpu.bench import load_tpch
-    from tidb_tpu.session import Session
-    from tidb_tpu.storage import Catalog, scan_table
+    import tidb_tpu.planner.physical as PH
+    from tidb_tpu.bench.serve_load import MysqlClient
+    from tidb_tpu.parallel.mesh import shared_mesh
+    from tidb_tpu.server import Server
+    from tidb_tpu.storage import scan_table
+    from tidb_tpu.utils.config import Config
 
     n = args.mesh
-    require(len(jax.devices()) >= n, (len(jax.devices()), n))
-    cat = Catalog()
     t0 = time.perf_counter()
-    load_tpch(cat, sf=args.sf, seed=args.seed)
+    cat, meshed = tidb_server.bootstrap(
+        Config().override(port=0, mesh_devices=n), tpch_sf=args.sf, seed=args.seed
+    )
+    require(meshed.mesh_devices == n, meshed.mesh_devices)
     emit(phase="load", sf=args.sf, seed=args.seed,
          lineitem_rows=cat.table("tpch", "lineitem").nrows,
          seconds=round(time.perf_counter() - t0, 2))
+    single = Server(cat, port=0)
     oracle = Oracle(cat)
     checks = {"q1": oracle.check_q1, "q18": oracle.check_q18, "q5": oracle.check_q5}
 
-    # keep each steady program of the mesh session (callable + inputs)
+    # keep each steady program of the mesh server (callable + inputs)
     # so its compiled text can be read back after the run
     steady: dict = {}
     current = [None]
@@ -414,49 +423,55 @@ def mesh_phase(args, meter: CompileMeter) -> None:
 
         return call
 
-    EW.watched_jit = keeping_jit
-
-    def text_rows(res):
-        return [tuple(None if v is None else str(v) for v in r) for r in res.rows]
-
-    single = Session(cat, db="tpch")
-    meshed = Session(cat, db="tpch", mesh_devices=n)
-    for s in (single, meshed):
-        s.execute(f"set tidb_mem_quota_query = {64 << 30}")
-    snap, t0 = meter.snapshot(), time.perf_counter()
-    for table in TABLES:  # statistics live on the catalog: both sessions plan from them
-        single.execute(f"analyze table {table}")
-    emit(phase="mesh", statement="analyze", tables=len(TABLES),
-         seconds=round(time.perf_counter() - t0, 2), **meter.since(snap))
-    for name in ("q1", "q18", "q5"):
-        sql = bench.QUERIES[name]
-        out = {}
-        for label, sess in (("single", single), ("mesh", meshed)):
-            current[0] = name if label == "mesh" else None
-            snap = meter.snapshot()
-            t0 = time.perf_counter()
-            res = sess.execute(sql)
-            cold = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            res2 = sess.execute(sql)
-            warm = time.perf_counter() - t0
-            require(res.rows == res2.rows, (name, label, "cold != warm"))
-            out[label] = res.rows
-            checks[name](text_rows(res))
-            emit(phase="mesh", statement=name, session=label,
-                 devices=n if label == "mesh" else 1,
-                 cold_s=round(cold, 4), warm_s=round(warm, 4),
-                 rows=len(res.rows), correct=True,
-                 wide_sum_max_rel_dev=oracle.wide_dev, **meter.since(snap))
-        same_rows(name, out["mesh"], out["single"])
-    current[0] = None
-    EW.watched_jit = real_jit
+    # the executor binds the name at import, the streamed paths on use
+    EW.watched_jit = PH.watched_jit = keeping_jit
+    clients = {}
+    try:
+        for label, srv in (("single", single), ("mesh", meshed)):
+            srv.start_background()
+            clients[label] = MysqlClient(srv.port, timeout_s=1100.0)
+            clients[label].query("use tpch")
+            clients[label].query(f"set tidb_mem_quota_query = {64 << 30}")
+        snap, t0 = meter.snapshot(), time.perf_counter()
+        for table in TABLES:  # statistics live on the catalog: both servers plan from them
+            clients["single"].query(f"analyze table {table}")
+        emit(phase="mesh", statement="analyze", tables=len(TABLES),
+             seconds=round(time.perf_counter() - t0, 2), **meter.since(snap))
+        for name in ("q1", "q18", "q5"):
+            sql = bench.QUERIES[name]
+            out = {}
+            for label in ("single", "mesh"):
+                current[0] = name if label == "mesh" else None
+                snap = meter.snapshot()
+                t0 = time.perf_counter()
+                rows = clients[label].query(sql)
+                cold = time.perf_counter() - t0
+                t0 = time.perf_counter()
+                rows2 = clients[label].query(sql)
+                warm = time.perf_counter() - t0
+                require(rows == rows2, (name, label, "cold != warm"))
+                out[label] = rows
+                checks[name](rows)
+                emit(phase="mesh", statement=name, server=label,
+                     devices=n if label == "mesh" else 1,
+                     cold_s=round(cold, 4), warm_s=round(warm, 4),
+                     rows=len(rows), correct=True,
+                     wide_sum_max_rel_dev=oracle.wide_dev, **meter.since(snap))
+            same_rows(name, out["mesh"], out["single"])
+    finally:
+        current[0] = None
+        EW.watched_jit = PH.watched_jit = real_jit
+        for client in clients.values():
+            client.close()
+        meshed.shutdown()
+        single.shutdown()
 
     # the work is really spread: one addressable shard of each scanned
-    # column on each of n distinct devices (the executor's scan cache)
+    # column on each of n distinct devices (the server's one mesh and
+    # its resident shards)
     batch, _d = scan_table(
         cat.table("tpch", "lineitem"), ["l_orderkey", "l_quantity"],
-        mesh=meshed.executor.mesh,
+        mesh=shared_mesh(n),
     )
     for cname, col in batch.cols.items():
         devs = sorted(str(s.device) for s in col.data.addressable_shards)
@@ -477,18 +492,18 @@ def mesh_phase(args, meter: CompileMeter) -> None:
 
 
 def same_rows(name, a, b) -> None:
-    """Mesh answer == one-device answer: exact but for float columns
-    (another summation order: relative 1e-9) and for which of several
-    tied orders Q18's LIMIT keeps (its sums must still agree)."""
+    """Mesh answer == one-device answer, as text off the wire: exact
+    but for the float-accumulated columns (another summation order:
+    relative 1e-9) and for which of several tied orders Q18's LIMIT
+    keeps (its sums must still agree)."""
     if name == "q18":
         a, b = [r[1:] for r in a], [r[1:] for r in b]
     require(len(a) == len(b), (name, len(a), len(b)))
     for ra, rb in zip(a, b):
         for x, y in zip(ra, rb):
-            if isinstance(x, float):
-                require(abs(x - y) <= 1e-9 * abs(y), (name, ra, rb))
-            else:
-                require(x == y, (name, ra, rb))
+            if x != y:
+                require(x is not None and y is not None, (name, ra, rb))
+                require(abs(float(x) - float(y)) <= 1e-9 * abs(float(y)), (name, ra, rb))
 
 
 def main() -> int:
@@ -524,6 +539,7 @@ def main() -> int:
     meter = CompileMeter()
     t0 = time.perf_counter()
     if args.mesh:
+        require(len(jax.devices()) >= args.mesh, (len(jax.devices()), args.mesh))
         mesh_phase(args, meter)
         count = args.mesh
     else:

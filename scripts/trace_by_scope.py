@@ -9,13 +9,16 @@ of the op's *event metadata*, which ``jax.profiler.ProfileData`` does
 not show: this reads the protobuf itself (TensorFlow's copy of
 ``xplane_pb2``; nothing else of TensorFlow is used).
 
-Prints, for the window the device ops span:
+Prints, for the window the device ops span (seconds are the mean over
+the devices that ran an op: the chips of a mesh run one program):
 
 - the modules launched, by name (``jit_<kind>``, obs/engine_watch.py);
 - self time by innermost ``label#nid`` with each scope's largest ops
   and the source line that emitted them, how much of a scope lies in
   a named sub-scope an operator opens (``SUB_SCOPES``: a join's
-  ``/compact``, executor/join.py), and who owns the custom
+  ``/compact``, executor/join.py; on a mesh an exchange's
+  ``/exchange/sort | pack | all-to-all`` and ``/broadcast/all-gather``,
+  parallel/exchange.py), and who owns the custom
   fusions (``hlo_category`` "custom fusion": XLA's scatter-shaped
   kCustom) and the ``custom-call``s;
 - how many module launches lie outside the ``execute/dispatch`` start
@@ -40,7 +43,12 @@ import sys
 
 SCOPE = re.compile(r"([^/]*#\d+)(?=/|$)")
 #: ``jax.named_scope``s operators open inside their own scope
-SUB_SCOPES = ("compact",)
+SUB_SCOPES = (
+    "compact",  # a join's output compaction (executor/join.py)
+    # a repartition and its stages, a broadcast (parallel/exchange.py)
+    "exchange", "exchange/sort", "exchange/pack", "exchange/all-to-all",
+    "broadcast/all-gather",
+)
 
 
 def load(path: str):
@@ -112,29 +120,34 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     space = load(args.trace)
 
-    ops, modules, notes = [], [], []
+    by_device, modules, notes = collections.defaultdict(list), [], []
     for plane in space.planes:
         for line in plane.lines:
             if plane.name.startswith("/device:TPU") and line.name == "XLA Ops":
-                ops += list(events(plane, line))
+                by_device[plane.name] += list(events(plane, line))
             elif plane.name.startswith("/device:TPU") and line.name == "XLA Modules":
                 modules += list(events(plane, line))
             elif plane.name.startswith("/host:"):
                 notes += [
                     e for e in events(plane, line) if e[0].startswith("tidbtpu/")
                 ]
+    ops = [op for dev_ops in by_device.values() for op in dev_ops]
     if not ops:
         print("no device op in the trace")
         return 1
     lo, hi = min(o[1] for o in ops), max(o[2] for o in ops)
-    print(f"device ops {len(ops)} over {(hi - lo) / 1e9:.4f} s; modules:",
+    n_dev = len(by_device)
+    print(f"device ops {len(ops)} on {n_dev} device(s) over {(hi - lo) / 1e9:.4f} s; modules:",
           dict(collections.Counter(re.sub(r"\(.*", "", m[0]) for m in modules)))
 
     by_scope = collections.defaultdict(collections.Counter)
     by_sub = collections.defaultdict(collections.Counter)
     custom = {"custom fusion": collections.Counter(),
               "custom-call": collections.Counter()}
-    for (name, _s, _t, st), own in self_times(ops):
+    # ops nest within one device's line, not across devices
+    timed = [pair for dev_ops in by_device.values() for pair in self_times(dev_ops)]
+    for (name, _s, _t, st), own in timed:
+        own /= n_dev
         tf_op = str(st.get("tf_op", ""))
         found = SCOPE.findall(tf_op)
         scope = found[-1] if found else "(no scope)"

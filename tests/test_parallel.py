@@ -84,20 +84,77 @@ class TestRepartition:
         batch, g, v = make_global_batch(1000, 1)  # all rows to one device
         sharded = shard_batch(batch, mesh)
 
-        @jax.jit
-        @functools.partial(
-            shard_map,
-            mesh=mesh, in_specs=P("d"), out_specs=(P("d"), P(), P()),
-        )
-        def step(b):
-            out, dropped, need = hash_repartition(b, colfn("g"), N, 64)
-            return out, dropped, need
+        def step(bucket):
+            @jax.jit
+            @functools.partial(
+                shard_map,
+                mesh=mesh, in_specs=P("d"), out_specs=(P("d"), P(), P()),
+            )
+            def run(b):
+                return hash_repartition(b, colfn("g"), N, bucket)
 
-        _out, dropped, need = step(sharded)
-        assert int(dropped) == 1000 - 64 * N or int(dropped) > 0
+            return run(sharded)
+
+        _out, dropped, need = step(64)
+        assert int(dropped) > 0
         # the region-balance analog: the exchange reports the TRUE
-        # hot-bucket size so the host retries at the exact capacity
-        assert int(need) == 1000
+        # hot-bucket size, the most one shard sends one other (the
+        # first three of the eight 256-row shards are full), so the
+        # host retries at the exact capacity, and nothing is dropped
+        assert int(need) == 256
+        out, dropped, need = step(int(need))
+        assert int(dropped) == 0 and int(need) == 256
+        assert int(np.sum(np.asarray(out.row_valid))) == 1000
+
+
+    @pytest.mark.parametrize("ncols", [3, 40])
+    def test_narrow_columns_and_nulls_cross_the_exchange(self, mesh, ncols):
+        """The send buffers hold nothing under 32 bits (the v5e compiler
+        takes 10-15 s for every large 8-bit scatter, PERF.md PR 29):
+        validity travels as bits of u32 words, 31 columns a word, and a
+        bool or int8 column widened. What arrives is what was sent."""
+        rng = np.random.default_rng(ncols)
+        rows, cap = 1500, 256 * N
+        kinds = [np.int64, np.bool_, np.int8, np.float64, np.int32]
+        host = {"g": (rng.integers(0, 50, cap).astype(np.int64), rng.random(cap) < 0.9)}
+        for i in range(ncols - 1):
+            dt = kinds[i % len(kinds)]
+            data = (rng.random(cap) < 0.5) if dt == np.bool_ else (
+                rng.integers(-100, 100, cap).astype(dt))
+            host[f"c{i}"] = (data, rng.random(cap) < 0.8)
+        row_valid = np.arange(cap) < rows
+        batch = Batch(
+            {n: DevCol(jnp.asarray(d), jnp.asarray(v)) for n, (d, v) in host.items()},
+            jnp.asarray(row_valid),
+        )
+        step = jax.jit(shard_map(
+            lambda b: hash_repartition(b, colfn("g"), N, 128)[:2],
+            mesh=mesh, in_specs=P("d"), out_specs=(P("d"), P()),
+        ))
+        sharded = shard_batch(batch, mesh)
+        out, dropped = step(sharded)
+        assert int(dropped) == 0
+        rv = np.asarray(out.row_valid)
+        assert int(rv.sum()) == rows
+
+        def as_rows(cols, mask):
+            arrays = []
+            for n in host:
+                d, v = (np.asarray(x)[mask] for x in cols[n])
+                assert np.asarray(cols[n][0]).dtype == host[n][0].dtype, n
+                arrays += [np.where(v, d, 0).astype(np.float64), v.astype(np.float64)]
+            return sorted(map(tuple, np.stack(arrays, axis=1).tolist()))
+
+        got = as_rows({n: (c.data, c.valid) for n, c in out.cols.items()}, rv)
+        assert got == as_rows(host, row_valid)
+        text = step.lower(sharded).as_text()
+        import re
+
+        scattered = re.findall(
+            r"stablehlo\.scatter.*?\}\) .*?: \([^)]*\) -> tensor<\d+x(\w+)>", text, re.S)
+        assert scattered and set(scattered) <= {"ui32", "i32", "i64", "f64", "f32"}, scattered
+        # one validity word a 31 columns, one buffer a data column
+        assert len(scattered) == ncols + -(-ncols // 31)
 
 
 class TestDistributedAgg:

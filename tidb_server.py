@@ -28,6 +28,12 @@ def bootstrap(cfg, tpch_sf=None, seed: int = 0, status_port=None):
     from tidb_tpu.utils.backend import enable_compile_cache
 
     enable_compile_cache()
+    if cfg.mesh_devices:
+        # before any load: a mesh wider than the devices JAX sees is an
+        # error, never a narrower server under the same name
+        from tidb_tpu.parallel.mesh import shared_mesh
+
+        shared_mesh(cfg.mesh_devices)
     catalog = Catalog()
     if cfg.path and os.path.exists(os.path.join(cfg.path, "manifest.json")):
         from tidb_tpu.storage.persist import load_catalog
@@ -42,7 +48,8 @@ def bootstrap(cfg, tpch_sf=None, seed: int = 0, status_port=None):
         load_tpch(catalog, sf=tpch_sf, seed=seed)
 
     sp = status_port if status_port is not None else cfg.status_port
-    srv = Server(catalog, host=cfg.host, port=cfg.port, status_port=sp)
+    srv = Server(catalog, host=cfg.host, port=cfg.port, status_port=sp,
+                 mesh_devices=cfg.mesh_devices)
     srv.stats_handle.interval_s = cfg.auto_analyze_interval_s
     from tidb_tpu.utils.watchdog import ensure_watchdog
 
@@ -62,6 +69,10 @@ def main() -> int:
                     help="HTTP status/metrics port (reference :10080)")
     ap.add_argument("--store", default=None, choices=["tpu"],
                     help="storage/compute engine (TPU device engine)")
+    ap.add_argument("--mesh-devices", type=int, default=None, metavar="N",
+                    help="serve every statement as one SPMD program over a "
+                         "mesh of N devices (MPP mode); more than JAX sees "
+                         "is an error at start-up")
     ap.add_argument("--tpch", type=float, default=None, metavar="SF",
                     help="bootstrap with TPC-H data at scale factor SF")
     args = ap.parse_args()
@@ -70,11 +81,13 @@ def main() -> int:
 
     cfg = Config.from_toml(args.config) if args.config else Config()
     cfg = cfg.override(
-        host=args.host, port=args.port, path=args.path, store=args.store
+        host=args.host, port=args.port, path=args.path, store=args.store,
+        mesh_devices=args.mesh_devices,
     )
     catalog, srv = bootstrap(cfg, tpch_sf=args.tpch, status_port=args.status_port)
     print(
-        f"tidb_tpu listening on {cfg.host}:{srv.port} (store={cfg.store})",
+        f"tidb_tpu listening on {cfg.host}:{srv.port} (store={cfg.store}"
+        + (f", mesh-devices={cfg.mesh_devices})" if cfg.mesh_devices else ")"),
         flush=True,
     )
 

@@ -275,23 +275,32 @@ class Batch:
         return jnp.sum(self.row_valid.astype(jnp.int32))
 
 
-def block_to_batch(block: HostBlock, capacity: Optional[int] = None) -> Batch:
-    """Pad a host block to a static tile and move it to device layout."""
+def block_to_batch(
+    block: HostBlock, capacity: Optional[int] = None, sharding=None
+) -> Batch:
+    """Pad a host block to a static tile and move it to device layout:
+    the default device, or with `sharding` each shard of the padded host
+    array straight to its own device (no whole copy on one device, no
+    slicing program per shape)."""
     from tidb_tpu.obs.engine_watch import ENGINE_WATCH
 
     cap = capacity or pad_capacity(block.nrows)
     pad = cap - block.nrows
+    if sharding is None:
+        to_device = jnp.asarray
+    else:
+        to_device = lambda a: jax.device_put(a, sharding)  # noqa: E731
     cols = {}
     h2d = cap  # the row-validity mask ships too
     for name, col in block.columns.items():
         data = np.pad(col.data, (0, pad))
         valid = np.pad(col.valid, (0, pad))
         h2d += data.nbytes + valid.nbytes
-        cols[name] = DevCol(jnp.asarray(data), jnp.asarray(valid))
+        cols[name] = DevCol(to_device(data), to_device(valid))
     row_valid = np.zeros(cap, dtype=bool)
     row_valid[: block.nrows] = True
     ENGINE_WATCH.note_h2d(h2d)
-    return Batch(cols, jnp.asarray(row_valid))
+    return Batch(cols, to_device(row_valid))
 
 
 def batch_from_padded(

@@ -170,6 +170,7 @@ class QueryFlight:
         "device_mem_peak_bytes", "compile_flops",
         "compile_bytes_accessed", "compile_output_bytes", "live_phase",
         "est_rows", "act_rows", "spans", "served_s", "background",
+        "exchanges", "exchange_rows", "exchange_bytes",
     )
 
     def __init__(self, qid: int, conn_id: int, sql: str):
@@ -194,6 +195,13 @@ class QueryFlight:
         self.retraces = 0
         self.h2d_bytes = 0
         self.d2h_bytes = 0
+        #: what the mesh programs this statement ran exchanged between
+        #: chips: exchanges executed, the valid rows they sent (summed
+        #: over shards) and the bytes those rows must carry across
+        #: (parallel/exchange.py); all 0 on a one-device executor
+        self.exchanges = 0
+        self.exchange_rows = 0
+        self.exchange_bytes = 0
         self.device_mem_peak_bytes = 0
         # XLA cost analysis summed over this statement's compiles
         # (obs/engine_watch.py per-signature harvest)
@@ -602,6 +610,15 @@ class FlightRecorder:
         if rec is not None:
             rec.rows_sent = int(n)
 
+    def note_exchanges(self, count: int, rows: int, nbytes: int) -> None:
+        """One executed mesh program's exchanges (planner/physical.py
+        reads them beside the program's cardinality scalars)."""
+        rec = self.current()
+        if rec is not None:
+            rec.exchanges += int(count)
+            rec.exchange_rows += int(rows)
+            rec.exchange_bytes += int(nbytes)
+
     def note_cardinality(self, est: float, act: float) -> None:
         """Planner-estimated vs observed output rows of a routed
         statement (AQE): feeds the statements_summary est/act
@@ -679,6 +696,9 @@ class FlightRecorder:
                 "retraces": r.retraces,
                 "h2d_bytes": r.h2d_bytes,
                 "d2h_bytes": r.d2h_bytes,
+                "exchanges": r.exchanges,
+                "exchange_rows": r.exchange_rows,
+                "exchange_bytes": r.exchange_bytes,
                 "device_mem_peak_bytes": r.device_mem_peak_bytes,
                 "compile_flops": r.compile_flops,
                 "compile_bytes_accessed": r.compile_bytes_accessed,

@@ -19,6 +19,8 @@ returned so the host can detect overflow and retry at a larger tile
 
 from __future__ import annotations
 
+import contextlib
+import threading
 from typing import Callable, Tuple
 
 import jax
@@ -27,6 +29,49 @@ import jax.numpy as jnp
 from tidb_tpu.chunk import Batch, DevCol
 
 ExprFn = Callable[[Batch], DevCol]
+
+_TRACING = threading.local()
+
+
+@contextlib.contextmanager
+def sent_ledger():
+    """What the exchanges traced inside send: one (rows, bytes) pair of
+    replicated int64 scalars an exchange. The mesh program opens it
+    around the plan and returns the sums beside its cardinality scalars
+    (planner/physical.py), so a statement's flight can say what crossed
+    the chips."""
+    prev = getattr(_TRACING, "sent", None)
+    _TRACING.sent = out = []
+    try:
+        yield out
+    finally:
+        _TRACING.sent = prev
+
+
+def _note_exchange(
+    kind: str, batch: Batch, rows: jax.Array, hops: Tuple[int, int]
+) -> None:
+    """Count one traced exchange (once per exchange per traced program,
+    as the joins count their compactions) and put what it sends on the
+    open ledger. `rows`: the valid rows sent, summed over the shards.
+    Bytes are the least any implementation moves between chips: those
+    rows times the logical width of the columns that travel (a value's
+    bytes; validity bits, padding and bucket slack are not counted)
+    times `hops` (a fraction), the other chips a row must reach:
+    (n-1)/n of them on average under a hash or range partition, n-1
+    under a broadcast."""
+    from tidb_tpu.utils.metrics import REGISTRY
+
+    REGISTRY.counter(
+        "tidbtpu_executor_exchanges_total",
+        "exchanges in traced mesh programs, by kind",
+        labels=("kind",),
+    ).labels(kind=kind).inc()
+    sent = getattr(_TRACING, "sent", None)
+    if sent is not None:
+        width = sum(c.data.dtype.itemsize for c in batch.cols.values())
+        rows = rows.astype(jnp.int64)
+        sent.append((rows, rows * (width * hops[0]) // hops[1]))
 
 _MIX = jnp.int64(-7046029254386353131)  # 0x9E3779B97F4A7C15 as signed
 
@@ -114,12 +159,28 @@ def range_repartition(
         jnp.int32
     )
     target = jnp.where(batch.row_valid, target, n)
-    out, dropped, need = exchange_by_target(
-        batch, target, n, bucket_capacity, axis
+    return exchange_by_target(
+        batch, target, n, bucket_capacity, axis, kind="range"
     )
-    # `need` is exact on BOTH sides: the true per-bucket requirement on
-    # overflow AND the shrink target when over-provisioned
-    return out, dropped, need
+
+
+def _pack_bits(bit0: jax.Array, flags) -> jax.Array:
+    """A u32 word a row: `bit0` (a u32 of 0 or 1), then one bit a flag."""
+    word = bit0
+    for i, flag in enumerate(flags):
+        word = word | (flag.astype(jnp.uint32) << (1 + i))
+    return word
+
+
+def _widened(arr: jax.Array) -> jax.Array:
+    """An operand of under 32 bits as 32 (see exchange_by_target)."""
+    if arr.dtype.itemsize >= 4:
+        return arr
+    if arr.dtype == jnp.bool_ or jnp.issubdtype(arr.dtype, jnp.unsignedinteger):
+        return arr.astype(jnp.uint32)
+    if jnp.issubdtype(arr.dtype, jnp.integer):
+        return arr.astype(jnp.int32)
+    return arr.astype(jnp.float32)
 
 
 def exchange_by_target(
@@ -128,56 +189,98 @@ def exchange_by_target(
     n: int,
     bucket_capacity: int,
     axis: str = "d",
+    kind: str = "hash",
 ) -> Tuple[Batch, jax.Array, jax.Array]:
     """all_to_all exchange of rows to explicit per-row target devices
     (bucket n = drop). Shared by hash and range repartition.
 
     Returns (new local batch, globally dropped rows, TRUE per-bucket
-    need): `need` is the max over destinations of the global row count
-    headed there — the region-balance analog
-    (pkg/store/copr/batch_coprocessor.go balances tasks by actual
-    region sizes). On overflow the host retries at exactly `need`
-    instead of doubling blindly, so a hot key costs ONE recompile, not
-    log2(hot/B); in steady state the plan-cache keeps the discovered
-    capacity and nothing recompiles."""
+    need): `need` is the fullest (source, destination) bucket of this
+    data, the region-balance analog (pkg/store/copr/batch_coprocessor.go
+    balances tasks by actual region sizes), exact in both directions: on
+    overflow the host retries at exactly `need` instead of doubling
+    blindly, so a hot key costs ONE recompile, not log2(hot/B), and an
+    over-provisioned first tile shrinks to it; in steady state the
+    plan-cache keeps the discovered capacity and nothing recompiles.
+
+    On the device trace the stages sit in `exchange/sort` (bucket and
+    slot of each row), `exchange/pack` (the send buffers) and
+    `exchange/all-to-all` inside the operator's scope
+    (scripts/trace_by_scope.py sums them)."""
     B = bucket_capacity
     cap = batch.capacity
 
     from tidb_tpu.executor.sortops import bits_for, sort_rows, unpack_lex
+    from tidb_tpu.parallel.mesh import pmax
 
-    # (destination, row id) packed into one key word: rows keep their
-    # order inside a bucket (sortops: compile time follows key limbs)
-    ops, where, perm = sort_rows(
-        [(jnp.clip(target, 0, n), bits_for(n + 1))], cap
-    )
-    sorted_t = unpack_lex(ops, where, 0).astype(jnp.int32)
-    start = jnp.searchsorted(sorted_t, jnp.arange(n + 1, dtype=jnp.int32))
-    slot = jnp.arange(cap, dtype=jnp.int32) - start[jnp.clip(sorted_t, 0, n)]
-    fits = (slot < B) & (sorted_t < n)
-    buf_idx = jnp.clip(sorted_t, 0, n - 1) * B + jnp.clip(slot, 0, B - 1)
+    with jax.named_scope("exchange"):
+        with jax.named_scope("sort"):
+            # (destination, row id) packed into one key word: rows keep
+            # their order inside a bucket (sortops: compile time follows
+            # key limbs)
+            ops, where, perm = sort_rows(
+                [(jnp.clip(target, 0, n), bits_for(n + 1))], cap
+            )
+            sorted_t = unpack_lex(ops, where, 0).astype(jnp.int32)
+            start = jnp.searchsorted(
+                sorted_t, jnp.arange(n + 1, dtype=jnp.int32)
+            )
+            slot = (
+                jnp.arange(cap, dtype=jnp.int32)
+                - start[jnp.clip(sorted_t, 0, n)]
+            )
+            fits = (slot < B) & (sorted_t < n)
+            buf_idx = (
+                jnp.clip(sorted_t, 0, n - 1) * B + jnp.clip(slot, 0, B - 1)
+            )
 
-    sent = jnp.sum(fits.astype(jnp.int64))
-    valid_rows = jnp.sum((target < n).astype(jnp.int64))
-    dropped = jax.lax.psum(valid_rows - sent, axis)
-    # per-destination global sizes: local bucket counts (start deltas),
-    # psum'd — one [n] vector over ICI, negligible next to the exchange
-    local_counts = (start[1 : n + 1] - start[:n]).astype(jnp.int64)
-    need = jnp.max(jax.lax.psum(local_counts, axis))
+            sent = jnp.sum(fits.astype(jnp.int64))
+            valid_rows = jnp.sum((target < n).astype(jnp.int64))
+            all_valid = jax.lax.psum(valid_rows, axis)
+            dropped = all_valid - jax.lax.psum(sent, axis)
+            # this shard's bucket sizes (start deltas); the fullest
+            # bucket of any shard is what B has to hold
+            local_counts = (start[1 : n + 1] - start[:n]).astype(jnp.int64)
+            need = pmax(jnp.max(local_counts), axis)
+        _note_exchange(kind, batch, all_valid, (n - 1, n))
 
-    def scatter(arr: jax.Array) -> jax.Array:
-        src = arr[perm]
-        buf = jnp.zeros((n * B,), dtype=arr.dtype)
-        buf = buf.at[jnp.where(fits, buf_idx, n * B)].set(src, mode="drop")
-        return buf.reshape(n, B)
+        def scatter(arr: jax.Array) -> jax.Array:
+            src = arr[perm]
+            buf = jnp.zeros((n * B,), dtype=arr.dtype)
+            buf = buf.at[jnp.where(fits, buf_idx, n * B)].set(src, mode="drop")
+            return buf.reshape(n, B)
 
-    new_cols = {}
-    for name, c in batch.cols.items():
-        d = jax.lax.all_to_all(scatter(c.data), axis, 0, 0)
-        v = jax.lax.all_to_all(scatter(c.valid), axis, 0, 0)
-        new_cols[name] = DevCol(d.reshape(n * B), v.reshape(n * B))
-    rv_send = jnp.zeros((n * B,), dtype=jnp.bool_)
-    rv_send = rv_send.at[jnp.where(fits, buf_idx, n * B)].set(True, mode="drop")
-    rv = jax.lax.all_to_all(rv_send.reshape(n, B), axis, 0, 0).reshape(n * B)
+        names = list(batch.cols)
+        with jax.named_scope("pack"):
+            # Nothing 8 bits wide is scattered: the v5e compiler takes
+            # 10-15 s for every scatter of a bool or u8 operand, whatever
+            # its length, and 0.1 s for a 32-bit one (PERF.md, PR 29; a
+            # mesh Q5 had six such shapes in each of its two programs).
+            # The row's presence and every column's validity travel as
+            # the bits of u32 words, 31 columns a word, and a narrow
+            # data column travels widened.
+            present = jnp.ones((cap,), dtype=jnp.uint32)
+            words = [
+                scatter(_pack_bits(
+                    present, [batch.cols[c].valid for c in names[at:at + 31]]
+                ))
+                for at in range(0, max(len(names), 1), 31)
+            ]
+            send = [
+                scatter(_widened(batch.cols[c].data)) for c in names
+            ]
+        with jax.named_scope("all-to-all"):
+            words = [
+                jax.lax.all_to_all(w, axis, 0, 0).reshape(n * B) for w in words
+            ]
+            rv = (words[0] & 1) != 0
+            new_cols = {}
+            for i, (name, d) in enumerate(zip(names, send)):
+                d = jax.lax.all_to_all(d, axis, 0, 0).reshape(n * B)
+                v = ((words[i // 31] >> (1 + i % 31)) & 1) != 0
+                new_cols[name] = DevCol(
+                    d.astype(batch.cols[name].data.dtype), v
+                )
     return Batch(new_cols, rv), dropped, need
 
 
@@ -193,8 +296,12 @@ def broadcast_gather(batch: Batch, axis: str = "d") -> Batch:
         g = jax.lax.all_gather(arr, axis)  # [n, cap]
         return g.reshape(-1)
 
-    cols = {
-        name: DevCol(gather(c.data), gather(c.valid))
-        for name, c in batch.cols.items()
-    }
-    return Batch(cols, gather(batch.row_valid))
+    n = jax.lax.axis_size(axis)
+    rows = jax.lax.psum(jnp.sum(batch.row_valid.astype(jnp.int64)), axis)
+    _note_exchange("broadcast", batch, rows, (n - 1, 1))
+    with jax.named_scope("broadcast"), jax.named_scope("all-gather"):
+        cols = {
+            name: DevCol(gather(c.data), gather(c.valid))
+            for name, c in batch.cols.items()
+        }
+        return Batch(cols, gather(batch.row_valid))

@@ -11,6 +11,7 @@ ride ICI within a slice and DCN across, with no code change here.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -54,6 +55,13 @@ def pmax(v, axis: str = AXIS):
 def make_mesh(n_devices: Optional[int] = None) -> Mesh:
     devs = jax.devices()
     n = n_devices or len(devs)
+    if n > len(devs):
+        # never a smaller mesh than was asked for: a server given four
+        # chips that silently served from one would be measured as four
+        raise ValueError(
+            f"mesh of {n} devices asked for, JAX sees {len(devs)} "
+            f"({devs[0].platform})"
+        )
     # Auto, not jax 0.9's default Explicit: the engine reshapes and
     # concatenates sharded operands and leaves their layout to the
     # partitioner
@@ -61,6 +69,15 @@ def make_mesh(n_devices: Optional[int] = None) -> Mesh:
         (n,), (AXIS,), devices=devs[:n],
         axis_types=(jax.sharding.AxisType.Auto,),
     )
+
+
+@functools.lru_cache(maxsize=None)
+def shared_mesh(n_devices: int) -> Mesh:
+    """THE mesh of width n in this process: every session of a server
+    (and every executor a test builds) runs its programs over the same
+    Mesh object and the same resident shards (storage/scan.py keys its
+    cache by the mesh's width)."""
+    return make_mesh(n_devices)
 
 
 def init_multihost(

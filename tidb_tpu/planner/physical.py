@@ -202,6 +202,12 @@ class CompiledQuery:
     bound_checks: List[Tuple[int, str, int, int]] = dataclasses.field(
         default_factory=list
     )
+    # sized nodes that are an exchange's bucket tile (mesh mode): a
+    # bump of one is counted as an exchange overflow retry
+    exchange_nids: frozenset = frozenset()
+    # (mesh mode) the output's first compaction tile, from the root's
+    # estimated rows; 0 on one device
+    first_out_cap: int = 0
     # plan signature for the engine watch: a second jit trace for the
     # same sig is a retrace (obs/engine_watch.py)
     sig: Optional[object] = None
@@ -958,6 +964,8 @@ class PlanCompiler:
         # fragments for order-sensitive operators.
         self.mesh_n = mesh_n
         self._tag = "shard"
+        # sized nodes that are an exchange's bucket tile (mesh mode)
+        self.exchange_nids: set = set()
 
     def fresh_id(self) -> int:
         self._next_id += 1
@@ -974,6 +982,37 @@ class PlanCompiler:
         self.sized.append(nid)
         self.defaults[nid] = 16
         self.widths[nid] = 8
+        return nid
+
+    def _first_tile(self, plan: L.LogicalPlan, parts: int = 1) -> int:
+        """(mesh mode) A knob's first tile from the planner's estimate
+        of `plan`'s rows (exact table counts and ANALYZE's statistics,
+        planner/cardinality.py), a `parts`-th of them, with a quarter
+        of room for the estimate's error and a partition's unevenness,
+        to the next tile. Where the estimates hold the first program a
+        mesh statement compiles is already its steady one
+        (PhysicalExecutor._run_pinned); a tile too small retries at the
+        exact need like any other. 0 off the mesh: one device starts
+        from the dominant input tile, as it always has."""
+        if not self.mesh_n:
+            return 0
+        from tidb_tpu.planner import cardinality as C
+
+        return _cap_tile(int(1.25 * C.est_rows(plan, self.catalog)) // parts + 1)
+
+    def _exchange_knob(self, plan: L.LogicalPlan, sides) -> int:
+        """The sized node of an exchange of `sides` (a join's pair, a
+        sort's child): B, the rows one shard may send one other, first
+        sized for the larger side spread over the n x n buckets.
+        Discovery shrinks it to the fullest bucket, or retries once at
+        the exact need."""
+        nid = self.fresh_id()
+        self.sized.append(nid)
+        self.exchange_nids.add(nid)
+        self.widths[nid] = _schema_width(plan.schema)
+        self.defaults[nid] = max(
+            self._first_tile(side, self.mesh_n**2) for side in sides
+        )
         return nid
 
     def _gathered(self, fn, tag):
@@ -1079,6 +1118,8 @@ class PlanCompiler:
             widths=dict(self.widths),
             nonnull=list(self.nonnull),
             bound_checks=list(self.bound_checks),
+            exchange_nids=frozenset(self.exchange_nids),
+            first_out_cap=self._first_tile(plan),
         )
 
     # ------------------------------------------------------------------
@@ -1289,13 +1330,11 @@ class PlanCompiler:
                 # (reference: sortexec multi-way merge over partitions;
                 # VERDICT round-1 weak #2).
                 mesh_n = self.mesh_n
-                nid = self.fresh_id()
-                self.sized.append(nid)
-                self.defaults[nid] = 0  # filled from the dominant tile
+                nid = self._exchange_knob(plan, (plan.child,))
                 # the exchange allocates an (n, B) send buffer + an n*B
                 # receive batch per device: account ~n tiles of width,
                 # not one (memory-quota admission honesty)
-                self.widths[nid] = _schema_width(plan.schema) * mesh_n
+                self.widths[nid] *= mesh_n
                 first_fn, first_desc = key_fns[0], descs[0]
 
                 def fn_dsort(inputs, caps):
@@ -1457,7 +1496,7 @@ class PlanCompiler:
         child_tag = self._tag
         nid = self.fresh_id()
         self.sized.append(nid)
-        self.defaults[nid] = 1024
+        self.defaults[nid] = self._first_tile(plan) or 1024
         self.widths[nid] = _schema_width(plan.schema)
         key_fns, key_names, key_widths, descs = build_agg_parts(
             plan, dicts, compiler=self
@@ -1785,10 +1824,7 @@ class PlanCompiler:
                     if ltag == "shard" and rtag == "shard":
                         # repartition both sides on the join key so equal
                         # keys colocate (MPP HashPartition exchange)
-                        part_nid = self.fresh_id()
-                        self.sized.append(part_nid)
-                        self.widths[part_nid] = _schema_width(plan.schema)
-                        self.defaults[part_nid] = 0
+                        part_nid = self._exchange_knob(plan, (plan.left, plan.right))
                     self._tag = ltag
 
                 snid = self._stale_sentinel_node(rprops)
@@ -1801,13 +1837,14 @@ class PlanCompiler:
                         from tidb_tpu.parallel import repartition_pair
 
                         B = caps[part_nid]
-                        lb, rb, drp, xneed = repartition_pair(
+                        lb, rb, _drp, xneed = repartition_pair(
                             lb, rb, lkey, rkey, mesh, B
                         )
-                        # overflow reports the TRUE per-bucket need: a
-                        # hot key costs ONE recompile at the exact
-                        # size, not a doubling ladder
-                        needs[part_nid] = jnp.where(drp > 0, xneed, B)
+                        # the TRUE per-bucket need, in both directions:
+                        # a hot key costs ONE recompile at the exact
+                        # size, not a doubling ladder, and the first
+                        # tile shrinks to what the data fills
+                        needs[part_nid] = xneed
                     out, _t = equi_join(
                         rb, lb, rkey, lkey, 0, kind, build_bounds=rprops[0]
                     )
@@ -1847,10 +1884,7 @@ class PlanCompiler:
                         right = self._gathered(right, rtag)
                         rtag = "repl"
                     if ltag == "shard" and rtag == "shard":
-                        part_nid = self.fresh_id()
-                        self.sized.append(part_nid)
-                        self.widths[part_nid] = _schema_width(plan.schema)
-                        self.defaults[part_nid] = 0
+                        part_nid = self._exchange_knob(plan, (plan.left, plan.right))
                     self._tag = ltag
                 # the sorted lookup's stale source is a runtime
                 # uniqueness violation, not just outgrown bounds — the
@@ -1868,10 +1902,10 @@ class PlanCompiler:
                         from tidb_tpu.parallel import repartition_pair
 
                         B = caps[part_nid]
-                        lb, rb, drp, xneed = repartition_pair(
+                        lb, rb, _drp, xneed = repartition_pair(
                             lb, rb, lkey, rkey, mesh, B
                         )
-                        needs[part_nid] = jnp.where(drp > 0, xneed, B)
+                        needs[part_nid] = xneed
                     brow, matched, stale = lookup_build_rows(
                         rb, lb, rkey, lkey, build_bounds=rprops[0]
                     )
@@ -2053,10 +2087,7 @@ class PlanCompiler:
                     rtag = "repl"
                     self._tag = "repl"
             elif ltag == "shard" and rtag == "shard":
-                part_nid = self.fresh_id()
-                self.sized.append(part_nid)
-                self.widths[part_nid] = _schema_width(plan.schema)
-                self.defaults[part_nid] = 0
+                part_nid = self._exchange_knob(plan, (plan.left, plan.right))
                 self._tag = "shard"
             else:
                 # rtag repl: build side already everywhere (broadcast join)
@@ -2064,20 +2095,24 @@ class PlanCompiler:
         nid = self.fresh_id()
         self.sized.append(nid)
         self.widths[nid] = _schema_width(plan.schema)
-        self.defaults[nid] = 0  # resolved at first execution from probe cap
+        # one device: resolved at first execution from the probe's tile;
+        # a mesh: from the estimate, a shard's share where it is sharded
+        self.defaults[nid] = self._first_tile(
+            plan, self.mesh_n if mesh and self._tag == "shard" else 1
+        )
 
         def fn_join(inputs, caps):
             lb, n1 = left(inputs, caps)
             rb, n2 = right(inputs, caps)
-            extra_needs = {}
+            needs = {**n1, **n2}
             if part_nid is not None:
                 from tidb_tpu.parallel import repartition_pair
 
                 B = caps[part_nid]
-                lb, rb, drp, xneed = repartition_pair(
+                lb, rb, _drp, xneed = repartition_pair(
                     lb, rb, lkey, rkey, mesh, B
                 )
-                extra_needs[part_nid] = jnp.where(drp > 0, xneed, B)
+                needs[part_nid] = xneed
             build_b, probe_b, build_k, probe_k = rb, lb, rkey, lkey
             build_props = rprops
             if forced_swap or (
@@ -2105,7 +2140,6 @@ class PlanCompiler:
                 out = filter_batch(out, vf)
             if res is not None:
                 out = filter_batch(out, res)
-            needs = {**n1, **n2}
             needs[nid] = total
             return out, needs
 
@@ -2298,9 +2332,9 @@ class PhysicalExecutor:
         self.mesh = None
         self.mesh_n = mesh_devices
         if mesh_devices:
-            from tidb_tpu.parallel.mesh import make_mesh
+            from tidb_tpu.parallel.mesh import shared_mesh
 
-            self.mesh = make_mesh(mesh_devices)
+            self.mesh = shared_mesh(mesh_devices)
 
     def _resolve(self, db: str, table: str):
         if self.table_hook is not None:
@@ -2420,13 +2454,21 @@ class PhysicalExecutor:
 
         from tidb_tpu.parallel.mesh import pmax, reshard, shard_map
 
+        from tidb_tpu.parallel.exchange import sent_ledger
+
         n = self.mesh_n
 
         def local(i, _f=fn, _c=frozen_caps):
-            b, needs = _f(i, _c)
+            with sent_ledger() as sent:
+                b, needs = _f(i, _c)
             # pmax proves replication of the cardinality scalars to
             # shard_map AND takes the per-shard max for sizing knobs
             needs = {k: pmax(v, "d") for k, v in needs.items()}
+            # what the program's exchanges sent, beside them: how many
+            # there are, their rows and their cross-chip bytes
+            needs[_EXCHANGES] = jnp.int64(len(sent))
+            needs[_EXCHANGE_ROWS] = sum((r for r, _ in sent), jnp.int64(0))
+            needs[_EXCHANGE_BYTES] = sum((b for _, b in sent), jnp.int64(0))
             return b, needs
 
         sm = shard_map(
@@ -2467,8 +2509,10 @@ class PhysicalExecutor:
             for dc in b.cols.values():
                 nb += b.capacity * (dc.data.dtype.itemsize + 1)
             ws += nb
+        # an operator's tile is allocated on every shard of a mesh
+        shards = self.mesh_n or 1
         for nid, cap in caps.items():
-            ws += 2 * cap * cq.widths.get(nid, 64)
+            ws += 2 * cap * cq.widths.get(nid, 64) * shards
         self.last_working_set = ws
         ENGINE_WATCH.note_device_mem(ws)
         if not quota:
@@ -2490,7 +2534,7 @@ class PhysicalExecutor:
                 w = cq.widths.get(nid, 64)
                 # keyed group tables allocate 2x slots; exchanges double-
                 # buffer: a conservative 2x multiplier covers both
-                nodes.child(f"node#{nid}").consume(2 * cap * w)
+                nodes.child(f"node#{nid}").consume(2 * cap * w * shards)
         except QuotaExceeded as e:
             report = "\n".join(root.report())
             raise ExecError(
@@ -2531,7 +2575,9 @@ class PhysicalExecutor:
             share = max(int(self.quota_bytes) // (4 * len(caps)), 1)
             for nid in defaulted:
                 w = cq.widths.get(nid, 64)
-                lim = _cap_tile(max(share // (2 * max(w, 1)), 1024))
+                lim = _cap_tile(
+                    max(share // (2 * max(w, 1) * (self.mesh_n or 1)), 1024)
+                )
                 if caps[nid] > lim:
                     caps[nid] = lim
         from tidb_tpu.utils.sqlkiller import current_check
@@ -2562,7 +2608,7 @@ class PhysicalExecutor:
             with FLIGHT.span("device-wait"):
                 jax.block_until_ready((needs, out))
             with FLIGHT.span("fetch"):
-                needs_host = jax.device_get(needs)
+                needs_host = _take_exchange_stats(jax.device_get(needs))
             bumped = False
             for nid, true_n in needs_host.items():
                 n = int(true_n)
@@ -2573,6 +2619,14 @@ class PhysicalExecutor:
                     raise StaleWidthsError()
                 if n > caps[nid]:
                     failpoint.inject("executor/cap-overflow")
+                    if nid in cq.exchange_nids:
+                        from tidb_tpu.utils.metrics import REGISTRY
+
+                        REGISTRY.counter(
+                            "tidbtpu_executor_exchange_overflow_retries_total",
+                            "whole-program recompiles because an "
+                            "exchange's bucket tile overflowed",
+                        ).inc()
                     caps[nid] = _cap_tile(n)
                     if caps[nid] > _MAX_JOIN_CAP:
                         raise ExecError(f"result too large at node {nid}: {n} rows")
@@ -2713,6 +2767,78 @@ class PhysicalExecutor:
                     sp.pop(k, None)
         raise ExecError("packed key widths did not stabilize after recompiles")
 
+    def _steady_at(self, cq: CompiledQuery, caps, out_cap: int, inputs):
+        """Compile the steady program at `caps` and run it once:
+        (callable, output, cardinalities on the host)."""
+        program = self._make_program(cq, dict(caps))
+        jitted = watched_jit(
+            lambda i, pv, _p=program, _oc=out_cap: _steady_step(
+                _p, _oc, i, pv, mesh=self.mesh
+            ),
+            sig=("steady", cq.sig),
+        )
+        out, needs_host = _launch_and_fetch(jitted, inputs, self._params())
+        return jitted, out, needs_host
+
+    def _steady_first(self, cq: CompiledQuery, inputs, shape_key):
+        """A mesh plan's first execution: where every knob has a first
+        tile from the planner's estimates (PlanCompiler._first_tile),
+        compile the STEADY program at them and run it, in the discover
+        program's place. It returns the knobs' true cardinalities like
+        any steady run. Nothing overflowed and no tile is more than
+        twice its tight one: that program is published as the steady
+        one and the statement has compiled one whole program, not two
+        (on the v5e host a mesh Q5's take 90 s each, PERF.md PR 29).
+        A looser tile: the tight tiles are known, the steady program is
+        compiled at them, and discovery is skipped all the same. An
+        overflow, which cuts short what lies downstream of it: the
+        discover loop, as before.
+
+        Returns (output, None, 0) when the first program was kept,
+        (None, tight caps, tight output tile) when it ran clean and is
+        to be tightened, (None, None, 0) when discovery has to run."""
+        first, first_out = cq.default_caps, cq.first_out_cap
+        if self.mesh is None or cq.caps or not first_out or not all(first.values()):
+            return None, None, 0
+        from tidb_tpu.utils.metrics import REGISTRY
+
+        outcomes = REGISTRY.counter(
+            "tidbtpu_executor_steady_first_total",
+            "mesh plans first compiled as their steady program at "
+            "estimated tiles: kept, tightened (recompiled at the tight "
+            "tiles, no discovery), overflowed (discovery ran)",
+            labels=("outcome",),
+        )
+        if self.kill_check is not None:
+            self.kill_check()
+        self._admit(cq, inputs, first)
+        jitted, out, needs_host = self._steady_at(cq, first, first_out, inputs)
+        full = {**first, _OUT_NODE: first_out}
+        if _overflowed(needs_host, full):
+            if any(int(n) >= _WIDTH_STALE for n in needs_host.values()):
+                # baked key bounds no longer cover the data: no tile fixes that
+                raise StaleWidthsError()
+            outcomes.labels(outcome="overflowed").inc()
+            # discovery starts from what this run saw (a lower bound
+            # downstream of the overflow), not from the estimates again
+            cq.caps = {
+                nid: max(cap, _cap_tile(int(needs_host.get(nid, 0))))
+                for nid, cap in first.items()
+            }
+            return None, None, 0
+        tight = {
+            nid: _cap_tile(int(needs_host[nid])) if nid in needs_host else cap
+            for nid, cap in full.items()
+        }
+        if all(cap <= 2 * tight[nid] for nid, cap in full.items()):
+            outcomes.labels(outcome="kept").inc()
+            cq.caps = dict(full)
+            cq.steady = (jitted, full, shape_key)
+            return out, None, 0
+        outcomes.labels(outcome="tightened").inc()
+        out_cap = tight.pop(_OUT_NODE)
+        return None, tight, out_cap
+
     def _run_pinned(
         self, cq: CompiledQuery, pins, staged=None
     ) -> Tuple[Batch, Dicts]:
@@ -2756,25 +2882,20 @@ class PhysicalExecutor:
             if cq.steady is st:
                 cq.steady = None
 
+        out, caps, out_cap = self._steady_first(cq, inputs, shape_key)
+        if out is not None:
+            return out, cq.out_dicts  # the first program is the steady one
         for _attempt in range(8):
-            out, caps = self._discover(cq, inputs)
-            nvalid = int(jax.device_get(_count_valid(out.row_valid)))
-            out_cap = min(_cap_tile(max(nvalid, 1)), out.capacity)
+            if caps is None:
+                out, caps = self._discover(cq, inputs)
+                nvalid = int(jax.device_get(_count_valid(out.row_valid)))
+                out_cap = min(_cap_tile(max(nvalid, 1)), out.capacity)
             full_caps = dict(caps)
             full_caps[_OUT_NODE] = out_cap
             cq.caps = dict(full_caps)  # warm-start hint for _discover
-            program = self._make_program(cq, dict(caps))
-            jitted = watched_jit(
-                lambda i, pv, _p=program, _oc=out_cap: _steady_step(
-                    _p, _oc, i, pv, mesh=self.mesh
-                ),
-                sig=("steady", cq.sig),
-            )
             # compile + run the steady program now so every later run is a
             # single launch + single fetch
-            out, needs_host = _launch_and_fetch(
-                jitted, inputs, self._params()
-            )
+            jitted, out, needs_host = self._steady_at(cq, caps, out_cap, inputs)
             if not _overflowed(needs_host, full_caps):
                 # verified: publish the consistent snapshot atomically
                 cq.steady = (jitted, full_caps, shape_key)
@@ -2786,6 +2907,7 @@ class PhysicalExecutor:
                 if nid in caps and int(n) > caps[nid]:
                     caps[nid] = _cap_tile(int(n))
             cq.caps = dict(caps)
+            caps = None
         raise ExecError("capacity discovery did not converge")
 
     def run_analyze(
@@ -3022,6 +3144,36 @@ def _merge_shuffle_stats(lines: List[str], stage, infos) -> List[str]:
 
 # pseudo node id for the final output's compaction capacity
 _OUT_NODE = -1
+# pseudo node ids of what a mesh program's exchanges sent (never knobs)
+_EXCHANGES, _EXCHANGE_ROWS, _EXCHANGE_BYTES = -2, -3, -4
+
+
+def _take_exchange_stats(needs_host: dict) -> dict:
+    """Take what the exchanges sent out of a mesh program's fetched
+    scalars, onto the statement's flight and the registry; what is left
+    are the cardinalities of the knobs."""
+    if _EXCHANGES not in needs_host:
+        return needs_host
+    needs_host = dict(needs_host)
+    count, rows, nbytes = (
+        int(needs_host.pop(k))
+        for k in (_EXCHANGES, _EXCHANGE_ROWS, _EXCHANGE_BYTES)
+    )
+    from tidb_tpu.utils.metrics import REGISTRY
+
+    REGISTRY.counter(
+        "tidbtpu_executor_exchange_rows_total",
+        "valid rows the executed mesh programs' exchanges sent, "
+        "summed over shards",
+    ).inc(rows)
+    REGISTRY.counter(
+        "tidbtpu_executor_exchange_bytes_total",
+        "bytes those rows must carry between chips: rows x the "
+        "travelling columns' logical width x the share that leaves "
+        "its chip",
+    ).inc(nbytes)
+    FLIGHT.note_exchanges(count, rows, nbytes)
+    return needs_host
 
 
 def _steady_step(program, out_cap, inputs, params=None, mesh=None):
@@ -3033,14 +3185,17 @@ def _steady_step(program, out_cap, inputs, params=None, mesh=None):
     out, needs = program(inputs, params)
     needs = dict(needs)
     needs[_OUT_NODE] = _count_valid(out.row_valid)
+    if mesh is not None:
+        # replicated whether or not it is compacted: every process of a
+        # mesh that spans hosts fetches the answer, and an output tile
+        # from an estimate (_steady_first) may be the whole capacity
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from tidb_tpu.parallel.mesh import reshard
+
+        repl = NamedSharding(mesh, P())
+        out = jax.tree.map(lambda a: reshard(a, repl), out)
     if out_cap < out.capacity:
-        if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            from tidb_tpu.parallel.mesh import reshard
-
-            repl = NamedSharding(mesh, P())
-            out = jax.tree.map(lambda a: reshard(a, repl), out)
         out = _compact_impl(out, out_cap)
     return out, needs
 
@@ -3070,7 +3225,7 @@ def _launch_and_fetch(jitted, inputs, params):
     with FLIGHT.span("device-wait"):
         jax.block_until_ready((needs, out))
     with FLIGHT.span("fetch"):
-        needs_host = jax.device_get((needs, out))[0]
+        needs_host = _take_exchange_stats(jax.device_get((needs, out))[0])
         ENGINE_WATCH.d2h_batch(out)
     return out, needs_host
 

@@ -3,8 +3,8 @@
 Reference: pkg/server/server.go:429 (Server.Run accept loop) +
 conn.go:1009 (clientConn.Run read-dispatch loop), one goroutine per
 connection; here one thread per connection, all sharing the catalog (the
-device engine serializes on the single jit dispatch path, matching one
-TPU chip per process; multi-chip serving shards sessions across hosts).
+device engine serializes on the single jit dispatch path: one chip per
+process, or with `mesh_devices=N` the N chips of one host as one mesh).
 """
 
 from __future__ import annotations
@@ -41,8 +41,19 @@ class Server:
         port: int = 4000,
         status_port: Optional[int] = None,
         dcn_scheduler=None,
+        mesh_devices: Optional[int] = None,
     ):
         self.catalog = catalog or Catalog()
+        # MPP mode (tidb_server --mesh-devices N): every connection's
+        # session runs its statements as one SPMD program over the
+        # process's ONE mesh of N devices and its one set of resident
+        # shards. A width JAX cannot give raises here, before anything
+        # is served; None is the one-device server
+        self.mesh_devices = mesh_devices or None
+        if self.mesh_devices:
+            from tidb_tpu.parallel.mesh import shared_mesh
+
+            shared_mesh(self.mesh_devices)
         self.host = host
         self.port = port
         # serving tier (PR 8): with a DCNFragmentScheduler attached,
@@ -129,7 +140,7 @@ class Server:
         with self._lock:
             self._next_conn_id[0] += 1
             conn_id = self._next_conn_id[0]
-        sess = Session(self.catalog)
+        sess = Session(self.catalog, mesh_devices=self.mesh_devices)
         version = str(sess.vars.get("version"))
         scramble = P.new_scramble()
         io.write_packet(P.handshake_v10(conn_id, version, scramble))

@@ -148,11 +148,14 @@ def scan_table(
             np.ones(block.nrows, dtype=bool),
             None,
         )
-    batch = block_to_batch(block, cap)
+    sharding = None
     if mesh is not None:
-        from tidb_tpu.parallel.mesh import shard_batch
+        from jax.sharding import NamedSharding
 
-        batch = shard_batch(batch, mesh)
+        from tidb_tpu.parallel.mesh import batch_spec
+
+        sharding = NamedSharding(mesh, batch_spec())
+    batch = block_to_batch(block, cap, sharding=sharding)
     # drop cached batches of older versions of this table
     for k in [k for k in _scan_cache if k[0] == uid and k[1] != v]:
         del _scan_cache[k]
