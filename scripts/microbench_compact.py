@@ -14,6 +14,24 @@ Isolates, at Q5's two SF1 shapes (6,291,456 -> 2,097,152 and
            and against the scatter a column the join used to pay
 
     chiprun -- python scripts/microbench_compact.py
+
+`exchange-pack` (PERF.md, PR 30) isolates the send buffers of
+`parallel/exchange.exchange_by_target` at the mesh Q5's three SF1
+shapes (`cap` rows a shard, `n` destinations, `B` slots a bucket), with
+five and with three int64 columns and their validity word: slot
+`b * B + s` holds row `perm[start[b] + s]` of the `(destination, row)`
+sort, so the buffer is
+
+  scatter   the form deleted: `arr[perm]`, then one scatter a column
+  gather    one gather a column through the composed index
+            `perm[start[b] + s]`, itself a gather of `n * B` from perm
+            (or `n` slices of the padded perm)
+  slices    `arr[perm]` a column, then `n` dynamic slices of `B` rows
+  stacked   the columns' u32 limbs and the word as one [cap, lanes]
+            operand (or [lanes, cap]) moved by ONE row-gather, unpacked
+            to int64 again on the far side of the all-to-all
+
+    chiprun -- python scripts/microbench_compact.py exchange-pack
 """
 
 import os
@@ -26,12 +44,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tidb_tpu.executor.sortops import compaction_index, merge_searchsorted
+from tidb_tpu.executor.sortops import (
+    bits_for,
+    compaction_index,
+    merge_searchsorted,
+    sort_rows,
+    unpack_lex,
+)
+from tidb_tpu.parallel.exchange import _from_lanes, _to_lanes
 from tidb_tpu.utils.backend import backend_label, enable_compile_cache
 
 jax.config.update("jax_enable_x64", True)
 
 SHAPES = [(6_291_456, 2_097_152, 0.29), (2_097_152, 262_144, 0.11)]
+# (cap, n, B): Join#6's lineitem and orders sides, Join#5's (PERF.md, PR 29)
+PACK_SHAPES = [(524_288, 4, 131_072), (393_216, 4, 131_072), (65_536, 4, 16_384)]
 
 
 def timeit(name, fn, *args, reps=5):
@@ -53,9 +80,7 @@ def merge_index(valid, out_cap):
     return merge_searchsorted(cum, jnp.arange(out_cap, dtype=jnp.int32), "right")
 
 
-def main():
-    enable_compile_cache()
-    print("backend:", backend_label(), flush=True)
+def compact():
     rng = np.random.default_rng(28)
     for cap, out_cap, density in SHAPES:
         tag = f"{cap} -> {out_cap}"
@@ -116,5 +141,132 @@ def main():
                jax.jit(scatter), d64[0], valid, reps=2)
 
 
+def exchange_pack():
+    rng = np.random.default_rng(30)
+    for cap, n, B in PACK_SHAPES:
+        # six rows in ten are valid and spread evenly: the buckets fill
+        # to 0.6 of a tight tile, as Join#6's do (77-81 k of 131,072)
+        target = jnp.asarray(
+            np.where(rng.random(cap) < 0.6, rng.integers(0, n, cap), n).astype(np.int32)
+        )
+
+        def prelude(target):
+            ops, where, perm = sort_rows([(target, bits_for(n + 1))], cap)
+            sorted_t = unpack_lex(ops, where, 0).astype(jnp.int32)
+            start = jnp.searchsorted(sorted_t, jnp.arange(n + 1, dtype=jnp.int32))
+            return perm, sorted_t, start.astype(jnp.int32)
+
+        perm, sorted_t, start = timeit(
+            f"sort (destination, row)  cap {cap}", jax.jit(prelude), target
+        )
+
+        def slots(start):
+            s = jnp.arange(B, dtype=jnp.int32)
+            count = jnp.minimum(start[1:] - start[:n], B)
+            filled = s[None, :] < count[:, None]
+            return jnp.minimum(start[:n, None] + s[None, :], cap - 1), filled
+
+        def index_gather(perm, start):
+            pos, filled = slots(start)
+            return perm[pos], filled
+
+        def index_slices(perm, start):
+            padded = jnp.concatenate([perm, jnp.zeros(B, perm.dtype)])
+            return sliced(padded, start), slots(start)[1]
+
+        def sliced(padded, start):
+            return jnp.stack([
+                jax.lax.dynamic_slice_in_dim(padded, start[b], B) for b in range(n)
+            ])
+
+        def masked(filled, x):
+            return jnp.where(filled.reshape(filled.shape + (1,) * (x.ndim - 2)), x, 0)
+
+        def by_scatter(perm, sorted_t, start, arrs):
+            slot = jnp.arange(cap, dtype=jnp.int32) - start[jnp.clip(sorted_t, 0, n)]
+            fits = (slot < B) & (sorted_t < n)
+            at = jnp.where(
+                fits, jnp.clip(sorted_t, 0, n - 1) * B + jnp.clip(slot, 0, B - 1), n * B
+            )
+            return [
+                jnp.zeros(n * B, a.dtype).at[at].set(a[perm], mode="drop").reshape(n, B)
+                for a in arrs
+            ]
+
+        def by_gather(index):
+            def run(perm, sorted_t, start, arrs):
+                idx, filled = index(perm, start)
+                return [masked(filled, a[idx]) for a in arrs]
+            return run
+
+        def by_gather_unmasked(perm, sorted_t, start, arrs):
+            idx, _filled = index_gather(perm, start)
+            return [a[idx] for a in arrs]
+
+        def by_slices(perm, sorted_t, start, arrs):
+            filled = slots(start)[1]
+            return [
+                masked(filled, sliced(
+                    jnp.concatenate([a[perm], jnp.zeros(B, a.dtype)]), start))
+                for a in arrs
+            ]
+
+        def stacked(axis, index=index_gather, unpack=True):
+            def run(perm, sorted_t, start, arrs):
+                lanes = [l for a in arrs[:-1] for l in _to_lanes(a)] + [arrs[-1]]
+                idx, filled = index(perm, start)
+                if axis == 1:
+                    got = masked(filled, jnp.stack(lanes, axis=1)[idx])  # [n, B, L]
+                    got = [got[..., i] for i in range(len(lanes))]
+                else:
+                    got = jnp.stack(lanes, axis=0)[:, idx]  # [L, n, B]
+                    got = [masked(filled, got[i]) for i in range(len(lanes))]
+                if not unpack:
+                    return got
+                return [
+                    _from_lanes(got[2 * i:2 * i + 2], jnp.int64)
+                    for i in range(len(arrs) - 1)
+                ] + [got[-1]]
+            return run
+
+        def stacked_slices(perm, sorted_t, start, arrs):
+            lanes = [l for a in arrs[:-1] for l in _to_lanes(a)] + [arrs[-1]]
+            rows = jnp.stack(lanes, axis=1)[perm]
+            rows = jnp.concatenate([rows, jnp.zeros((B, len(lanes)), rows.dtype)])
+            got = masked(slots(start)[1], jnp.stack([
+                jax.lax.dynamic_slice_in_dim(rows, start[b], B) for b in range(n)
+            ]))
+            return [
+                _from_lanes([got[..., 2 * i], got[..., 2 * i + 1]], jnp.int64)
+                for i in range(len(arrs) - 1)
+            ] + [got[..., -1]]
+
+        for k in (5, 3):
+            tag = f"{k} x int64 + word  cap {cap} n {n} B {B}"
+            arrs = [jnp.asarray(rng.integers(-(1 << 40), 1 << 40, cap)) for _ in range(k)]
+            arrs.append(jnp.asarray(rng.integers(0, 1 << (k + 1), cap).astype(np.uint32)))
+            want = timeit(f"scatter a column (the form deleted)  {tag}",
+                          jax.jit(by_scatter), perm, sorted_t, start, arrs, reps=2)
+            forms = [
+                ("gather a column, index perm[start[b]+s]", by_gather(index_gather)),
+                ("gather a column, index by slices of perm", by_gather(index_slices)),
+                ("gather a column, no empty-slot select", by_gather_unmasked),
+                ("arr[perm] a column, then n slices", by_slices),
+                ("stacked [cap, lanes] row-gather", stacked(1)),
+                ("stacked [cap, lanes], index by slices", stacked(1, index_slices)),
+                ("stacked [cap, lanes], limbs not rejoined", stacked(1, unpack=False)),
+                ("stacked [lanes, cap] lane-gather", stacked(0)),
+                ("stacked [cap, lanes][perm], then n slices", stacked_slices),
+            ]
+            for name, fn in forms:
+                got = timeit(f"{name}  {tag}", jax.jit(fn), perm, sorted_t, start, arrs)
+                if "no empty-slot" in name or "not rejoined" in name:
+                    continue
+                for w, g in zip(want, got):
+                    assert (np.asarray(w) == np.asarray(g)).all(), name
+
+
 if __name__ == "__main__":
-    main()
+    enable_compile_cache()
+    print("backend:", backend_label(), flush=True)
+    exchange_pack() if sys.argv[1:] == ["exchange-pack"] else compact()

@@ -167,6 +167,21 @@ def _steady_q5(dep, sql):
     return sess.executor, cq
 
 
+def _scatter_scopes(lowered):
+    """{operator scope: most slots} of every scatter in a program's
+    StableHLO, printed with debug_info."""
+    import re
+
+    named = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', lowered, re.M))
+    scopes = {}
+    for slots, loc in re.findall(
+        r'"stablehlo\.scatter"\(.*?\}\) : \([^)]*\) -> tensor<(\d+)x\w+> loc\((#loc\d+)\)',
+        lowered, re.S,
+    ):
+        scopes[named[loc]] = max(scopes.get(named[loc], 0), int(slots))
+    return scopes
+
+
 def test_the_steady_q5_holds_an_all_to_all(bench, mesh):
     import jax
 
@@ -185,6 +200,12 @@ def test_the_steady_q5_holds_an_all_to_all(bench, mesh):
     lowered = jax.jit(executor._make_program(cq, caps)).lower(inputs, {}).as_text(debug_info=True)
     for scope in ("exchange/sort", "exchange/pack", "exchange/all-to-all", "broadcast/all-gather"):
         assert scope in lowered, scope
+    # the send buffers are gathers (PERF.md, PR 30: their scatters were
+    # 321 ms of a 455-ms statement on the v5e). What scatters is a dense
+    # table of a few slots: the nation and region lookups, the groups
+    scopes = _scatter_scopes(lowered)
+    assert scopes and not [s for s in scopes if "exchange" in s], scopes
+    assert all(slots <= 32 for slots in scopes.values()), scopes
 
 
 def test_a_scanned_column_has_a_shard_on_each_of_four_devices(mesh):
@@ -351,6 +372,31 @@ def test_exchange_counters_equal_a_numpy_count(two_tables, floor):
     assert (flight["exchanges"], flight["exchange_rows"], flight["exchange_bytes"]) == (2, rows, nbytes)
     after = [REGISTRY.counter(name).value for name in counters]
     assert [a - b for a, b in zip(after, before)] == [rows, nbytes]
+
+
+def test_a_join_s_own_keys_stay_behind_its_parent_s_exchange(two_tables):
+    """a x b emits a.k, a.v, b.k, b.w; the join above reads a.v, b.k and
+    b.w of it. Column pruning says so on the plan (JoinPlan.needs) and
+    the upper join's exchange moves those three, not four: a row's
+    columns travel as lanes of one operand, out of which XLA drops no
+    dead one (PERF.md, PR 30)."""
+    ici = _load(os.path.join(BENCH, "ici.py"), "bench_ici_for_mesh_tests")
+    tables, client = two_tables
+    a, b = tables["a"], tables["b"]
+    sql = ("select a.v, c.v from a join b on a.k = b.k "
+           "join a c on b.w + 4000 * (b.k % 2) = c.k")
+    lower = np.argwhere(a["k"][:, None] == b["k"][None, :])
+    upper = (b["w"] + 4000 * (b["k"] % 2))[:, None] == a["k"][None, :]
+    want = sorted(
+        (int(a["v"][i]), int(a["v"][m])) for i, j in lower for m in np.nonzero(upper[j])[0])
+    for _ in range(2):  # the second is the steady program alone
+        got = client.query(sql)
+    assert sorted((int(x), int(y)) for x, y in got) == want
+    flight = _last_flight(sql)
+    sides = [(ROWS_A, 2), (ROWS_B, 2), (len(lower), 3), (ROWS_A, 2)]
+    assert (flight["exchanges"], flight["exchange_rows"]) == (4, sum(rows for rows, _ in sides))
+    assert flight["exchange_bytes"] == sum(
+        ici.partition_bytes(rows, cols * 8, WIDTH) for rows, cols in sides)
 
 
 # ---------------------------------------------------------------------------

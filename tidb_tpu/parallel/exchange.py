@@ -102,8 +102,9 @@ def hash_repartition(
     """Redistribute rows so equal keys colocate. Per-shard view:
 
     1. target[i] = mix(key[i]) % n                  (hash partition fn)
-    2. sort rows by target; slot = rank within bucket
-    3. scatter into an [n, B] send buffer (overflow slots drop)
+    2. sort (target, row id): perm, and where each bucket starts
+    3. gather the [n, B] send buffer through perm: slot s of bucket b
+       is row perm[start[b] + s] (rows past a bucket's B slots drop)
     4. lax.all_to_all exchanges bucket j to device j
     5. flatten received [n, B] to a new local batch of capacity n*B
 
@@ -172,15 +173,34 @@ def _pack_bits(bit0: jax.Array, flags) -> jax.Array:
     return word
 
 
-def _widened(arr: jax.Array) -> jax.Array:
-    """An operand of under 32 bits as 32 (see exchange_by_target)."""
-    if arr.dtype.itemsize >= 4:
-        return arr
-    if arr.dtype == jnp.bool_ or jnp.issubdtype(arr.dtype, jnp.unsignedinteger):
-        return arr.astype(jnp.uint32)
-    if jnp.issubdtype(arr.dtype, jnp.integer):
-        return arr.astype(jnp.int32)
-    return arr.astype(jnp.float32)
+def _to_lanes(arr: jax.Array):
+    """A column's values as u32 lanes, exactly: one lane for 32 bits or
+    fewer (a narrow value widened first), the high and the low limb of
+    a 64-bit integer. None for a float64, which the v5e compiler cannot
+    bitcast: it travels beside the lanes."""
+    dt = arr.dtype
+    if dt == jnp.float64:
+        return None
+    if dt.itemsize == 8:
+        u = jax.lax.bitcast_convert_type(arr, jnp.uint64)
+        return [(u >> jnp.uint64(32)).astype(jnp.uint32), u.astype(jnp.uint32)]
+    if dt == jnp.bool_ or jnp.issubdtype(dt, jnp.unsignedinteger):
+        return [arr.astype(jnp.uint32)]
+    wide = jnp.int32 if jnp.issubdtype(dt, jnp.integer) else jnp.float32
+    return [jax.lax.bitcast_convert_type(arr.astype(wide), jnp.uint32)]
+
+
+def _from_lanes(lanes, dtype) -> jax.Array:
+    """Inverse of _to_lanes."""
+    dt = jnp.dtype(dtype)
+    if dt.itemsize == 8:
+        hi, lo = (x.astype(jnp.uint64) for x in lanes)
+        return jax.lax.bitcast_convert_type((hi << jnp.uint64(32)) | lo, dt)
+    (u,) = lanes
+    if dt == jnp.bool_ or jnp.issubdtype(dt, jnp.unsignedinteger):
+        return u.astype(dt)
+    wide = jnp.int32 if jnp.issubdtype(dt, jnp.integer) else jnp.float32
+    return jax.lax.bitcast_convert_type(u, wide).astype(dt)
 
 
 def exchange_by_target(
@@ -203,8 +223,8 @@ def exchange_by_target(
     over-provisioned first tile shrinks to it; in steady state the
     plan-cache keeps the discovered capacity and nothing recompiles.
 
-    On the device trace the stages sit in `exchange/sort` (bucket and
-    slot of each row), `exchange/pack` (the send buffers) and
+    On the device trace the stages sit in `exchange/sort` (the order of
+    the rows by bucket), `exchange/pack` (the send buffers) and
     `exchange/all-to-all` inside the operator's scope
     (scripts/trace_by_scope.py sums them)."""
     B = bucket_capacity
@@ -224,63 +244,72 @@ def exchange_by_target(
             sorted_t = unpack_lex(ops, where, 0).astype(jnp.int32)
             start = jnp.searchsorted(
                 sorted_t, jnp.arange(n + 1, dtype=jnp.int32)
-            )
-            slot = (
-                jnp.arange(cap, dtype=jnp.int32)
-                - start[jnp.clip(sorted_t, 0, n)]
-            )
-            fits = (slot < B) & (sorted_t < n)
-            buf_idx = (
-                jnp.clip(sorted_t, 0, n - 1) * B + jnp.clip(slot, 0, B - 1)
-            )
-
-            sent = jnp.sum(fits.astype(jnp.int64))
+            ).astype(jnp.int32)
+            # this shard's bucket sizes (start deltas), of which a
+            # bucket sends its first B rows; the fullest bucket of any
+            # shard is what B has to hold
+            local_counts = start[1 : n + 1] - start[:n]
+            kept = jnp.minimum(local_counts, B)
+            sent = jnp.sum(kept.astype(jnp.int64))
             valid_rows = jnp.sum((target < n).astype(jnp.int64))
             all_valid = jax.lax.psum(valid_rows, axis)
             dropped = all_valid - jax.lax.psum(sent, axis)
-            # this shard's bucket sizes (start deltas); the fullest
-            # bucket of any shard is what B has to hold
-            local_counts = (start[1 : n + 1] - start[:n]).astype(jnp.int64)
-            need = pmax(jnp.max(local_counts), axis)
+            need = pmax(jnp.max(local_counts).astype(jnp.int64), axis)
         _note_exchange(kind, batch, all_valid, (n - 1, n))
-
-        def scatter(arr: jax.Array) -> jax.Array:
-            src = arr[perm]
-            buf = jnp.zeros((n * B,), dtype=arr.dtype)
-            buf = buf.at[jnp.where(fits, buf_idx, n * B)].set(src, mode="drop")
-            return buf.reshape(n, B)
 
         names = list(batch.cols)
         with jax.named_scope("pack"):
-            # Nothing 8 bits wide is scattered: the v5e compiler takes
-            # 10-15 s for every scatter of a bool or u8 operand, whatever
-            # its length, and 0.1 s for a 32-bit one (PERF.md, PR 29; a
-            # mesh Q5 had six such shapes in each of its two programs).
-            # The row's presence and every column's validity travel as
-            # the bits of u32 words, 31 columns a word, and a narrow
-            # data column travels widened.
+            # Slot s of bucket b is row perm[start[b] + s] where the
+            # bucket holds more than s rows, and empty otherwise: the
+            # buffer's row index is n slices of perm, and the buffer a
+            # gather through it. A scatter pays 69 ns an INPUT row on
+            # the v5e, serially; a gather 5 ns an output row, and no
+            # more for a row of many lanes than for a row of one. So
+            # all that a row carries travels as u32 lanes of ONE
+            # operand, moved by one gather and one all-to-all: 2.9 ms
+            # for five int64 columns of 524,288 rows against 228 ms for
+            # a scatter and 42 ms for a gather a column (PERF.md, PR
+            # 30). The row's presence and every column's validity are
+            # the bits of lanes too, 31 columns a word: nothing 8 bits
+            # wide is moved, which the v5e compiler takes 10-15 s to
+            # compile into a scatter (PERF.md, PR 29).
+            padded = jnp.concatenate([perm, jnp.zeros((B,), perm.dtype)])
+            row = jnp.stack([
+                jax.lax.dynamic_slice_in_dim(padded, start[b], B)
+                for b in range(n)
+            ])
+            filled = jnp.arange(B, dtype=jnp.int32)[None, :] < kept[:, None]
             present = jnp.ones((cap,), dtype=jnp.uint32)
-            words = [
-                scatter(_pack_bits(
+            lanes = [
+                _pack_bits(
                     present, [batch.cols[c].valid for c in names[at:at + 31]]
-                ))
+                )
                 for at in range(0, max(len(names), 1), 31)
             ]
-            send = [
-                scatter(_widened(batch.cols[c].data)) for c in names
-            ]
+            span, apart = {}, {}
+            for c in names:
+                limbs = _to_lanes(batch.cols[c].data)
+                if limbs is None:
+                    apart[c] = jnp.where(filled, batch.cols[c].data[row], 0)
+                else:
+                    span[c] = (len(lanes), len(lanes) + len(limbs))
+                    lanes += limbs
+            send = jnp.where(filled, jnp.stack(lanes)[:, row], 0)
         with jax.named_scope("all-to-all"):
-            words = [
-                jax.lax.all_to_all(w, axis, 0, 0).reshape(n * B) for w in words
-            ]
-            rv = (words[0] & 1) != 0
+            got = jax.lax.all_to_all(send, axis, 1, 1).reshape(-1, n * B)
+            rv = (got[0] & 1) != 0
             new_cols = {}
-            for i, (name, d) in enumerate(zip(names, send)):
-                d = jax.lax.all_to_all(d, axis, 0, 0).reshape(n * B)
-                v = ((words[i // 31] >> (1 + i % 31)) & 1) != 0
-                new_cols[name] = DevCol(
-                    d.astype(batch.cols[name].data.dtype), v
-                )
+            for i, name in enumerate(names):
+                if name in apart:
+                    d = jax.lax.all_to_all(apart[name], axis, 0, 0).reshape(n * B)
+                else:
+                    lo, hi = span[name]
+                    d = _from_lanes(
+                        [got[k] for k in range(lo, hi)],
+                        batch.cols[name].data.dtype,
+                    )
+                v = ((got[i // 31] >> (1 + i % 31)) & 1) != 0
+                new_cols[name] = DevCol(d, v)
     return Batch(new_cols, rv), dropped, need
 
 

@@ -253,12 +253,24 @@ def repartition_pair(
     n_devices: int,
     bucket_capacity: int,
     axis: str = "d",
+    keep=None,
 ) -> Tuple[Batch, Batch, jax.Array, jax.Array]:
     """Hash-partition both join sides on their keys so equal keys
     colocate (the MPP HashPartition exchange applied to a join pair).
     Returns (left', right', global dropped rows, true per-bucket need
     over BOTH sides — the retry-at-exact-size signal). The single
-    shared composition used by both partitioned_join and the planner."""
+    shared composition used by both partitioned_join and the planner.
+
+    `keep`: the (left, right) column names the join and its readers
+    use (JoinPlan.needs); the other columns stay behind. The exchange
+    moves a row's columns as lanes of one operand, from which XLA can
+    drop no dead column, nor then the work below that made it: 60 ms of
+    a mesh Q5's 209 on the v5e (PERF.md, PR 30)."""
+    if keep is not None:
+        left, right = (
+            Batch({c: v for c, v in b.cols.items() if c in k}, b.row_valid)
+            for b, k in zip((left, right), keep)
+        )
     lex, d1, n1 = hash_repartition(left, left_key, n_devices, bucket_capacity, axis)
     rex, d2, n2 = hash_repartition(right, right_key, n_devices, bucket_capacity, axis)
     return lex, rex, d1 + d2, jnp.maximum(n1, n2)

@@ -177,6 +177,13 @@ class JoinPlan(LogicalPlan):
     # mark join only: name of the boolean result column appended to the
     # probe schema (expression_rewriter.go LeftOuterSemiJoin analog)
     mark_name: Optional[str] = None
+    # set by column pruning: the internal names that this join and the
+    # operators above it read of its (left, right) input. An input may
+    # deliver more (a join below emits its own keys too), which XLA
+    # drops as dead code on one device; a mesh's exchange moves a
+    # row's columns as one operand, so the planner hands it only these
+    # (physical.py). None: not known, every column travels.
+    needs: Optional[Tuple[frozenset, frozenset]] = None
 
 
 @dataclasses.dataclass
@@ -2269,6 +2276,7 @@ def prune_plan(plan: LogicalPlan, required: set, catalog=None) -> LogicalPlan:
         return JoinPlan(
             sch, plan.kind, left, right, plan.equi_keys, plan.residual,
             plan.null_aware, plan.broadcast, plan.mark_name,
+            (frozenset(lneed), frozenset(rneed)),
         )
     if isinstance(plan, Sort):
         need = set(required)

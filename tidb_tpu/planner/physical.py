@@ -254,6 +254,9 @@ def plan_fingerprint(plan: L.LogicalPlan) -> str:
                 # schema — without it two same-shaped IN-subqueries would
                 # collide in the subtree memo (_build)
                 + str(getattr(p, "mark_name", None))
+                # what a mesh join's exchange carries: two joins alike
+                # but for their readers must not share one subtree
+                + repr(p.needs and [sorted(side) for side in p.needs])
             )
         elif isinstance(p, L.Sort):
             parts.append(repr(p.keys))
@@ -1838,7 +1841,7 @@ class PlanCompiler:
 
                         B = caps[part_nid]
                         lb, rb, _drp, xneed = repartition_pair(
-                            lb, rb, lkey, rkey, mesh, B
+                            lb, rb, lkey, rkey, mesh, B, keep=plan.needs
                         )
                         # the TRUE per-bucket need, in both directions:
                         # a hot key costs ONE recompile at the exact
@@ -1903,7 +1906,7 @@ class PlanCompiler:
 
                         B = caps[part_nid]
                         lb, rb, _drp, xneed = repartition_pair(
-                            lb, rb, lkey, rkey, mesh, B
+                            lb, rb, lkey, rkey, mesh, B, keep=plan.needs
                         )
                         needs[part_nid] = xneed
                     brow, matched, stale = lookup_build_rows(
@@ -2110,7 +2113,7 @@ class PlanCompiler:
 
                 B = caps[part_nid]
                 lb, rb, _drp, xneed = repartition_pair(
-                    lb, rb, lkey, rkey, mesh, B
+                    lb, rb, lkey, rkey, mesh, B, keep=plan.needs
                 )
                 needs[part_nid] = xneed
             build_b, probe_b, build_k, probe_k = rb, lb, rkey, lkey
