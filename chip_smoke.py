@@ -7,9 +7,7 @@ the TPC-H SF1 catalog with the bootstrap `tidb_server.main` uses, starts
 process, and drives it over a real socket with the dependency-free
 MySQL client: ANALYZE, then Q6, Q1, Q18, Q5 cold (with compile) then
 warm, each answer compared with the numpy oracle of bench.py, then an
-INSERT that is
-acknowledged and must be read back by Q6. Before that, both Pallas
-kernels run compiled against their references (information only).
+INSERT that is acknowledged and must be read back by Q6.
 
 `--mesh 4` (four chips, run by hand) runs ONLY the multi-chip path and
 what it is compared with: Q1, Q18, Q5 over a socket of the server that
@@ -85,8 +83,8 @@ class CompileMeter:
 
 
 class LoweringCounter:
-    """Which TPU-gated lowering a statement's trace went through:
-    counts calls of the gated entry points while programs are traced
+    """Which lowering a statement's trace went through: counts calls of
+    the formulations' entry points while programs are traced
     (tracing runs in this process, on the serving thread)."""
 
     SITES = {
@@ -239,49 +237,6 @@ class Oracle:
 # ---------------------------------------------------------------------------
 # phases
 # ---------------------------------------------------------------------------
-
-
-def kernels_phase() -> None:
-    """Both Pallas kernels compiled (interpret=False) at the flagship
-    shapes against their references. Information, under no claim."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from tidb_tpu.executor.pallas_kernels import (
-        prefix_sum_i32, prefix_sum_reference, slot_sums_f32,
-        slot_sums_reference,
-    )
-
-    def timed(fn, *args):
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(fn(*args))
-        cold = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        return out, cold, time.perf_counter() - t0
-
-    rng = np.random.default_rng(0)
-    lanes, n, slots = 8, 6_001_215, 8
-    vals = jnp.asarray(rng.integers(0, 100, (lanes, n)), dtype=jnp.float32)
-    contrib = jnp.asarray(rng.random((lanes, n)) < 0.9)
-    seg = jnp.asarray(rng.integers(0, slots + 1, n), dtype=jnp.int32)
-    got, cold, warm = timed(
-        lambda v, c, s: slot_sums_f32(v, c, s, slots), vals, contrib, seg
-    )
-    want = np.asarray(slot_sums_reference(vals, contrib, seg, slots))
-    rel = float(np.max(np.abs(np.asarray(got) - want) / np.maximum(np.abs(want), 1.0)))
-    require(got.shape == (lanes, slots) and rel < 1e-4, ("slot_sums_f32", rel))
-    emit(phase="kernels", kernel="slot_sums_f32", shape=[lanes, n], slots=slots,
-         cold_s=round(cold, 4), warm_s=round(warm, 6),
-         max_rel_err_vs_f64=rel, correct=True)
-
-    m = 2**23
-    mask = jnp.asarray(rng.random(m) < 0.3)
-    got, cold, warm = timed(prefix_sum_i32, mask)
-    require(bool((got == prefix_sum_reference(mask)).all()), "prefix_sum_i32")
-    emit(phase="kernels", kernel="prefix_sum_i32", shape=[m],
-         cold_s=round(cold, 4), warm_s=round(warm, 6), correct=True)
 
 
 def serve_phase(args, meter: CompileMeter, lowerings: LoweringCounter) -> None:
@@ -543,7 +498,6 @@ def main() -> int:
         mesh_phase(args, meter)
         count = args.mesh
     else:
-        kernels_phase()
         serve_phase(args, meter, LoweringCounter())
         count = len(jax.devices())
     total = meter.since((0.0, 0, 0))

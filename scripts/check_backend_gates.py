@@ -1,11 +1,14 @@
 #!/usr/bin/env python
-"""Lint: one gate for the backend, no private JAX, no swallowed device query.
+"""Lint: one question for the backend, no private JAX, no swallowed
+device query, and an executor that asks neither backend nor shell.
 
-`utils/backend.is_tpu()` is THE gate for TPU-only engine paths: it asks
-`jax.devices()[0].platform` once. A `jax.default_backend() == "tpu"`
-string compare asks the PJRT plugin's own name instead, which a plugin
-is free to spell differently — such a gate then silently disables every
-TPU-only lowering on the hardware it was written for.
+`utils/backend.is_tpu()` is THE way engine code asks what it runs on:
+it asks `jax.devices()[0].platform` once. A `jax.default_backend() ==
+"tpu"` string compare asks the PJRT plugin's own name instead, which a
+plugin is free to spell differently. And the executor asks not at all:
+it lowers an operator one way, chosen from the shapes and widths it can
+see when the program is traced, so the tests run the kernels the chip
+runs.
 
 Rules:
   1. anywhere in the repo's .py files: `default_backend() == "tpu"`
@@ -20,7 +23,11 @@ Rules:
   4. inside tidb_tpu/: a device query (`jax.devices()`,
      `local_devices()`, `default_backend()`, `memory_stats()`, ...)
      inside a `try` whose handler catches Exception (or everything) is
-     an error — a failed device query is an error, not "CPU".
+     an error — a failed device query is an error, not "CPU";
+  5. inside tidb_tpu/executor/: a call of `is_tpu()` or
+     `default_backend()`, or a read of the process environment
+     (`os.environ`, `os.getenv`), is an error — which formulation of an
+     operator runs is no business of the platform's or the shell's.
 
 Usage: python scripts/check_backend_gates.py [root]
 Exit 0 = clean, 1 = violations (printed one per line).
@@ -101,6 +108,10 @@ def check(root: str):
             violations.extend(
                 (rel, line, msg) for line, msg in _swallowed_queries(lines)
             )
+        if rel.split(os.sep)[:2] == ["tidb_tpu", "executor"]:
+            violations.extend(
+                (rel, line, msg) for line, msg in _executor_asks(lines)
+            )
     return violations
 
 
@@ -139,6 +150,39 @@ def _swallowed_queries(lines):
                          "`except Exception`: a failed device query is an "
                          "error, not a fallback")
                     )
+    return out
+
+
+def _executor_asks(lines):
+    """(line, message) for every call of is_tpu() / default_backend()
+    and every read of the environment."""
+    try:
+        tree = ast.parse("".join(lines))
+    except SyntaxError:
+        return []
+    out = []
+    for node in ast.walk(tree):
+        if not hasattr(node, "lineno") or PRAGMA in lines[node.lineno - 1]:
+            continue
+        name = (
+            node.id if isinstance(node, ast.Name)
+            else node.attr if isinstance(node, ast.Attribute)
+            else None
+        )
+        if name in ("environ", "getenv"):
+            out.append(
+                (node.lineno, "the executor reads the process environment: "
+                 "a kernel is chosen from shapes and widths, not by the "
+                 "shell that started the process")
+            )
+        elif isinstance(node, ast.Call) and (
+            getattr(node.func, "id", None) or getattr(node.func, "attr", None)
+        ) in ("is_tpu", "default_backend"):
+            out.append(
+                (node.lineno, "the executor asks which backend it runs on: "
+                 "a kernel is chosen from shapes and widths, one lowering "
+                 "on every platform")
+            )
     return out
 
 

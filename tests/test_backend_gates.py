@@ -1,7 +1,8 @@
 """Tier-1 gate for scripts/check_backend_gates.py: the repo stays free
 of raw `== "tpu"` backend string compares (utils/backend.is_tpu() is
-the one sanctioned check), of imports of JAX's private package, and of
-device queries swallowed by a catch-all handler."""
+the one sanctioned check), of imports of JAX's private package, of
+device queries swallowed by a catch-all handler, and of an executor
+that asks the backend or the environment which kernel to take."""
 
 import os
 import subprocess
@@ -30,6 +31,22 @@ def test_lint_catches_violations(tmp_path):
         'OTHER = backend != "tpu"\n'                  # rule 2
         'OK = backend == "tpu"  # backend-gate-ok\n'  # pragma exempts
     )
+    asks = (
+        'import os\n'
+        'import jax\n'
+        'from tidb_tpu.utils.backend import is_tpu\n'
+        'def pick(m):\n'
+        '    if is_tpu():\n'                              # rule 5
+        '        return "merge"\n'
+        '    if jax.default_backend() != "cpu":\n'        # rule 5
+        '        return "merge"\n'
+        '    if os.environ.get("TIDB_TPU_X") == "1":\n'   # rule 5
+        '        return "merge"\n'
+        '    return "merge" if m >= 4096 else "search"\n' # a size: fine
+    )
+    (pkg / "executor").mkdir()
+    (pkg / "executor" / "bad_kernel_gate.py").write_text(asks)
+    (pkg / "planner_like.py").write_text(asks)  # rule 5 is executor-only
     (tmp_path / "outside.py").write_text(
         'x = store == "tpu"\n'  # outside tidb_tpu/: rule 2 not applied
     )
@@ -42,6 +59,11 @@ def test_lint_catches_violations(tmp_path):
     assert "bad_gate.py:3" in proc.stdout
     assert "bad_gate.py:4" not in proc.stdout
     assert "outside.py" not in proc.stdout
+    for line in (5, 7, 9):
+        assert f"bad_kernel_gate.py:{line}:" in proc.stdout, line
+    assert "bad_kernel_gate.py:11" not in proc.stdout
+    assert "bad_kernel_gate.py:3" not in proc.stdout  # the import alone asks nothing
+    assert "planner_like.py" not in proc.stdout
 
 
 def test_lint_catches_private_jax_and_swallowed_queries(tmp_path):
@@ -112,36 +134,6 @@ def test_device_budget_raises_on_unknown_kind(monkeypatch):
     assert streamed._device_budget() == 123
     monkeypatch.setattr(backend, "_IS_TPU", False)
     assert streamed._device_budget() == 4 << 30
-
-
-def test_opted_in_pallas_failure_raises(monkeypatch):
-    """TIDB_TPU_PALLAS=1: a kernel that fails raises — the jnp path never
-    answers in its place — and interpret mode on a TPU is an error."""
-    import jax.numpy as jnp
-    import pytest
-
-    import tidb_tpu.executor.pallas_kernels as PK
-    import tidb_tpu.utils.backend as backend
-    from tidb_tpu.executor.aggregate import _prefix_sum
-
-    mask = jnp.arange(64) % 3 == 0
-    assert PK.pallas_interpret() is None  # default: kernels off
-    monkeypatch.setenv("TIDB_TPU_PALLAS", "1")
-    assert PK.pallas_interpret() is None  # CPU without the hatch: no kernel
-    monkeypatch.setenv("TIDB_TPU_PALLAS_INTERPRET", "1")
-    assert PK.pallas_interpret() is True
-
-    def boom(*_a, **_k):
-        raise ValueError("kernel failed to lower")
-
-    monkeypatch.setattr(PK, "prefix_sum_i32", boom)
-    with pytest.raises(ValueError, match="failed to lower"):
-        _prefix_sum(mask)
-    monkeypatch.setattr(backend, "_IS_TPU", True)
-    with pytest.raises(RuntimeError, match="CPU tests"):
-        PK.pallas_interpret()
-    monkeypatch.delenv("TIDB_TPU_PALLAS_INTERPRET")
-    assert PK.pallas_interpret() is False  # compiled on the chip
 
 
 def test_compile_cache_placement(monkeypatch):

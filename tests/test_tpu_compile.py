@@ -2,12 +2,11 @@
 
 The TPU compiler is installed in the sandbox and compiles for a chip
 that is described, not attached (`v5e:2x2`). These tests compile — never
-run — the Pallas kernels and the lowerings only a TPU takes, at TPC-H
-SF1 shapes, so a refusal (a 64-bit op the X64 rewriter lacks, a kernel
-past VMEM, a program past 16 GB) or a compile too slow for a cold
-server start shows here at no chip time. The code under test asks
-`is_tpu()` and sees the CPU, so the gate is steered in the test (the
-cached flag in tidb_tpu.utils.backend), not through a program option.
+run — the executor's lowerings at TPC-H SF1 shapes, so a refusal (a
+64-bit op the X64 rewriter lacks, a program past 16 GB) or a compile
+too slow for a cold server start shows here at no chip time. Nothing is
+steered: the executor picks its kernels from the shapes it is handed,
+and these are the chip's.
 
 Rules this file keeps (on-chip-measurement guide, section 2): the
 topology is described inside a module-scoped fixture — never at import,
@@ -56,14 +55,6 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture
-def tpu_gates(monkeypatch):
-    """Steer every is_tpu() gate onto its TPU branch for one test."""
-    import tidb_tpu.utils.backend as backend
-
-    monkeypatch.setattr(backend, "_IS_TPU", True)
-
-
 def _sds(shape, dtype, sharding):
     import jax
 
@@ -110,38 +101,7 @@ def _fits_v5e(compiled):
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernels, compiled (interpret=False)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("slots", [8, 128])
-def test_slot_sums_kernel_compiles(one_chip, slots):
-    """[8, 6,001,215] f32 lanes; slots=128 is the widest the gate in
-    aggregate._try_pallas_slot_sums admits ([A,128] out block and the
-    [1024,128] one-hot are the VMEM question)."""
-    from tidb_tpu.executor.pallas_kernels import slot_sums_f32
-
-    compiled, _s = _compile(
-        lambda v, c, s: slot_sums_f32(v, c, s, slots),
-        _sds((8, LINEITEM_SF1), np.float32, one_chip),
-        _sds((8, LINEITEM_SF1), np.bool_, one_chip),
-        _sds((LINEITEM_SF1,), np.int32, one_chip),
-    )
-    assert "tpu_custom_call" in compiled.as_text()
-    _fits_v5e(compiled)
-
-
-def test_prefix_sum_kernel_compiles(one_chip):
-    """2**23: the dense-domain cap named in pallas_kernels.py."""
-    from tidb_tpu.executor.pallas_kernels import prefix_sum_i32
-
-    compiled, _s = _compile(prefix_sum_i32, _sds((2**23,), np.bool_, one_chip))
-    assert "tpu_custom_call" in compiled.as_text()
-    _fits_v5e(compiled)
-
-
-# ---------------------------------------------------------------------------
-# the flagship fragment: scatter form (CPU's branch) and masked backend
+# the flagship fragment, and Q1's aggregation through the masked reducer
 # ---------------------------------------------------------------------------
 
 
@@ -159,10 +119,10 @@ def test_q1_fragment_compiles(one_chip):
     _fits_v5e(compiled)
 
 
-def test_masked_backend_q1_shape_compiles(one_chip, tpu_gates, monkeypatch):
+def test_masked_backend_q1_shape_compiles(one_chip, monkeypatch):
     """Q1's real plan carries planner widths for its two dictionary
-    keys, so on a TPU the 4-bit dense domain reduces through
-    aggregate._masked_backend (scatter-free), not segment_sum."""
+    keys, so the 4-bit dense domain reduces through
+    aggregate._masked_backend (scatter-free)."""
     import tidb_tpu.executor.aggregate as A
     from tidb_tpu.chunk import pad_capacity
 
@@ -244,7 +204,7 @@ def _sort_signatures(fn, *args):
     return found
 
 
-def test_sorted_aggregation_q18_shape_compiles(one_chip, tpu_gates):
+def test_sorted_aggregation_q18_shape_compiles(one_chip):
     """The lowering that took 434 s in the seed (five int8 keys + row id,
     stable). With planner widths the whole key — row validity, key
     validity, 24 key bits, 23 row-id bits — is two uint32 limbs."""
@@ -259,7 +219,7 @@ def test_sorted_aggregation_q18_shape_compiles(one_chip, tpu_gates):
 
 
 @pytest.mark.slow
-def test_sorted_aggregation_without_widths_compiles(one_chip, tpu_gates):
+def test_sorted_aggregation_without_widths_compiles(one_chip):
     """No planner bounds: the int64 key keeps all 64 bits (3 limbs)."""
     agg, batch = _q18_aggregation(one_chip, None)
     assert _sort_signatures(agg, batch)[0][1] == 3
@@ -305,7 +265,7 @@ def _q5_join_sides(one_chip):
 
 
 @pytest.mark.slow
-def test_merge_probe_join_q5_shape_compiles(one_chip, tpu_gates):
+def test_merge_probe_join_q5_shape_compiles(one_chip):
     from tidb_tpu.chunk import pad_capacity
     from tidb_tpu.executor.join import _use_merge_probe, equi_join
 
@@ -322,14 +282,14 @@ def test_merge_probe_join_q5_shape_compiles(one_chip, tpu_gates):
 
 
 @pytest.mark.slow
-def test_sorted_unique_lookup_q5_shape_compiles(one_chip, tpu_gates):
+def test_sorted_unique_lookup_q5_shape_compiles(one_chip):
     from tidb_tpu.executor.join import lookup_build_rows
 
     orders, lineitem = _q5_join_sides(one_chip)
     compiled, _s = _compile(
         lambda b, p: lookup_build_rows(
             b, p, lambda x: x.cols["o_orderkey"], lambda x: x.cols["l_orderkey"],
-            build_bounds=(1, 6_000_000),  # past 2**16 rows a TPU sorts anyway
+            build_bounds=(1, 6_000_000),  # past 2**16 build rows: sorted anyway
         ),
         orders, lineitem,
     )
@@ -337,7 +297,7 @@ def test_sorted_unique_lookup_q5_shape_compiles(one_chip, tpu_gates):
 
 
 @pytest.mark.slow
-def test_unique_join_compaction_q5_shape_compiles(one_chip, tpu_gates):
+def test_unique_join_compaction_q5_shape_compiles(one_chip):
     """Q5's largest join at SF1: 6,291,456 probe rows into the
     discovered 2,097,152-row tile, four output columns. 55 s here (the
     lookup's three-limb sorts; the compaction adds one single-limb
@@ -376,7 +336,7 @@ def test_unique_join_compaction_q5_shape_compiles(one_chip, tpu_gates):
 # ---------------------------------------------------------------------------
 
 
-def test_mesh_repartition_join_has_all_to_all(topo, tpu_gates):
+def test_mesh_repartition_join_has_all_to_all(topo):
     """The hash-partition shuffle of the north star, compiled for the
     2x2 v5e mesh. Small tiles: what is asserted is the collective, and
     the sorts of a 4096-row tile compile in seconds."""

@@ -1,11 +1,13 @@
-"""Numerics of the lowerings only a TPU takes, run here on the CPU.
+"""Numerics of the executor's lowerings, run here on the CPU.
 
-The gates ask `utils.backend.is_tpu()`; each test steers the cached flag
-and compares the TPU formulation with the default one (or with numpy)
-on the same batch, at the operator level — no planner, so no compiled
-plan cached under one gate setting can answer for the other. What the
-chip's compiler makes of the same code is tests/test_tpu_compile.py's
-business; what the chip answers is chip_smoke.py's.
+The executor lowers an operator one way, chosen from the shapes and
+widths it is handed (7 bits of packed key or fewer: masked reductions;
+other keys: sorted; probes of 4,096 and more: merge; builds over 65,536:
+sorted lookup), so what runs here is what the chip runs. Each test
+compares a formulation with numpy (or a loop over the rows) on the same
+batch, at the operator level — no planner. What the chip's compiler
+makes of the same code is tests/test_tpu_compile.py's business; what
+the chip answers is chip_smoke.py's.
 """
 
 import numpy as np
@@ -16,13 +18,6 @@ import jax.numpy as jnp
 
 from tidb_tpu.chunk import Batch, HostBlock, block_to_batch, column_from_values
 from tidb_tpu.dtypes import FLOAT64, INT64
-
-
-@pytest.fixture
-def tpu_gates(monkeypatch):
-    import tidb_tpu.utils.backend as backend
-
-    monkeypatch.setattr(backend, "_IS_TPU", True)
 
 
 def _mk(cols: dict, capacity: int) -> Batch:
@@ -127,7 +122,7 @@ def test_merge_searchsorted_matches_jnp(side):
 
 
 # ---------------------------------------------------------------------------
-# sorted aggregation vs the hash / dense paths
+# group_aggregate's three shapes (scalar, dense + masked, sorted) vs numpy
 # ---------------------------------------------------------------------------
 
 
@@ -137,20 +132,110 @@ def _agg_batch():
     k1 = [None if rng.random() < 0.05 else int(x) for x in rng.integers(-40, 40, n)]
     k2 = [None if rng.random() < 0.05 else int(x) for x in rng.integers(0, 3, n)]
     kf = [
-        None if r < 0.05 else (float("nan") if r < 0.08 else float(x) * 0.5)
+        None if r < 0.05 else float("nan") if r < 0.08
+        else -0.0 if x == 0 and r > 0.5 else float(x) * 0.5
         for r, x in zip(rng.random(n), rng.integers(-4, 4, n))
     ]
     v = [None if rng.random() < 0.1 else int(x) for x in rng.integers(-10**6, 10**6, n)]
+    d = [None if rng.random() < 0.1 else int(x) for x in rng.integers(0, 6, n)]
     f = [float(x) for x in rng.normal(size=n)]
     batch = _mk(
         {"k1": (k1, INT64), "k2": (k2, INT64), "kf": (kf, FLOAT64),
-         "v": (v, INT64), "f": (f, FLOAT64)},
+         "v": (v, INT64), "d": (d, INT64), "f": (f, FLOAT64)},
         4096,
     )
     # some invalid rows in the middle of the tile
     rv = np.asarray(batch.row_valid).copy()
     rv[::17] = False
     return Batch(batch.cols, jnp.asarray(rv))
+
+
+def _cells(batch: Batch, names) -> list:
+    """Valid rows as tuples of exact host values: None for NULL, "nan",
+    int or float (-0.0 as 0.0)."""
+    cols = [
+        (np.asarray(batch.cols[n].data), np.asarray(batch.cols[n].valid))
+        for n in names
+    ]
+
+    def cell(data, valid, i):
+        x = data[i].item()
+        return (
+            None if not valid[i] else "nan" if x != x
+            else x + 0.0 if isinstance(x, float) else x
+        )
+
+    return [
+        tuple(cell(data, valid, i) for data, valid in cols)
+        for i in np.nonzero(np.asarray(batch.row_valid))[0]
+    ]
+
+
+def _reference_groups(batch: Batch, keys, aggs) -> list:
+    """GROUP BY in plain Python over the host's values. `aggs` are
+    (func, column or None, distinct). MySQL's rules: NULLs (and NaNs,
+    and the two zeros) of a key are one group; an aggregate skips NULL
+    arguments, and is NULL over none, except COUNT."""
+    import math
+
+    args = sorted({col for _f, col, _d in aggs if col is not None})
+    table = _cells(batch, list(keys) + args)  # one tuple a valid row
+    at = {col: len(keys) + j for j, col in enumerate(args)}
+    groups: dict = {}
+    for r in table:
+        groups.setdefault(r[: len(keys)], []).append(r)
+    rows = []
+    for key, members in groups.items():
+        row = list(key)
+        for func, col, distinct in aggs:
+            if col is None:
+                row.append(len(members))
+                continue
+            vals = [r[at[col]] for r in members if r[at[col]] is not None]
+            if distinct:
+                vals = sorted(set(vals))
+            total = math.fsum(vals) if vals and isinstance(vals[0], float) else sum(vals)
+            row.append(
+                len(vals) if func == "count"
+                else None if not vals
+                else total if func == "sum"
+                else total / len(vals) if func == "avg"
+                else min(vals) if func == "min" else max(vals)
+            )
+        rows.append(tuple(row))
+    return rows
+
+
+def _assert_same_groups(got: list, want: list, nkeys: int):
+    """Same groups, cell for cell: keys, integers and NULLs exactly, a
+    float within the benchmark's own float_rel_dev limit (1e-10): a sum
+    accumulated in another order is the same sum."""
+    def order(r):
+        return tuple((x is None, str(x)) for x in r[:nkeys])
+
+    got, want = sorted(got, key=order), sorted(want, key=order)
+    assert [r[:nkeys] for r in got] == [r[:nkeys] for r in want]
+    for g, w in zip(got, want):
+        for x, y in zip(g[nkeys:], w[nkeys:]):
+            if isinstance(y, float) and isinstance(x, float):
+                assert abs(x - y) <= 1e-10 * max(abs(y), 1.0), (g, w)
+            else:
+                assert x == y and type(x) is type(y), (g, w)
+
+
+def _agg_descs(specs):
+    from tidb_tpu.executor import AggDesc
+
+    return [
+        AggDesc(func, None if col is None else _col(col), f"a{i}", distinct=distinct)
+        for i, (func, col, distinct) in enumerate(specs)
+    ]
+
+
+_PLAIN_AGGS = [
+    ("sum", "v", False), ("count", None, False), ("count", "v", False),
+    ("min", "v", False), ("max", "f", False), ("avg", "v", False),
+]
 
 
 @pytest.mark.parametrize(
@@ -164,36 +249,96 @@ def _agg_batch():
         (["k2", "kf", "k1"], None),
     ],
 )
-def test_sorted_aggregation_matches_default_path(keys, widths, monkeypatch):
-    import tidb_tpu.utils.backend as backend
-    from tidb_tpu.executor import AggDesc, group_aggregate
+def test_sorted_aggregation_matches_default_path(keys, widths):
+    """Keyed aggregation past the dense domain: rows sorted by key,
+    runs reduced by cumulative sums and segmented scans, against the
+    reference GROUP BY."""
+    from tidb_tpu.executor import group_aggregate
 
     batch = _agg_batch()
-    aggs = [
-        AggDesc("sum", _col("v"), "s"),
-        AggDesc("count", None, "c"),
-        AggDesc("count", _col("v"), "cv"),
-        AggDesc("min", _col("v"), "mn"),
-        AggDesc("max", _col("f"), "mx"),
-        AggDesc("avg", _col("v"), "av"),
+    aggs = _agg_descs(_PLAIN_AGGS)
+    out, ng = jax.jit(
+        lambda b: group_aggregate(
+            b, [_col(k) for k in keys], aggs, 4096, key_names=keys,
+            key_widths=widths,
+        )
+    )(batch)
+    want = _reference_groups(batch, keys, _PLAIN_AGGS)
+    assert int(ng) == len(want)
+    _assert_same_groups(
+        _cells(out, keys + [a.out_name for a in aggs]), want, len(keys)
+    )
+
+
+_SHAPES = {
+    # name: (keys, key_widths, group_capacity, the dense path?)
+    "scalar": ([], None, 1, False),
+    "dense 3-bit key": (["k2"], [(3, 0)], 16, True),
+    "8-bit key": (["k1"], [(8, 40)], 4096, False),
+    "two keys": (["k1", "k2"], [(8, 40), (3, 0)], 4096, False),
+    "float key": (["kf"], None, 4096, False),  # NaN group, -0.0 == 0.0
+    "no widths": (["k2", "k1"], None, 4096, False),
+}
+
+
+@pytest.mark.parametrize("mode", ["plain", "post_filter", "distinct sum"])
+@pytest.mark.parametrize("shape", list(_SHAPES))
+def test_group_aggregate_is_its_numpy_reference(shape, mode, monkeypatch):
+    """Every shape group_aggregate lowers, picked from keys and widths
+    alone (no keys: one full-array reduction a lane; 7 bits or fewer:
+    dense domain, masked reductions; any other keys: sorted), plain,
+    under a fused HAVING, and with DISTINCT aggregates (the claim-loop
+    pair table), against a GROUP BY in plain Python: same groups, same
+    NULLs, integers exact, float sums within 1e-10."""
+    import tidb_tpu.executor.aggregate as A
+    import tidb_tpu.executor.sortops as S
+    from tidb_tpu.chunk import DevCol
+
+    keys, widths, capacity, dense = _SHAPES[shape]
+    took = []
+    for module, name in ((A, "_masked_backend"), (A, "_scalar_backend"),
+                         (S, "sort_group_aggregate")):
+        real = getattr(module, name)
+        monkeypatch.setattr(
+            module, name,
+            lambda *a, _n=name, _f=real, **k: took.append(_n) or _f(*a, **k),
+        )
+    specs = _PLAIN_AGGS + [("sum", "f", False)]
+    if mode == "distinct sum":
+        specs = specs + [("sum", "d", True), ("count", "d", True), ("avg", "d", True)]
+    aggs = _agg_descs(specs)
+    post = None
+    if mode == "post_filter":  # HAVING sum(v) < 0: a NULL sum is dropped
+        def post(out):
+            return DevCol(out.cols["a0"].data < 0, out.cols["a0"].valid)
+
+    batch = _agg_batch()
+    out, ng = jax.jit(
+        lambda b: A.group_aggregate(
+            b, [_col(k) for k in keys], aggs, capacity, key_names=keys,
+            key_widths=widths, post_filter=post,
+        )
+    )(batch)
+    assert took == [
+        "_scalar_backend" if not keys
+        else "_masked_backend" if dense else "sort_group_aggregate"
     ]
+    groups = _reference_groups(batch, keys, specs)
+    want = [
+        r for r in groups
+        if post is None or (r[len(keys)] is not None and r[len(keys)] < 0)
+    ]
+    assert want and (post is None or not keys or len(want) < len(groups))
+    # the dense path compacts the survivors and reports them; the others
+    # mask them and report what their tile had to hold
+    assert int(ng) == (len(want) if dense else len(groups))
+    assert out.capacity == max(capacity, 16) if keys else out.capacity == capacity
+    _assert_same_groups(
+        _cells(out, keys + [a.out_name for a in aggs]), want, len(keys)
+    )
 
-    def run():
-        out, ng = jax.jit(
-            lambda b: group_aggregate(
-                b, [_col(k) for k in keys], aggs, 4096, key_names=keys,
-                key_widths=widths,
-            )
-        )(batch)
-        return _rows(out, keys + [a.out_name for a in aggs]), int(ng)
 
-    want, want_n = run()
-    monkeypatch.setattr(backend, "_IS_TPU", True)
-    got, got_n = run()
-    assert got_n == want_n and got == want
-
-
-def test_sorted_aggregation_reports_stale_widths(tpu_gates):
+def test_sorted_aggregation_reports_stale_widths():
     """A valid key outside its planner-baked width must surface as
     WIDTH_STALE (the host recompiles), never as a wrong group."""
     from tidb_tpu.executor import AggDesc, group_aggregate
@@ -209,7 +354,7 @@ def test_sorted_aggregation_reports_stale_widths(tpu_gates):
     assert int(ng) >= WIDTH_STALE
 
 
-def test_small_dense_domain_takes_masked_backend(tpu_gates, monkeypatch):
+def test_small_dense_domain_takes_masked_backend(monkeypatch):
     import tidb_tpu.executor.aggregate as A
 
     used = []
@@ -235,7 +380,7 @@ def test_small_dense_domain_takes_masked_backend(tpu_gates, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# sorted join build + merge probe vs the default probe
+# sorted join build + merge probe, sorted unique lookup vs a loop over rows
 # ---------------------------------------------------------------------------
 
 
@@ -246,53 +391,63 @@ def _join_sides():
     pk = [None if rng.random() < 0.05 else int(x) for x in rng.integers(-20, 450, npr)]
     build = _mk({"bk": (bk, INT64), "bv": (list(range(nb)), INT64)}, 1024)
     probe = _mk({"pk": (pk, INT64), "pv": (list(range(npr)), INT64)}, 8192)
-    return build, probe
+    return build, probe, bk, pk
 
 
 @pytest.mark.parametrize("join_type", ["inner", "left", "semi", "anti"])
-def test_merge_probe_join_matches_default(join_type, monkeypatch):
-    import tidb_tpu.utils.backend as backend
+def test_merge_probe_join_matches_default(join_type):
+    """A probe tile of 4,096 rows and more takes the merge probe (and
+    the expansion its merge_searchsorted); the rows are those of a
+    nested loop. anti is null-aware: a NULL probe key survives."""
     from tidb_tpu.executor.join import _use_merge_probe, equi_join
 
-    build, probe = _join_sides()
+    build, probe, bk, pk = _join_sides()
+    assert _use_merge_probe(probe.capacity) and not _use_merge_probe(build.capacity)
+    rows_of = {}
+    for j, k in enumerate(bk):
+        if k is not None:
+            rows_of.setdefault(k, []).append(j)
+    want = []
+    for i, k in enumerate(pk):
+        hits = rows_of.get(k, [])
+        if join_type in ("inner", "left"):
+            want += [(k, i, bk[j], j) for j in hits]
+            if join_type == "left" and not hits:
+                want.append((k, i, None, None))
+        elif (join_type == "semi") == bool(hits):
+            want.append((k, i))
+    out, total = jax.jit(
+        lambda b, p: equi_join(b, p, _col("bk"), _col("pk"), 16384, join_type)
+    )(build, probe)
     names = ["pk", "pv"] + (["bk", "bv"] if join_type in ("inner", "left") else [])
-
-    def run():
-        out, total = jax.jit(
-            lambda b, p: equi_join(b, p, _col("bk"), _col("pk"), 16384, join_type)
-        )(build, probe)
-        return _rows(out, names), int(total)
-
-    want = run()
-    monkeypatch.setattr(backend, "_IS_TPU", True)
-    assert _use_merge_probe(probe.capacity)
-    assert run() == want
+    assert int(total) == len(want)
+    assert sorted(_cells(out, names), key=repr) == sorted(want, key=repr)
 
 
-def test_sorted_unique_lookup_matches_dense(monkeypatch):
+def test_sorted_unique_lookup_matches_dense():
+    """A unique build of 65,536 rows or fewer with planner bounds is a
+    dense table; past that capacity (or without bounds) it is sorted
+    and probed. Same build rows, same answer: numpy's."""
     import tidb_tpu.executor.join as J
-    import tidb_tpu.utils.backend as backend
 
     rng = np.random.default_rng(6)
     bk = rng.permutation(3000)[:2000].tolist()  # unique build keys
-    build = _mk({"bk": (bk, INT64)}, 2048)
     pk = [None if rng.random() < 0.05 else int(x) for x in rng.integers(-5, 3100, 5000)]
     probe = _mk({"pk": (pk, INT64)}, 8192)
+    row_of = {k: j for j, k in enumerate(bk)}
+    want = np.full(8192, -1)
+    want[: len(pk)] = [row_of.get(k, -1) for k in pk]
 
-    def run():
+    for bcap, span in ((2048, 3000), (1 << 17, None)):
+        assert J._dense_span((0, 2999), bcap, probe.capacity) == span
+        build = _mk({"bk": (bk, INT64)}, bcap)
         brow, matched, stale = jax.jit(
             lambda b, p: J.lookup_build_rows(
                 b, p, _col("bk"), _col("pk"), build_bounds=(0, 2999)
             )
         )(build, probe)
-        m = np.asarray(matched)
-        return np.where(m, np.asarray(brow), -1), bool(stale)
-
-    want, want_stale = run()  # dense direct index on the CPU
-    monkeypatch.setattr(backend, "_IS_TPU", True)
-    monkeypatch.setattr(J, "_dense_span", lambda *a: None)  # as past 2**16 rows
-    got, got_stale = run()
-    assert not want_stale and not got_stale and (got == want).all()
+        assert not bool(stale)
+        assert (np.where(np.asarray(matched), np.asarray(brow), -1) == want).all()
 
 
 # ---------------------------------------------------------------------------
@@ -303,7 +458,7 @@ def test_sorted_unique_lookup_matches_dense(monkeypatch):
 _PCAP, _OCAP = 4096, 1024
 
 
-def _unique_join_sides(join_type, fill):
+def _unique_join_sides(join_type, fill, bcap=2048):
     """(build, probe, expected rows in probe order). NULL keys on both
     sides, NULL values, invalid probe rows scattered through the tile,
     a 64-bit and a bool column a side; the probe's row_valid is thinned
@@ -344,7 +499,7 @@ def _unique_join_sides(join_type, fill):
         expected.append((pk[i], pv[i], pb[i]) + build_vals)
     assert len(expected) == want_n
 
-    build = _mk({"bk": (bk, INT64), "bv": (bv, INT64), "bf": (bf, BOOL)}, 2048)
+    build = _mk({"bk": (bk, INT64), "bv": (bv, INT64), "bf": (bf, BOOL)}, bcap)
     probe = _mk({"pk": (pk, INT64), "pv": (pv, INT64), "pb": (pb, BOOL)}, _PCAP)
     rv = np.zeros(_PCAP, dtype=bool)
     rv[:npr] = alive
@@ -354,21 +509,22 @@ def _unique_join_sides(join_type, fill):
 @pytest.mark.parametrize("fill", ["under", "full", "over"])
 @pytest.mark.parametrize("lookup", ["dense", "sorted"])
 @pytest.mark.parametrize("join_type", ["inner", "left"])
-def test_unique_join_compacts_like_a_plain_join(join_type, lookup, fill, monkeypatch):
+def test_unique_join_compacts_like_a_plain_join(join_type, lookup, fill):
     """Same rows, same (probe) order, same validity as a loop over the
     probe rows; an overflowing tile holds the first out_capacity rows
-    and reports the true total; no column is valid past the rows."""
-    import tidb_tpu.utils.backend as backend
-    from tidb_tpu.executor.join import equi_join
+    and reports the true total; no column is valid past the rows. The
+    build's capacity picks the lookup: a dense table up to 65,536 rows,
+    sorted past them (Q5's orders at SF1), the planner's bounds given
+    either way."""
+    from tidb_tpu.executor.join import _dense_span, equi_join
 
-    build, probe, expected = _unique_join_sides(join_type, fill)
-    if lookup == "sorted":  # what the chip takes past 2**16 build rows
-        monkeypatch.setattr(backend, "_IS_TPU", True)
-    bounds = (0, 2999) if lookup == "dense" else None
+    bcap = 2048 if lookup == "dense" else 1 << 17
+    build, probe, expected = _unique_join_sides(join_type, fill, bcap)
+    assert (_dense_span((0, 2999), bcap, _PCAP) is None) == (lookup == "sorted")
     out, total = jax.jit(
         lambda b, p: equi_join(
             b, p, _col("bk"), _col("pk"), _OCAP, join_type,
-            build_bounds=bounds, build_unique=True,
+            build_bounds=(0, 2999), build_unique=True,
         )
     )(build, probe)
     assert out.capacity == _OCAP and int(total) == len(expected)
@@ -396,11 +552,11 @@ def _lowered(fn, *args):
     return collections.Counter(re.findall(r"stablehlo\.(\w+)", text)), text
 
 
-def test_unique_join_compaction_holds_no_scatter(tpu_gates):
+def test_unique_join_compaction_holds_no_scatter():
     """The branch pays per OUTPUT row: one sort for the index, gathers
     for the columns. A scatter pays per probe row and the v5e runs it
     serially (2.46 s of Q5's 3.17 s at SF1, PERF.md PR 28). Sorted
-    lookup, as the chip takes at Q5's sizes: the dense table build is a
+    lookup (no bounds), as Q5's sizes take: the dense table build is a
     scatter of its own, per BUILD row."""
     from tidb_tpu.executor.join import equi_join
     from tidb_tpu.utils.metrics import REGISTRY

@@ -1,32 +1,46 @@
-"""Hash-based group aggregation with static shapes (no sort).
+"""Group aggregation with static shapes: one lowering per operator shape,
+chosen from what is known when the program is traced (key widths and
+counts), never from the platform or the environment.
 
 Reference: the parallel hash aggregate with partial/final workers
 (pkg/executor/aggregate/agg_hash_executor.go:60-91) and StreamAggExec
 (agg_stream_executor.go:32). The reference builds a dynamic hash table;
-TPU needs static shapes, so the table is a fixed power-of-two slot array
-(2x the group-capacity knob) built with a data-parallel claim loop:
+a static-shape program has three shapes instead:
 
-  1. every row hashes its group key to a slot,
+  no keys                  one group at slot 0, one full-array
+                           reduction a lane (`_scalar_backend`)
+  keys packing into        slot id == packed key over a dense domain of
+  _DENSE_BITS bits or      at most 128 slots, one fused masked reduction
+  fewer (Q1's flags)       per (slot, lane) (`_masked_backend`), then a
+                           cumsum compaction of the occupied slots
+  any other keys           sort rows by key, reduce runs by cumulative
+                           sums and segmented scans
+                           (`sortops.sort_group_aggregate`)
+
+A scatter costs the v5e ~69 ns an update, serially; that is why no
+shape above is built on one (sortops.py's header has the measurements).
+
+DISTINCT sum/count/avg dedupe their (group, value) pairs with the one
+hash table that is left, `group_assign`, a data-parallel claim loop over
+a fixed power-of-two slot array:
+
+  1. every row hashes its key to a slot,
   2. unassigned rows scatter-min their row id into the slot (the smallest
      row id claims it),
   3. rows whose key equals the claimer's key adopt the slot; the rest
      linear-probe to the next slot and repeat.
 
-All rows of one key follow the same probe sequence, so each group settles
-on exactly one slot and the loop runs for ~the longest probe chain (a few
-memory-bound passes) instead of a full bitonic sort of the batch
-(O(n log^2 n) on TPU, the reason the sort-based first cut was slow).
-Aggregation is then jax.ops.segment_* straight into the slot array —
-segment ops do not need sorted input.
+All rows of one key follow the same probe sequence, so each key settles
+on exactly one slot and the loop runs for ~the longest probe chain.
 
-The kernel returns the true group count; table overflow (unassigned rows
-after the probe limit) reports slots+1 so the host bumps the capacity
-tile and re-jits — the analog of the reference's spill escalation
-(aggregate/agg_spill.go), replaced by recompile-at-larger-tile. The
-partial/final split of the reference maps to per-device local aggregation
-followed by an all_to_all repartition of group keys and a final
-aggregation (parallel/fragment.py), mirroring agg partial workers ->
-shuffle -> final workers.
+Every shape returns the true group count; a count above the output tile
+(or a pair table that overflowed its probe limit) makes the host bump
+the capacity tile and re-jit — the analog of the reference's spill
+escalation (aggregate/agg_spill.go), replaced by recompile-at-larger-
+tile. The partial/final split of the reference maps to per-device local
+aggregation followed by an all_to_all repartition of group keys and a
+final aggregation (parallel/fragment.py), mirroring agg partial workers
+-> shuffle -> final workers.
 """
 
 from __future__ import annotations
@@ -38,13 +52,17 @@ import jax
 import jax.numpy as jnp
 
 from tidb_tpu.chunk import Batch, DevCol, pad_capacity
-from tidb_tpu.utils.backend import is_tpu as _is_tpu
 
 ExprFn = Callable[[Batch], DevCol]
 
 # linear-probe bound per table size; beyond this the table is declared
 # full and the host retries at the next tile
 _MAX_PROBES = 64
+
+# widest packed key domain the dense path takes: 2**7 = 128 slots, the
+# most that the masked reductions unroll over (Q1's two flags pack into
+# 4 bits). Wider keys sort.
+_DENSE_BITS = 7
 
 # reported in place of the group count when a row's key falls outside the
 # compile-time-baked packed-key bounds (int-column widths come from
@@ -105,8 +123,7 @@ class AggDesc:
     # fetch via CompiledQuery.bound_checks): lets the kernel pack the
     # (sum, count) lane pair into ONE biased int64 reduction —
     # (value + bound) << count_bits | 1 — halving the reduction passes
-    # (one segment scatter instead of two on CPU; one lane instead of
-    # two on the masked/TPU backends).
+    # (one lane instead of two, whatever the reducer).
     pack_bound: Optional[int] = None
 
 
@@ -240,78 +257,6 @@ def group_assign(
     return seg, claimer, ngroups, overflow
 
 
-def _packed_group_assign(
-    keys: Sequence[DevCol],
-    key_widths: Sequence[Tuple[int, int]],
-    row_valid: jax.Array,
-    slots: int,
-):
-    """Scatter/gather-free group assignment for keys that pack losslessly
-    into one int64 (dict-coded strings, dates, bools — widths are static,
-    sound bounds from the planner).
-
-    Discovers the distinct packed values with a min-above reduction loop
-    (one full reduction per group — TPU reductions are fast; TPU random
-    scatter/gather is not), then derives segment ids by comparing against
-    the sorted distinct table. Returns (seg, uniq, count, overflow) where
-    uniq is the sorted packed-key table for key-column reconstruction.
-    """
-    cap = row_valid.shape[0]
-    sent = jnp.int64(2**63 - 1)
-    packed, stale = _pack_keys(keys, key_widths, row_valid)
-    packed = jnp.where(row_valid, packed, sent)
-
-    def cond(s):
-        return ~s[-1]
-
-    def body(s):
-        uniq, count, prev, over, _stop = s
-        cur = jnp.min(jnp.where(packed > prev, packed, sent))
-        found = cur < sent
-        room = count < slots
-        take = found & room
-        uniq = uniq.at[jnp.where(take, count, slots)].set(cur, mode="drop")
-        count = count + take.astype(jnp.int32)
-        prev = jnp.where(found, cur, prev)
-        over = over | (found & ~room)
-        stop = ~take
-        return uniq, count, prev, over, stop
-
-    z = jnp.min(row_valid.astype(jnp.int32)) * 0  # varying seed (shard_map)
-    uniq0 = jnp.full(slots + 1, sent, dtype=jnp.int64) + z
-    state = (
-        uniq0,
-        jnp.int32(0) + z,
-        jnp.int64(-1) + z,
-        (z == 1),
-        (z == 1),
-    )
-    uniq, count, _prev, over, _stop = jax.lax.while_loop(cond, body, state)
-    uniq = uniq[:slots]
-    eq = packed[:, None] == uniq[None, :]
-    seg = jnp.argmax(eq, axis=1).astype(jnp.int32)
-    # mask with row_valid too: invalid rows carry the sentinel, which
-    # also fills unclaimed uniq slots and would otherwise match one
-    seg = jnp.where(row_valid & jnp.any(eq, axis=1), seg, slots)
-    return seg, uniq, count, over, stale
-
-
-def _prefix_sum(mask):
-    """int32 inclusive prefix sum of a bool mask; routes through the
-    Pallas streaming-scan kernel when opted in (TIDB_TPU_PALLAS=1 on
-    TPU, or interpret mode under TIDB_TPU_PALLAS_INTERPRET=1). An
-    opted-in kernel that fails raises: the jnp form never answers in
-    its place."""
-    from tidb_tpu.executor.pallas_kernels import (
-        pallas_interpret, prefix_sum_i32,
-    )
-
-    interp = pallas_interpret()
-    if interp is not None:
-        return prefix_sum_i32(mask, interpret=interp)
-    return jnp.cumsum(mask.astype(jnp.int32))
-
-
 def _packs(a: AggDesc, col, cap: int) -> bool:
     """Whether a sum/avg lane qualifies for the packed (sum, count)
     single reduction: proven per-row bound, integer data, and the
@@ -329,33 +274,31 @@ def _dense_compact_group_aggregate(
     batch, keys, key_widths, aggs, arg_cols, slots, dense_bits,
     key_names, reps, fold_distinct_overflow, post_filter=None,
 ):
-    """Aggregation over the full dense packed-key domain followed by a
+    """Aggregation over the full dense packed-key domain (slot id ==
+    packed key, at most 2**_DENSE_BITS slots: no assignment pass at
+    all), every lane a fused masked reduction per slot, followed by a
     cumsum compaction of occupied slots into the `slots` output tile.
-    For high-cardinality keys the claim loop needs O(probe-chain) full
-    scatter passes; this costs one segment scatter per agg over the dense
-    domain plus ~2 passes per output column to compact. Reports the true
-    group count — when it exceeds `slots` the host bumps the capacity
-    knob and re-jits exactly like the probed paths (results here stay
-    correct regardless; only the compaction tile was too small)."""
+    Reports the true group count — when it exceeds `slots` the host
+    bumps the capacity knob and re-jits exactly like the sorted path
+    (results here stay correct regardless; only the compaction tile was
+    too small)."""
     cap = batch.capacity
     dense = 1 << dense_bits
     packed, stale = _pack_keys(keys, key_widths, batch.row_valid)
-    # invalid / stale-width rows -> `dense`, out of range for every
-    # dense-domain scatter below (scatter drops OOB indices under jit)
+    # invalid / stale-width rows -> `dense`: no slot's mask matches them
+    # (and the `first` scatter below drops the out-of-range index)
     seg = jnp.where(
         batch.row_valid & (packed < dense), packed, dense
     ).astype(jnp.int32)
 
-    # TPU: a segment scatter costs ~45x a fused masked reduction at small
-    # domains (measured 64ms vs 1.4ms per lane at 1M rows) — route the
-    # reductions through the masked backend whenever the dense domain is
-    # small enough for full unrolling
-    red = _pick_backend(seg, dense)
+    # a segment scatter costs the v5e ~45x a fused masked reduction at
+    # small domains (measured 64ms vs 1.4ms per lane at 1M rows)
+    red = _masked_backend(seg, dense)
 
     # occupancy anchor: with a fused HAVING, a packed sum/avg lane whose
     # contribution mask IS the row mask (nonnull-folded column — object
     # identity is the trace-time proof) already carries the per-group
-    # row count, so the dedicated occupancy scatter can be skipped: its
+    # row count, so the dedicated occupancy lane can be skipped: its
     # output column's validity (count > 0) IS `occupied`.
     anchor = None
     if post_filter is not None and not any(a.func == "first" for a in aggs):
@@ -373,21 +316,14 @@ def _dense_compact_group_aggregate(
         occupied = jnp.ones(dense, dtype=bool)
         ngroups = None  # derived from the anchor lane below
     else:
-        if red is not None:
-            occ_n = red(
-                "sum",
-                batch.row_valid.astype(jnp.int64),
-                batch.row_valid,
-                jnp.int64(0),
-            )
-        else:
-            occ_n = jax.ops.segment_sum(
-                batch.row_valid.astype(jnp.int64), seg, num_segments=dense
-            )
+        occ_n = red(
+            "sum",
+            batch.row_valid.astype(jnp.int64),
+            batch.row_valid,
+            jnp.int64(0),
+        )
         occupied = occ_n > 0
-        from tidb_tpu.executor.fastreduce import count as _fr_count
-
-        ngroups = _fr_count(occupied)
+        ngroups = jnp.sum(occupied.astype(jnp.int64))
         ngroups = jnp.where(stale, jnp.int64(WIDTH_STALE), ngroups)
 
     # dense-domain key reconstruction
@@ -416,7 +352,7 @@ def _dense_compact_group_aggregate(
 
     wide = _run_aggs(
         batch, aggs, arg_cols, seg, dense, occupied, cl, out_cols, red,
-        reps=reps, num_segments=dense,
+        reps=reps,
     )
 
     if post_filter is not None:
@@ -434,20 +370,17 @@ def _dense_compact_group_aggregate(
         c = post_filter(wide)
         keep = occ_true & c.valid & (c.data != 0)
         occupied = keep
-        from tidb_tpu.executor.fastreduce import count as _fr_count2
-
         ngroups = jnp.where(
-            stale, jnp.int64(WIDTH_STALE), _fr_count2(keep)
+            stale,
+            jnp.int64(WIDTH_STALE),
+            jnp.sum(keep.astype(jnp.int64)),
         )
         wide = Batch(wide.cols, keep)
 
     # compact occupied dense slots into the output tile, in slot-id
-    # (ascending key) order (int32 cumsum: dense <= 2^23 and a 34MB
-    # serial chain runs ~1.6x faster than the 67MB int64 one on CPU).
-    # Opt-in TPU path: the Pallas streaming prefix sum does the scan in
-    # ONE sequential-grid pass vs XLA's log-depth multi-pass lowering.
+    # (ascending key) order
     pos = jnp.where(
-        occupied, _prefix_sum(occupied) - 1, slots
+        occupied, jnp.cumsum(occupied.astype(jnp.int32)) - 1, slots
     )
     cols = {}
     for name, c in wide.cols.items():
@@ -502,20 +435,20 @@ def group_aggregate(
 ) -> Tuple[Batch, jax.Array]:
     """Returns (group batch, reported group count).
 
-    The group batch has one row per group; its capacity depends on the
-    path (2*group_capacity hash-slot tile for the probed keyed paths,
-    1x for dense compaction, group_capacity for scalar) — callers must
-    size overflow checks from the RETURNED batch's capacity, never from
-    a 2x assumption. Key columns first (named key_names or k0..kn),
-    then one agg column each. The reported count is the true group
-    count, a value above the output capacity when the table overflowed
-    (host: bump the tile and re-jit), or WIDTH_STALE when baked key
-    bounds no longer cover the data (host: recompile with fresh bounds).
+    The group batch has one row per group; its capacity is the power of
+    two at or above max(group_capacity, 16) for keyed aggregation and
+    group_capacity for scalar — callers must size overflow checks from
+    the RETURNED batch's capacity. Key columns first (named key_names or
+    k0..kn), then one agg column each. The reported count is the true
+    group count, a value above the output capacity when the tile
+    overflowed (host: bump the tile and re-jit), or WIDTH_STALE when
+    baked key bounds no longer cover the data (host: recompile with
+    fresh bounds).
 
     key_widths: per-key (bit width, bias) for keys whose packed encoding
-    ``data + bias + 1`` (0 = NULL) provably fits the width — enables the
-    scatter-free packed fast path when all keys qualify and the widths
-    sum to <= 62 bits.
+    ``data + bias + 1`` (0 = NULL) provably fits the width. Keys that
+    all have one and pack into _DENSE_BITS bits or fewer take the dense
+    path; any other keys are sorted, packed by the widths they have.
     """
 
     from tidb_tpu.utils.failpoint import inject
@@ -527,7 +460,7 @@ def group_aggregate(
     # fused HAVING (post_filter): the dense path compacts only
     # surviving groups (capacity win); every other path masks the
     # output rows — reported counts stay PRE-filter there because the
-    # group/hash tables must still hold every group.
+    # output tile must still hold every group.
     def _mask_post(out, ng):
         if post_filter is None:
             return out, ng
@@ -564,160 +497,58 @@ def group_aggregate(
             jnp.where(dover, jnp.int64(pair_slots + 1), jnp.int64(0)),
         )
 
-    widths_ok = (
-        keys
-        and key_widths is not None
-        and all(w is not None for w in key_widths)
-        and sum(w for w, _b in key_widths) <= 62
-    )
-    dense_bits = sum(w for w, _b in key_widths) if widths_ok else 99
-    packable = widths_ok and group_capacity <= 256
-
-    # TPU (or TIDB_TPU_SORT_AGG=1): keyed aggregation by lexicographic
-    # sort (sortops) — the probed hash paths below are built on scatter
-    # and per-group reduction loops, both serial on TPU. The dense path
-    # keeps priority while its domain fits the masked-reduction unroll.
-    from tidb_tpu.utils.backend import sort_path_preference
-
-    _pref = sort_path_preference()
-    use_sorted = keys and (
-        _pref == "force" or (_is_tpu() and _pref != "avoid")
-    )
-    dense_ok = (
-        widths_ok
-        and dense_bits <= 26  # 2^26 domain = 536MB/lane: SF10 orderkeys
-        # stay on the dense path (the claim loop's serial probe passes
-        # are catastrophic at 60M rows); the 4*cap guard below still
-        # bounds the domain-to-batch waste
-        and (1 << dense_bits) <= max(4 * cap, 1 << 16)
-    )
-    if use_sorted and not (dense_ok and dense_bits <= 7):
+    if keys:
+        # Output tile is 1x the capacity knob: neither shape is a hash
+        # table that needs load-factor headroom, and downstream
+        # operators (sorts especially) pay per-capacity for every pass.
+        slots = _next_pow2(max(group_capacity, 16))
+        dense_bits = (
+            sum(w for w, _b in key_widths)
+            if key_widths is not None and all(w is not None for w in key_widths)
+            else None
+        )
+        if dense_bits is not None and dense_bits <= _DENSE_BITS:
+            return _dense_compact_group_aggregate(
+                batch, keys, key_widths, aggs, arg_cols, slots, dense_bits,
+                key_names, reps, fold_distinct_overflow,
+                post_filter=post_filter,
+            )
         from tidb_tpu.executor.sortops import sort_group_aggregate
 
-        slots = _next_pow2(max(group_capacity, 16))
         out, ngroups = sort_group_aggregate(
             batch, keys, aggs, arg_cols, slots, key_names, reps=reps,
             key_widths=key_widths,
         )
         return _mask_post(out, fold_distinct_overflow(ngroups))
 
-    if dense_ok:
-        # the whole packed-key domain fits a dense table (and is not
-        # wildly sparser than the batch): slot id == packed key, so
-        # assignment needs no probe loop at all — one segment scatter
-        # per agg plus a cumsum compaction into the output tile. The
-        # probed paths below cost one full-array pass PER GROUP (packed
-        # loop) or per probe-chain step (claim loop). Output tile is 1x
-        # the capacity knob (not the hash paths' 2x): compaction needs no
-        # load-factor headroom, and downstream operators (sorts
-        # especially) pay per-capacity for every pass.
-        slots = _next_pow2(max(group_capacity, 16))
-        return _dense_compact_group_aggregate(
-            batch, keys, key_widths, aggs, arg_cols, slots, dense_bits,
-            key_names, reps, fold_distinct_overflow,
-            post_filter=post_filter,
-        )
-
-    if packable:
-        slots = _next_pow2(max(2 * group_capacity, 16))
-        seg, uniq, count, over, stale = _packed_group_assign(
-            keys, key_widths, batch.row_valid, slots
-        )
-        ngroups = jnp.where(over, jnp.int64(slots + 1), count.astype(jnp.int64))
-        ngroups = jnp.where(stale, jnp.int64(WIDTH_STALE), ngroups)
-        occupied = jnp.arange(slots) < count
-        group_valid = occupied
-        # reconstruct key columns arithmetically from the packed table
-        out_cols = {}
-        off = 0
-        for name, k, (w, b) in zip(key_names, keys, key_widths):
-            limb = (uniq >> off) & ((1 << w) - 1)
-            off += w
-            kv = (limb != 0) & occupied
-            kd = (limb - (b + 1)).astype(k.data.dtype)
-            out_cols[name] = DevCol(jnp.where(kv, kd, jnp.zeros_like(kd)), kv)
-        # 'first' needs a representative row per group: min row id per slot
-        claimer = None
-        if any(a.func == "first" for a in aggs):
-            claimer = (
-                jnp.full(slots + 1, cap, dtype=jnp.int32)
-                .at[seg]
-                .min(jnp.arange(cap, dtype=jnp.int32), mode="drop")[:slots]
-            )
-        cl = (
-            jnp.minimum(claimer, cap - 1)
-            if claimer is not None
-            else jnp.zeros(slots, dtype=jnp.int32)
-        )
-        red = _pick_backend(seg, slots)
-        out = _run_aggs(
-            batch, aggs, arg_cols, seg, slots, group_valid, cl, out_cols, red,
-            reps=reps,
-        )
-        return _mask_post(out, fold_distinct_overflow(ngroups))
-
-    if keys:
-        slots = _next_pow2(max(2 * group_capacity, 16))
-        seg, claimer, true_ng, overflow = group_assign(
-            keys, batch.row_valid, slots
-        )
-        ngroups = jnp.where(overflow, jnp.int64(slots + 1), true_ng)
-        occupied = claimer < cap
-        red = _pick_backend(seg, slots)
-    else:
-        # scalar aggregation: one group at slot 0
-        slots = group_capacity
-        any_valid = jnp.any(batch.row_valid)
-        seg = jnp.where(batch.row_valid, 0, slots)
-        first_valid = jnp.argmax(batch.row_valid).astype(jnp.int32)
-        claimer = (
-            jnp.full(slots, cap, dtype=jnp.int32)
-            .at[0]
-            .set(jnp.where(any_valid, first_valid, cap))
-        )
-        occupied = claimer < cap
-        ngroups = jnp.sum(occupied.astype(jnp.int64))
-        red = _scalar_backend(slots)
-
-    group_valid = occupied
-    cl = jnp.minimum(claimer, cap - 1)
-
-    # --- group key columns: value at the first (claiming) row ---
-    out_cols = {}
-    for name, k in zip(key_names, keys):
-        kd = k.data[cl]
-        kv = k.valid[cl] & group_valid
-        out_cols[name] = DevCol(jnp.where(group_valid, kd, jnp.zeros_like(kd)), kv)
-
-    return _mask_post(
-        _run_aggs(
-            batch, aggs, arg_cols, seg, slots, group_valid, cl, out_cols, red,
-            reps=reps,
-        ),
-        fold_distinct_overflow(ngroups),
+    # scalar aggregation: one group at slot 0
+    slots = group_capacity
+    any_valid = jnp.any(batch.row_valid)
+    seg = jnp.where(batch.row_valid, 0, slots)
+    first_valid = jnp.argmax(batch.row_valid).astype(jnp.int32)
+    claimer = (
+        jnp.full(slots, cap, dtype=jnp.int32)
+        .at[0]
+        .set(jnp.where(any_valid, first_valid, cap))
     )
+    group_valid = claimer < cap
+    ngroups = jnp.sum(group_valid.astype(jnp.int64))
+    out = _run_aggs(
+        batch, aggs, arg_cols, seg, slots, group_valid,
+        jnp.minimum(claimer, cap - 1), {}, _scalar_backend(slots), reps=reps,
+    )
+    return _mask_post(out, fold_distinct_overflow(ngroups))
+
+
+_REDUCE = {"sum": jnp.sum, "min": jnp.min, "max": jnp.max}
 
 
 def _scalar_backend(slots):
     """Scalar (no GROUP BY) reductions: exactly one group lives at slot
-    0, so each lane is ONE full-array reduction. On CPU the reduction
-    routes through fastreduce (XLA:CPU lowers reduces with fused
-    producers to scalar loops — the two-stage GEMV is 10-45x faster,
-    measured); TPU keeps the fused jnp reduction, which is optimal
-    there."""
-    from tidb_tpu.executor import fastreduce as FR
-
-    fast = FR.use_fast()
-    ops = {"sum": jnp.sum, "min": jnp.min, "max": jnp.max}
+    0, so each lane is ONE fused full-array reduction."""
 
     def red(op, vals, contrib, ident):
-        if fast and op == "sum":
-            if jnp.issubdtype(vals.dtype, jnp.floating):
-                top = FR.sum_f64(vals, contrib).astype(vals.dtype)
-            else:
-                top = FR.sum_i64(vals, contrib)
-        else:
-            top = ops[op](jnp.where(contrib, vals, ident))
+        top = _REDUCE[op](jnp.where(contrib, vals, ident))
         out = jnp.full((slots,), ident, dtype=top.dtype)
         return out.at[0].set(top)
 
@@ -726,38 +557,21 @@ def _scalar_backend(slots):
 
 def _masked_backend(seg, slots):
     """Aggregate reductions as fused masked full-array reductions, one
-    accumulator per (slot, agg) — scatter-free. TPU scatter costs ~20x a
-    fused masked reduction at small slot counts, so this is the fast path
-    there when the slot table is small. The optimization barrier pins the
-    reduction inputs: without it XLA fuses the producer expression tree
-    (decimal products, filters, the claim loop) into EVERY per-slot
-    reduction, recomputing it slots*aggs times — measured 35x slowdown on
-    whole-query Q1."""
-    ops = {"sum": jnp.sum, "min": jnp.min, "max": jnp.max}
+    accumulator per (slot, agg) — scatter-free; unrolled over the slots,
+    so for the dense path's few (at most 2**_DENSE_BITS) only. The
+    optimization barrier pins the reduction inputs: without it XLA fuses
+    the producer expression tree (decimal products, filters) into EVERY
+    per-slot reduction, recomputing it slots*aggs times — measured 35x
+    slowdown on whole-query Q1."""
 
     def red(op, vals, contrib, ident):
-        f = ops[op]
+        f = _REDUCE[op]
         vals, contrib = jax.lax.optimization_barrier((vals, contrib))
         return jnp.stack(
             [f(jnp.where(contrib & (seg == s), vals, ident)) for s in range(slots)]
         )
 
     return red
-
-
-def _pick_backend(seg, slots):
-    """Small slot tables: masked reductions on TPU (scatter there costs
-    ~20x a fused reduction), segment_* scatter elsewhere (CPU XLA lowers
-    segment_sum to a fast serial scatter; the masked path is ~20x slower
-    there even with the barrier). Large tables: always segment.
-    TIDB_TPU_FORCE_MASKED=1 forces the masked path so the CPU test suite
-    can exercise the TPU lowering's numerics."""
-    import os
-
-    forced = os.environ.get("TIDB_TPU_FORCE_MASKED") == "1"
-    if slots <= 128 and (forced or _is_tpu()):
-        return _masked_backend(seg, slots)
-    return None
 
 
 class _SortedReducer:
@@ -851,94 +665,20 @@ def _run_sorted_aggs(
     )
 
 
-def _try_pallas_slot_sums(aggs, arg_cols, seg, slots, srow_valid, reps):
-    """Opt-in (TIDB_TPU_PALLAS=1) one-pass slot accumulation for the
-    non-wide SUM/COUNT/AVG aggregates: stacks their (value, contrib)
-    pairs and calls the Pallas kernel once. Returns {lane index ->
-    (sum f32 [slots], count i64-ish)} keyed by agg index, or None when
-    not opted in (the jnp path runs as before); an opted-in kernel that
-    fails to import, lower or run raises. float32 accumulation:
-    experimental, see pallas_kernels.py numerics note."""
-    from tidb_tpu.executor.pallas_kernels import (
-        pallas_interpret,
-        slot_sums_f32,
-    )
-
-    interp = pallas_interpret()
-    if interp is None or slots > 128:
-        return None
-    lanes = []  # (agg index, kind: 'cnt'|'sum', values, contrib)
-    for i, (a, col) in enumerate(zip(aggs, arg_cols)):
-        if a.func not in ("count", "sum", "avg") or a.wide:
-            continue
-        if col is None:
-            lanes.append((i, "cnt", jnp.ones_like(seg, jnp.float32), srow_valid))
-            continue
-        contrib = col.valid & srow_valid
-        if reps and i in reps:
-            contrib = contrib & reps[i]
-        if a.func in ("sum", "avg"):
-            lanes.append((i, "sum", col.data.astype(jnp.float32), contrib))
-        if a.func in ("count", "avg"):
-            lanes.append((i, "cnt", jnp.ones_like(seg, jnp.float32), contrib))
-    if not lanes:
-        return None
-    vals = jnp.stack([v for _i, _k, v, _c in lanes])
-    contribs = jnp.stack([c for _i, _k, _v, c in lanes])
-    sums = slot_sums_f32(
-        vals, contribs, seg.astype(jnp.int32), slots, interpret=interp
-    )
-    out = {}
-    for lane, (i, kind, _v, _c) in enumerate(lanes):
-        out.setdefault(i, {})[kind] = sums[lane]
-    return out
-
-
-_SEG_OPS = {
-    "sum": jax.ops.segment_sum,
-    "min": jax.ops.segment_min,
-    "max": jax.ops.segment_max,
-}
-
-
-def _exec_reqs(reqs, red, seg, slots, num_segments):
-    """Execute a list of (op, vals, contrib, ident) reduction requests,
-    one segment scatter per lane. (Stacking same-op lanes into one
-    [n, L] scatter was measured 2x SLOWER on CPU XLA: the stack
-    materializes an n x L intermediate because producers don't fuse into
-    scatter operands, costing more traffic than the shared seg reads
-    save.)"""
-    if red is not None:
-        batch_exec = getattr(red, "exec_all", None)
-        if batch_exec is not None:
-            return batch_exec(reqs)
-        return [red(op, v, c, i) for (op, v, c, i) in reqs]
-    ns = (slots + 1) if num_segments is None else num_segments
-    return [
-        _SEG_OPS[op](jnp.where(c, v, ident), seg, num_segments=ns)[:slots]
-        for (op, v, c, ident) in reqs
-    ]
-
-
 def _run_aggs(
-    batch, aggs, arg_cols, seg, slots, group_valid, cl, out_cols, red=None,
-    reps=None, num_segments=None,
+    batch, aggs, arg_cols, seg, slots, group_valid, cl, out_cols, red,
+    reps=None,
 ):
     """Compute all aggregates into the slot table. One implementation of
     the MySQL aggregate semantics (NULL rules, AVG decimal scale),
-    parameterized over the reduction backend. `reps` maps agg index to a
-    DISTINCT representative-row mask (_distinct_reps). Runs in three
-    phases — collect reduction requests, execute them (batched), then
-    assemble output columns — so independent lanes share scatter passes."""
+    parameterized over the reducer `red(op, vals, contrib, ident)` ->
+    [slots] (_scalar_backend, _masked_backend, _SortedReducer). `reps`
+    maps agg index to a DISTINCT representative-row mask
+    (_distinct_reps). Runs in three phases — collect reduction requests,
+    execute them (all at once where the reducer has `exec_all`), then
+    assemble output columns — so independent lanes share passes."""
     srow_valid = seg < slots
     ones = jnp.ones_like(seg, dtype=jnp.int64)
-    # the pallas slot kernel accumulates BY seg value — meaningless under
-    # the sorted reducer, whose seg only encodes row validity
-    pallas_pre = None
-    if not isinstance(red, _SortedReducer):
-        pallas_pre = _try_pallas_slot_sums(
-            aggs, arg_cols, seg, slots, srow_valid, reps
-        )
     reqs = []
 
     def req(op, vals, contrib, ident):
@@ -951,14 +691,9 @@ def _run_aggs(
         assemble.append((name, fn))
 
     for i, (a, col) in enumerate(zip(aggs, arg_cols)):
-        pre = (pallas_pre or {}).get(i)
         if a.func == "count" and col is None:
-            if pre is not None:
-                s = jnp.round(pre["cnt"]).astype(jnp.int64)
-                out_cols[a.out_name] = DevCol(s, group_valid)
-            else:
-                rid = req("sum", ones, srow_valid, jnp.int64(0))
-                emit(a.out_name, lambda R, rid=rid: DevCol(R[rid], group_valid))
+            rid = req("sum", ones, srow_valid, jnp.int64(0))
+            emit(a.out_name, lambda R, rid=rid: DevCol(R[rid], group_valid))
             continue
 
         data = col.data
@@ -969,12 +704,8 @@ def _run_aggs(
         if reps and i in reps:
             valid = valid & reps[i]
         if a.func == "count":
-            if pre is not None:
-                s = jnp.round(pre["cnt"]).astype(jnp.int64)
-                out_cols[a.out_name] = DevCol(s, group_valid)
-            else:
-                rid = req("sum", ones, valid, jnp.int64(0))
-                emit(a.out_name, lambda R, rid=rid: DevCol(R[rid], group_valid))
+            rid = req("sum", ones, valid, jnp.int64(0))
+            emit(a.out_name, lambda R, rid=rid: DevCol(R[rid], group_valid))
         elif a.func in ("sum", "avg"):
             if a.wide and not jnp.issubdtype(data.dtype, jnp.floating):
                 d64 = data.astype(jnp.int64)
@@ -987,17 +718,6 @@ def _run_aggs(
                     return R[rhi].astype(jnp.float64) * float(1 << 30) + R[
                         rlo
                     ].astype(jnp.float64)
-
-            elif pre is not None:
-                ps = pre["sum"]
-                s_pre = (
-                    jnp.round(ps).astype(data.dtype)
-                    if not jnp.issubdtype(data.dtype, jnp.floating)
-                    else ps.astype(data.dtype)
-                )
-
-                def mk_s(R, s_pre=s_pre):
-                    return s_pre
 
             elif _packs(a, col, batch.capacity):
                 # packed (sum, count) single reduction: values biased
@@ -1045,17 +765,10 @@ def _run_aggs(
                 def mk_s(R, rs=rs):
                     return R[rs]
 
-            if pre is not None and "cnt" in pre:
-                cnt_pre = jnp.round(pre["cnt"]).astype(jnp.int64)
+            rc = req("sum", ones, valid, jnp.int64(0))
 
-                def mk_cnt(R, cnt_pre=cnt_pre):
-                    return cnt_pre
-
-            else:
-                rc = req("sum", ones, valid, jnp.int64(0))
-
-                def mk_cnt(R, rc=rc):
-                    return R[rc]
+            def mk_cnt(R, rc=rc):
+                return R[rc]
 
             if a.func == "sum":
 
@@ -1098,7 +811,11 @@ def _run_aggs(
         else:
             raise NotImplementedError(f"agg func {a.func!r}")
 
-    results = _exec_reqs(reqs, red, seg, slots, num_segments)
+    exec_all = getattr(red, "exec_all", None)
+    results = (
+        exec_all(reqs) if exec_all is not None
+        else [red(op, v, c, ident) for (op, v, c, ident) in reqs]
+    )
     for name, fn in assemble:
         out_cols[name] = fn(results)
     return Batch(out_cols, group_valid)

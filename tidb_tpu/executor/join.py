@@ -35,29 +35,16 @@ import jax.numpy as jnp
 from tidb_tpu.chunk import Batch, DevCol
 from tidb_tpu.executor.aggregate import WIDTH_STALE
 
-
-def _fr_count(mask):
-    """Valid-row count via fastreduce (GEMV on CPU, jnp.sum elsewhere —
-    the backend gate lives inside fastreduce.count)."""
-    from tidb_tpu.executor.fastreduce import count
-
-    return count(mask)
-
 ExprFn = Callable[[Batch], DevCol]
 
 
 def _use_merge_probe(m: int) -> bool:
-    """Replace per-row binary search with sortops.merge_searchsorted on
-    TPU for large probe sides: searchsorted's log N rounds of random
-    gather measured 161ms at 1M probes vs ~15ms for the three regular
-    sorts of the merge formulation. Below the cutoff the extra sorts
-    don't pay. TIDB_TPU_SORT_AGG=1 forces it for CPU test coverage."""
-    from tidb_tpu.utils.backend import is_tpu, sort_path_preference
-
-    pref = sort_path_preference()
-    if pref == "force":
-        return True
-    return m >= 4096 and is_tpu() and pref != "avoid"
+    """Replace per-row binary search with sortops.merge_searchsorted
+    for large probe sides: searchsorted's log N rounds of random gather
+    measured 161ms at 1M probes on the v5e vs ~15ms for the three
+    regular sorts of the merge formulation. Below the cutoff the extra
+    sorts don't pay."""
+    return m >= 4096
 
 
 def _probe_lo_hi(skey, pkey, need_hi: bool):
@@ -111,20 +98,10 @@ def _dense_span(build_bounds, bcap: int, pcap: int) -> Optional[int]:
     """Static dense-table span for a bounded build key, or None when the
     domain is too large/sparse for direct indexing to pay off.
 
-    On TPU the dense table builds via scatter — XLA lowers large
-    scatters serially while lax.sort runs regular strided passes, so
-    dense only
-    pays for small builds there; CPU keeps dense at every size (its
-    scatter matches np.bincount). TIDB_TPU_SORT_AGG=1 forces the sort
-    path for CPU test coverage of the TPU lowering."""
-    from tidb_tpu.utils.backend import is_tpu, sort_path_preference
-
-    if build_bounds is None:
-        return None
-    pref = sort_path_preference()
-    if pref == "force" or (
-        is_tpu() and pref != "avoid" and bcap > (1 << 16)
-    ):
+    The dense table builds via scatter — the v5e runs large scatters
+    serially while lax.sort runs regular strided passes, so dense only
+    pays for small builds: past 65,536 build rows the callers sort."""
+    if build_bounds is None or bcap > (1 << 16):
         return None
     lo, hi = build_bounds
     span = int(hi) - int(lo) + 1
@@ -309,7 +286,7 @@ def equi_join(
             if join_type == "anti":
                 keep = keep | (~pvalid & probe.row_valid)
             out = Batch(probe.cols, probe.row_valid & keep)
-        total = _fr_count(out.row_valid)
+        total = jnp.sum(out.row_valid.astype(jnp.int64))
         return out, jnp.where(stale, jnp.int64(WIDTH_STALE), total)
 
     if join_type in ("inner", "left") and build_unique:
@@ -320,7 +297,7 @@ def equi_join(
             )
         else:
             # unique build without a usable dense span (domain too
-            # large/sparse, or scatter-hostile backend): sorted lookup —
+            # large/sparse, or a build past 65,536 rows): sorted lookup —
             # sort the build once, one searchsorted per probe, still 1:1
             # probe-aligned with NO expansion pass (vs the generic
             # expand path below that pays cumsum + output re-gather)
@@ -397,7 +374,7 @@ def equi_join(
             cols = dict(probe.cols)
             cols[mark_name] = DevCol(matched, mvalid)
             out = Batch(cols, probe.row_valid)
-            return out, _fr_count(out.row_valid)
+            return out, jnp.sum(out.row_valid.astype(jnp.int64))
         keep = matched if join_type == "semi" else (~matched & probe.row_valid & pvalid)
         if join_type == "anti":
             # NULL probe key in NOT IN/anti: row never matches but with a
@@ -406,7 +383,7 @@ def equi_join(
             # NOT EXISTS keeps it; planner selects via null_aware flag.
             keep = keep | (~pvalid & probe.row_valid)
         out = Batch(probe.cols, probe.row_valid & keep)
-        return out, _fr_count(out.row_valid)
+        return out, jnp.sum(out.row_valid.astype(jnp.int64))
 
     # ---- inner / left: sort build side, carry permutation ----
     skey, _svalid, sperm = _sort_build(bkey, bvalid, bcap)

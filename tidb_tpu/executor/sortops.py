@@ -307,8 +307,8 @@ def sort_group_aggregate(
     reps=None,
     key_widths=None,
 ) -> Tuple[Batch, jax.Array]:
-    """Keyed aggregation by lexicographic sort, replacing the claim-loop
-    hash table on TPU (see module docstring). Returns (group batch with
+    """Keyed aggregation by lexicographic sort, where the reference has
+    a hash table (see module docstring). Returns (group batch with
     capacity `slots`, true group count) under the same overflow protocol
     as group_aggregate: a count above `slots` makes the host bump the
     capacity knob and re-jit; results in the returned batch are correct

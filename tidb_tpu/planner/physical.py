@@ -3318,9 +3318,7 @@ def _node_label(plan: L.LogicalPlan) -> str:
 
 @jax.jit
 def _count_valid(row_valid: jax.Array) -> jax.Array:
-    from tidb_tpu.executor.fastreduce import count
-
-    return count(row_valid)
+    return jnp.sum(row_valid.astype(jnp.int64))
 
 
 def _compact_impl(batch: Batch, out_cap: int) -> Batch:

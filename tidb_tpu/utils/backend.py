@@ -1,9 +1,12 @@
 """Backend questions the engine asks of JAX, in one place.
 
-The program runs in two ways: on the CPU for tests (the caller sets
-JAX_PLATFORMS=cpu, plus XLA_FLAGS=--xla_force_host_platform_device_count=N
-for mesh paths) and on the TPU, one process per chip. Nothing here
-chooses between them or falls back from one to the other: a device
+The program is one program on two backends: the CPU for tests (the
+caller sets JAX_PLATFORMS=cpu, plus
+XLA_FLAGS=--xla_force_host_platform_device_count=N for mesh paths) and
+the TPU, one process per chip or per mesh. The executor lowers every
+operator the same way on both (its kernels are chosen from shapes and
+widths, executor/aggregate.py and executor/join.py); nothing here
+chooses between backends or falls back from one to the other: a device
 query that fails is an error.
 """
 
@@ -15,10 +18,11 @@ _IS_TPU: bool | None = None
 
 
 def is_tpu() -> bool:
-    """True when the default JAX device is a TPU. THE gate for every
-    TPU-only lowering (sorted aggregation, masked reductions, merge
-    probe): gates ask this, never `jax.default_backend()` string
-    compares (scripts/check_backend_gates.py). Cached: the backend
+    """True when the default JAX device is a TPU: a fact about the
+    device (how much memory it has, planner/streamed._device_budget;
+    whether a bench found its chip), never a choice of kernel —
+    scripts/check_backend_gates.py keeps it, `jax.default_backend()`
+    and the environment out of tidb_tpu/executor/. Cached: the backend
     never changes inside a process."""
     global _IS_TPU
     if _IS_TPU is None:
@@ -33,16 +37,6 @@ def backend_label() -> str:
     import jax
 
     return jax.devices()[0].platform
-
-
-def sort_path_preference() -> str:
-    """One switch for every sort-vs-scatter formulation gate:
-    TIDB_TPU_SORT_AGG=1 -> 'force' (CPU tests cover the TPU lowering),
-    =0 -> 'avoid' (TPU opt-out escape hatch), unset -> 'auto' (backend
-    decides). Gates combine this with is_tpu() and their own size
-    thresholds, but the env-var policy lives here only."""
-    v = os.environ.get("TIDB_TPU_SORT_AGG")
-    return "force" if v == "1" else "avoid" if v == "0" else "auto"
 
 
 def enable_compile_cache() -> str:
