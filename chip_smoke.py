@@ -95,6 +95,9 @@ class LoweringCounter:
         # one per unique-build join that emits a smaller tile than it
         # probes, and one for the steady program's own output
         "gather_compact": ("tidb_tpu.executor.sortops", "compaction_index"),
+        # one a side of every inner/left join's output tile, and one
+        # for a sorted unique lookup's reads
+        "stacked_gather": ("tidb_tpu.executor.sortops", "gather_rows"),
     }
 
     def __init__(self):

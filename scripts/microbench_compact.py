@@ -2,8 +2,8 @@
 
 A unique-build join whose discovered output tile is smaller than its
 probe tile moves `out_cap` of `cap` rows to the front, in row order.
-Isolates, at Q5's two SF1 shapes (6,291,456 -> 2,097,152 and
-2,097,152 -> 262,144):
+Isolates, at Q5's SF1 shapes (6,291,456 -> 2,097,152 and 2,097,152 ->
+262,144 on one chip, 1,572,864 -> 524,288 on a shard of four):
 
   index    sortops.compaction_index (one single-limb sort) against the
            generic expand path's slot-to-row map,
@@ -11,9 +11,20 @@ Isolates, at Q5's two SF1 shapes (6,291,456 -> 2,097,152 and
   columns  one gather a column through that index (u32, bool, int64,
            and the four (int64 data, valid) pairs a Q5 join emits)
            against one row-gather of the columns stacked as u32 lanes,
-           and against the scatter a column the join used to pay
+           (by hand in both orientations, and as sortops.gather_rows,
+           the helper the joins call), and against the scatter a column
+           the join used to pay
+  lookup   (PR 32) the sorted unique lookup's reads at `lo`: the sorted
+           build's validity, key (compared with the probe's) and row,
+           one gather each as the compiler fuses them, against the four
+           stacked lanes of one gather_rows and the compare after it;
+           `lo` monotone, as Q5's probe in l_orderkey order gives it,
+           and random
+  lanes    (PR 32) a stacked gather by the lanes it carries, one to ten
+  dense    (PR 32) the dense unique lookup's row-table read as a 1-D
+           gather and as a one-lane row gather
 
-    chiprun -- python scripts/microbench_compact.py
+    chiprun -- python scripts/microbench_compact.py [compact lookup lanes dense]
 
 `exchange-pack` (PERF.md, PR 30) isolates the send buffers of
 `parallel/exchange.exchange_by_target` at the mesh Q5's three SF1
@@ -47,16 +58,24 @@ import numpy as np
 from tidb_tpu.executor.sortops import (
     bits_for,
     compaction_index,
+    from_lanes,
+    gather_rows,
     merge_searchsorted,
     sort_rows,
+    to_lanes,
     unpack_lex,
 )
-from tidb_tpu.parallel.exchange import _from_lanes, _to_lanes
 from tidb_tpu.utils.backend import backend_label, enable_compile_cache
 
 jax.config.update("jax_enable_x64", True)
 
-SHAPES = [(6_291_456, 2_097_152, 0.29), (2_097_152, 262_144, 0.11)]
+# Join#7 and Join#6 on one chip, Join#7 on a shard of four
+SHAPES = [
+    (6_291_456, 2_097_152, 0.29), (2_097_152, 262_144, 0.11),
+    (1_572_864, 524_288, 0.29),
+]
+# (probe rows, build rows) of Join#6's sorted unique lookup: one chip, a shard
+LOOKUP_SHAPES = [(2_097_152, 2_097_152), (524_288, 524_288)]
 # (cap, n, B): Join#6's lineitem and orders sides, Join#5's (PERF.md, PR 29)
 PACK_SHAPES = [(524_288, 4, 131_072), (393_216, 4, 131_072), (65_536, 4, 16_384)]
 
@@ -133,12 +152,95 @@ def compact():
             d64, ok, sel,
         )
 
+        got = timeit(
+            f"gather 4 x (int64, bool), sortops.gather_rows  {tag}",
+            jax.jit(gather_rows), d64, ok, sel,
+        )
+        for have, src in zip(got[0] + got[1], d64 + ok):
+            assert (np.asarray(have) == np.asarray(src)[np.asarray(sel)]).all()
+
         def scatter(a, v):
             pos = jnp.where(v, jnp.cumsum(v) - 1, out_cap)
             return jnp.zeros(out_cap, a.dtype).at[pos].set(a, mode="drop")
 
         timeit(f"scatter int64 (the form deleted)  {tag}",
                jax.jit(scatter), d64[0], valid, reps=2)
+
+
+def lookup():
+    rng = np.random.default_rng(32)
+    for m, bcap in LOOKUP_SHAPES:
+        skey = jnp.asarray(np.sort(rng.choice(1 << 40, bcap, replace=False)))
+        svalid = jnp.asarray(np.arange(bcap) < int(bcap * 0.72))
+        sperm = jnp.asarray(rng.permutation(bcap).astype(np.int32))
+        for order in ("monotone", "random"):
+            at = rng.integers(0, bcap, m).astype(np.int32)
+            lo = jnp.asarray(np.sort(at) if order == "monotone" else at)
+            # three probes in four hit, as keys drawn from the build do
+            pkey = jnp.where(
+                jnp.asarray(rng.random(m) < 0.75), skey[lo], jnp.int64(-1)
+            )
+            tag = f"{m} probes of {bcap}, lo {order}"
+
+            def three(skey, svalid, sperm, lo, pkey):
+                return sperm[lo], svalid[lo] & (skey[lo] == pkey)
+
+            def four_lanes(skey, svalid, sperm, lo, pkey):
+                (key_at, brow), (valid_at,) = gather_rows(
+                    [skey, sperm], [svalid], lo
+                )
+                return brow, valid_at & (key_at == pkey)
+
+            want = timeit(f"lookup  three gathers, compare fused  {tag}",
+                          jax.jit(three), skey, svalid, sperm, lo, pkey)
+            got = timeit(f"lookup  four stacked lanes, compare after  {tag}",
+                         jax.jit(four_lanes), skey, svalid, sperm, lo, pkey)
+            for w, g in zip(want, got):
+                assert (np.asarray(w) == np.asarray(g)).all()
+
+
+def lanes():
+    """What a stacked gather costs by the lanes it carries, at Join#7's
+    one-chip shape: the 10-lane probe side read 27 ns a row where four
+    lanes of a 2,097,152-row source read 4.5 (PERF.md, PR 32)."""
+    rng = np.random.default_rng(33)
+    cap, out_cap, density = SHAPES[0]
+    sel = jnp.asarray(
+        np.nonzero(rng.random(cap) < density)[0][:out_cap].astype(np.int32)
+    )
+    sel = jnp.concatenate([sel, jnp.zeros(out_cap - sel.shape[0], jnp.int32)])
+    cols = [jnp.asarray(rng.integers(0, 1 << 32, cap, dtype=np.uint32))
+            for _ in range(10)]
+    for k in (1, 2, 3, 4, 6, 10):
+        timeit(
+            f"gather_rows  {k} u32 lanes ({k * cap * 4 >> 20} MiB)  {cap} -> {out_cap}",
+            jax.jit(lambda ds, s: gather_rows(ds, [], s)[0]), cols[:k], sel,
+        )
+    timeit(
+        f"gather_rows  10 u32 lanes as 5 gathers of 2  {cap} -> {out_cap}",
+        jax.jit(lambda ds, s: [
+            gather_rows(ds[i:i + 2], [], s)[0] for i in range(0, 10, 2)
+        ]), cols, sel,
+    )
+
+
+def dense():
+    """Join#7's dense-table read: 6,291,456 probe rows into a row table
+    of 10,000 build keys (44.9 ms of a one-chip Q5), as the plain 1-D
+    gather the join holds and as a one-lane row gather either way up."""
+    rng = np.random.default_rng(34)
+    for m, span in ((6_291_456, 10_000), (1_572_864, 10_000)):
+        tab = jnp.asarray(rng.permutation(span).astype(np.int32))
+        off = jnp.asarray(rng.integers(0, span, m).astype(np.int32))
+        tag = f"{m} probes of a {span}-entry table"
+        want = timeit(f"dense  tab[off]  {tag}", jax.jit(lambda t, o: t[o]), tab, off)
+        for name, fn in (
+            ("tab[None, :][:, off]", lambda t, o: t[None, :][:, o][0]),
+            ("tab[:, None][off]", lambda t, o: t[:, None][o][:, 0]),
+            ("gather_rows([tab])", lambda t, o: gather_rows([t], [], o)[0][0]),
+        ):
+            got = timeit(f"dense  {name}  {tag}", jax.jit(fn), tab, off)
+            assert (np.asarray(got) == np.asarray(want)).all()
 
 
 def exchange_pack():
@@ -213,7 +315,7 @@ def exchange_pack():
 
         def stacked(axis, index=index_gather, unpack=True):
             def run(perm, sorted_t, start, arrs):
-                lanes = [l for a in arrs[:-1] for l in _to_lanes(a)] + [arrs[-1]]
+                lanes = [l for a in arrs[:-1] for l in to_lanes(a)] + [arrs[-1]]
                 idx, filled = index(perm, start)
                 if axis == 1:
                     got = masked(filled, jnp.stack(lanes, axis=1)[idx])  # [n, B, L]
@@ -224,20 +326,20 @@ def exchange_pack():
                 if not unpack:
                     return got
                 return [
-                    _from_lanes(got[2 * i:2 * i + 2], jnp.int64)
+                    from_lanes(got[2 * i:2 * i + 2], jnp.int64)
                     for i in range(len(arrs) - 1)
                 ] + [got[-1]]
             return run
 
         def stacked_slices(perm, sorted_t, start, arrs):
-            lanes = [l for a in arrs[:-1] for l in _to_lanes(a)] + [arrs[-1]]
+            lanes = [l for a in arrs[:-1] for l in to_lanes(a)] + [arrs[-1]]
             rows = jnp.stack(lanes, axis=1)[perm]
             rows = jnp.concatenate([rows, jnp.zeros((B, len(lanes)), rows.dtype)])
             got = masked(slots(start)[1], jnp.stack([
                 jax.lax.dynamic_slice_in_dim(rows, start[b], B) for b in range(n)
             ]))
             return [
-                _from_lanes([got[..., 2 * i], got[..., 2 * i + 1]], jnp.int64)
+                from_lanes([got[..., 2 * i], got[..., 2 * i + 1]], jnp.int64)
                 for i in range(len(arrs) - 1)
             ] + [got[..., -1]]
 
@@ -269,4 +371,7 @@ def exchange_pack():
 if __name__ == "__main__":
     enable_compile_cache()
     print("backend:", backend_label(), flush=True)
-    exchange_pack() if sys.argv[1:] == ["exchange-pack"] else compact()
+    modes = {"compact": compact, "lookup": lookup, "lanes": lanes,
+             "dense": dense, "exchange-pack": exchange_pack}
+    for mode in sys.argv[1:] or ["compact", "lookup"]:
+        modes[mode]()

@@ -458,11 +458,46 @@ def test_sorted_unique_lookup_matches_dense():
 _PCAP, _OCAP = 4096, 1024
 
 
+# data columns beyond the (int64, int64, bool) a side starts with: the
+# narrow and the floating dtypes, float64 among them (it cannot ride as
+# lanes), and int32 columns enough that the probe side's validity bits
+# (36 columns and, in a left join, the match flag) need two u32 words
+_EXTRA_BUILD = [("b8", np.int8), ("bf32", np.float32), ("bd", np.float64)]
+_EXTRA_PROBE = [("p8", np.int8), ("pf32", np.float32), ("pd", np.float64)] + [
+    (f"w{i}", np.int32) for i in range(30)
+]
+_JOIN_NAMES = (
+    ["pk", "pv", "pb"] + [n for n, _ in _EXTRA_PROBE]
+    + ["bk", "bv", "bf"] + [n for n, _ in _EXTRA_BUILD]
+)
+
+
+def _extra_cols(rng, specs, n, cap):
+    """({name: DevCol}, {name: python values, None for NULL}) of random
+    columns of the given numpy dtypes: n rows, one in ten NULL."""
+    from tidb_tpu.chunk import DevCol
+
+    cols, values = {}, {}
+    for name, dt in specs:
+        if np.issubdtype(dt, np.floating):
+            arr = (rng.standard_normal(n) * 1e6).astype(dt)
+        else:
+            info = np.iinfo(dt)
+            arr = rng.integers(info.min, info.max, n, endpoint=True).astype(dt)
+        ok = rng.random(n) >= 0.1
+        data, valid = np.zeros(cap, dtype=dt), np.zeros(cap, dtype=bool)
+        data[:n], valid[:n] = arr, ok
+        cols[name] = DevCol(jnp.asarray(data), jnp.asarray(valid))
+        values[name] = [a.item() if o else None for a, o in zip(arr, ok)]
+    return cols, values
+
+
 def _unique_join_sides(join_type, fill, bcap=2048):
     """(build, probe, expected rows in probe order). NULL keys on both
     sides, NULL values, invalid probe rows scattered through the tile,
-    a 64-bit and a bool column a side; the probe's row_valid is thinned
-    until the join emits exactly the count `fill` asks for."""
+    a 64-bit, a bool, an int8, a float32 and a float64 column a side,
+    36 columns on the probe's; the probe's row_valid is thinned until
+    the join emits exactly the count `fill` asks for."""
     from tidb_tpu.dtypes import BOOL
 
     rng = np.random.default_rng(28)
@@ -490,20 +525,31 @@ def _unique_join_sides(join_type, fill, bcap=2048):
     for i in rng.permutation(emitting)[: len(emitting) - want_n]:
         alive[i] = False
 
+    bx_cols, bx = _extra_cols(rng, _EXTRA_BUILD, nb, bcap)
+    px_cols, px = _extra_cols(rng, _EXTRA_PROBE, npr, _PCAP)
     expected = []
     for i in np.nonzero(alive)[0]:
         j = by_key.get(pk[i])
         if j is None and join_type == "inner":
             continue
-        build_vals = (None, None, None) if j is None else (bk[j], bv[j], bf[j])
-        expected.append((pk[i], pv[i], pb[i]) + build_vals)
+        build_vals = (
+            (None,) * (3 + len(bx)) if j is None
+            else (bk[j], bv[j], bf[j]) + tuple(v[j] for v in bx.values())
+        )
+        expected.append(
+            (pk[i], pv[i], pb[i]) + tuple(v[i] for v in px.values()) + build_vals
+        )
     assert len(expected) == want_n
 
     build = _mk({"bk": (bk, INT64), "bv": (bv, INT64), "bf": (bf, BOOL)}, bcap)
     probe = _mk({"pk": (pk, INT64), "pv": (pv, INT64), "pb": (pb, BOOL)}, _PCAP)
     rv = np.zeros(_PCAP, dtype=bool)
     rv[:npr] = alive
-    return build, Batch(probe.cols, jnp.asarray(rv)), expected
+    return (
+        Batch({**build.cols, **bx_cols}, build.row_valid),
+        Batch({**probe.cols, **px_cols}, jnp.asarray(rv)),
+        expected,
+    )
 
 
 @pytest.mark.parametrize("fill", ["under", "full", "over"])
@@ -530,10 +576,13 @@ def test_unique_join_compacts_like_a_plain_join(join_type, lookup, fill):
     assert out.capacity == _OCAP and int(total) == len(expected)
     n = min(len(expected), _OCAP)
     assert np.asarray(out.row_valid).tolist() == [True] * n + [False] * (_OCAP - n)
-    names = ["pk", "pv", "pb", "bk", "bv", "bf"]
+    names = _JOIN_NAMES
+    assert sorted(out.cols) == sorted(names)
     data = {c: np.asarray(out.cols[c].data) for c in names}
     valid = {c: np.asarray(out.cols[c].valid) for c in names}
     assert data["pv"].dtype == np.int64 and data["pb"].dtype == np.bool_
+    for c, dt in _EXTRA_PROBE + _EXTRA_BUILD:
+        assert data[c].dtype == dt, c
     got = [
         tuple(data[c][j].item() if valid[c][j] else None for c in names)
         for j in range(n)
@@ -552,9 +601,23 @@ def _lowered(fn, *args):
     return collections.Counter(re.findall(r"stablehlo\.(\w+)", text)), text
 
 
+def _ops_under(text, op, scope):
+    """Scope stacks (op_name) of the `stablehlo.<op>` ops lowered under a
+    named scope, from _lowered's text: an op line names a location
+    alias, the alias's definition holds the op_name."""
+    import re
+
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    at = re.findall(rf'"?stablehlo\.{op}"?\(.*loc\((#loc\d+)\)$', text, re.M)
+    return [names[a] for a in at if scope in names.get(a, "")]
+
+
 def test_unique_join_compaction_holds_no_scatter():
-    """The branch pays per OUTPUT row: one sort for the index, gathers
-    for the columns. A scatter pays per probe row and the v5e runs it
+    """The branch pays per OUTPUT row, and a gathered row costs the v5e
+    the same with one lane or eleven: one sort for the index, then ONE
+    gather a side of its columns' stacked lanes, where PR 28 had one a
+    column and one a validity array (55 % of a Q5's device time,
+    PERF.md PR 32). A scatter pays per probe row and the v5e runs it
     serially (2.46 s of Q5's 3.17 s at SF1, PERF.md PR 28). Sorted
     lookup (no bounds), as Q5's sizes take: the dense table build is a
     scatter of its own, per BUILD row."""
@@ -563,19 +626,128 @@ def test_unique_join_compaction_holds_no_scatter():
 
     def join(out_capacity):
         return lambda b, p: equi_join(
-            b, p, _col("bk"), _col("pk"), out_capacity, "inner", build_unique=True
+            b, p, _col("bk"), _col("pk"), out_capacity, "inner",
+            build_unique=True, keep=(("pk", "pv", "pb"), ("bk", "bv", "bf")),
         )
 
     build, probe, _expected = _unique_join_sides("inner", "under")
     engaged = REGISTRY.counter("tidbtpu_executor_join_compactions_total")
-    before = engaged.value
+    stacked = REGISTRY.counter("tidbtpu_executor_stacked_gathers_total")
+    before, at = engaged.value, stacked.value
     ops, text = _lowered(join(_OCAP), build, probe)
     assert "scatter" not in ops
-    assert ops["gather"] >= 12 and "/compact/" in text  # six (data, valid) pairs
+    # six int columns and their validity: a gather a side
+    under_compact = _ops_under(text, "gather", "/compact/")
+    assert 2 <= len(under_compact) <= 3, under_compact
     assert engaged.value == before + 1  # once per traced program
-    # the probe tile as it stands: nothing to compact, nothing counted
+    # the lookup's read and the two of the compaction, a side each
+    assert stacked.value == at + 3
+    # the probe tile as it stands: nothing to compact, nothing counted,
+    # and the probe side does not move (the lookup's read, the build's)
     _lowered(join(_PCAP), build, probe)
-    assert engaged.value == before + 1
+    assert engaged.value == before + 1 and stacked.value == at + 5
+
+
+_GATHER_DTYPES = [
+    np.int64, np.uint64, np.int32, np.uint32, np.int16, np.int8, np.uint8,
+    np.bool_, np.float32, np.float64,
+]
+
+
+def _gather_index(kind, cap, m, rng):
+    if kind == "monotone":
+        return np.sort(rng.choice(cap, m, replace=False)).astype(np.int32)
+    if kind == "random":
+        return rng.permutation(cap)[:m].astype(np.int32)
+    if kind == "repeated":
+        return rng.integers(0, 7, m).astype(np.int32)
+    return rng.integers(0, 3 * cap, m).astype(np.int64)  # clipped to the tile
+
+
+@pytest.mark.parametrize(
+    "index", ["monotone", "random", "repeated", "out-of-tile"]
+)
+@pytest.mark.parametrize("dtype", _GATHER_DTYPES, ids=lambda d: np.dtype(d).name)
+def test_gather_rows_is_its_numpy_reference(dtype, index):
+    """sortops.gather_rows moves what it is handed bit for bit: two
+    arrays of the dtype (and an int64 beside them, so limbs of unlike
+    widths share the operand), 40 flags (two words), through every kind
+    of index; an index past the tile reads its last row, as np.take
+    with mode="clip" does. A float64 rides beside the lanes."""
+    from tidb_tpu.executor.sortops import gather_rows
+
+    rng = np.random.default_rng(32)
+    cap, m = 1000, 777
+
+    def draw(dt):
+        if dt == np.bool_:
+            return rng.random(cap) < 0.5
+        bits = rng.integers(0, 256, cap * np.dtype(dt).itemsize, dtype=np.uint8)
+        arr = bits.view(dt)
+        if np.issubdtype(dt, np.floating):  # every bit pattern but NaN's
+            arr = np.where(np.isnan(arr), dt(-0.0), arr)
+        return arr.astype(dt)
+
+    datas = [draw(dtype), draw(np.int64), draw(dtype)]
+    flags = [rng.random(cap) < 0.5 for _ in range(40)]
+    idx = _gather_index(index, cap, m, rng)
+    got_d, got_f = jax.jit(gather_rows)(
+        [jnp.asarray(d) for d in datas], [jnp.asarray(f) for f in flags],
+        jnp.asarray(idx),
+    )
+    assert len(got_d) == 3 and len(got_f) == 40
+    for g, d in zip(got_d, datas):
+        want = np.take(d, idx, mode="clip")
+        g = np.asarray(g)
+        assert g.dtype == d.dtype
+        assert g.tobytes() == want.tobytes()
+    for g, f in zip(got_f, flags):
+        assert np.asarray(g).dtype == np.bool_
+        assert (np.asarray(g) == np.take(f, idx, mode="clip")).all()
+
+
+def test_gather_rows_of_nothing_gathers_nothing():
+    from tidb_tpu.executor.sortops import gather_rows
+    from tidb_tpu.utils.metrics import REGISTRY
+
+    stacked = REGISTRY.counter("tidbtpu_executor_stacked_gathers_total")
+    at = stacked.value
+    ops, _ = _lowered(lambda i: gather_rows([], [], i), jnp.arange(8))
+    assert "gather" not in ops and stacked.value == at
+
+
+@pytest.mark.parametrize("path", ["compact", "in-place", "expand"])
+@pytest.mark.parametrize("join_type", ["inner", "left"])
+def test_a_join_emits_only_what_its_readers_read(join_type, path):
+    """With `keep` (JoinPlan.needs through the planner) the output batch
+    holds exactly those names, with the cells it holds without; with
+    keep=None, every column of both sides. A stacked operand keeps every
+    lane alive, so what nothing reads must not be stacked (PERF.md, PR
+    30: 147 ms against 87 in one join until pruned)."""
+    from tidb_tpu.executor.join import equi_join
+
+    build, probe, _expected = _unique_join_sides(join_type, "under")
+    cap = {"compact": _OCAP, "in-place": _PCAP, "expand": _PCAP}[path]
+
+    def join(keep):
+        return jax.jit(lambda b, p: equi_join(
+            b, p, _col("bk"), _col("pk"), cap, join_type,
+            build_unique=path != "expand", keep=keep,
+        ))(build, probe)
+
+    (full, n_full), (some, n_some) = join(None), join((("pk", "pd"), ("bv",)))
+    assert sorted(full.cols) == sorted(_JOIN_NAMES)
+    assert sorted(some.cols) == ["bv", "pd", "pk"]
+    assert int(n_full) == int(n_some) == 700
+    assert (np.asarray(full.row_valid) == np.asarray(some.row_valid)).all()
+    for c in some.cols:
+        ok = np.asarray(some.cols[c].valid)
+        assert (ok == np.asarray(full.cols[c].valid)).all()
+        got, want = np.asarray(some.cols[c].data), np.asarray(full.cols[c].data)
+        assert (got[ok] == want[ok]).all()
+    # a side nothing reads is not gathered at all
+    none, _n = join(((), ()))
+    assert not none.cols and int(_n) == 700
 
 
 def test_compact_impl_lowers_as_before_the_shared_index():

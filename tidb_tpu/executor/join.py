@@ -150,17 +150,23 @@ def _dense_unique_lookup(bkey, bvalid, lo: int, hi: int, span: int,
 def _sorted_unique_lookup(bkey, bvalid, bcap: int, pkey, pvalid):
     """Sorted 1:1 lookup into a planner-proven-unique build key:
     (brow, matched, stale) probe-aligned. ONE searchsorted + one gather
-    (uniqueness makes `hi` redundant: a hit is an equality at lo).
+    of the sorted build's (key, validity, row) at lo (uniqueness makes
+    `hi` redundant: a hit is an equality at lo).
     stale must be the build-side adjacent-duplicate check — a
     probe-derived hi-lo>1 would also fire on garbage probe lanes equal
     to the invalid-row int64-max sentinel run, and a spurious stale is
     a recompile livelock."""
+    from tidb_tpu.executor.sortops import gather_rows
+
     skey, svalid, sperm = _sort_build(bkey, bvalid, bcap)
     lo, _hi = _probe_lo_hi(skey, pkey, need_hi=False)
-    lo_c = jnp.clip(lo, 0, bcap - 1)
-    matched = pvalid & (lo < bcap) & svalid[lo_c] & (skey[lo_c] == pkey)
+    with jax.named_scope("lookup"):
+        (key_at, brow), (valid_at,) = gather_rows(
+            [skey, sperm], [svalid], jnp.clip(lo, 0, bcap - 1)
+        )
+    matched = pvalid & (lo < bcap) & valid_at & (key_at == pkey)
     stale = jnp.any(svalid[1:] & (skey[1:] == skey[:-1]))
-    return sperm[lo_c], matched, stale
+    return brow, matched, stale
 
 
 def lookup_build_rows(
@@ -192,32 +198,66 @@ def lookup_build_rows(
     return _sorted_unique_lookup(bkey, bvalid, bcap, pkey, pvalid)
 
 
+def gather_cols(batch: Batch, index: jax.Array, names=None) -> Dict[str, DevCol]:
+    """The columns of `batch` (those in `names`; None: all) at rows
+    `index`, moved by ONE gather of their stacked lanes
+    (sortops.gather_rows)."""
+    from tidb_tpu.executor.sortops import gather_rows
+
+    cols = {n: c for n, c in batch.cols.items() if names is None or n in names}
+    data, flag = gather_rows(
+        [c.data for c in cols.values()], [c.valid for c in cols.values()], index
+    )
+    return {n: DevCol(d, v) for n, d, v in zip(cols, data, flag)}
+
+
 def _emit(
     probe: Batch,
     build: Batch,
     prow: Optional[jax.Array],
     brow: jax.Array,
     out_valid: jax.Array,
-    bmatched: jax.Array,
+    bmatched: Optional[jax.Array],
     probe_prefix: str,
     build_prefix: str,
+    keep=None,
+    aligned: bool = False,
 ) -> Batch:
-    """The output tile of an inner/left join by gathers: slot j holds
-    probe row prow[j] (None: the probe tile as it stands, slot j = row j)
-    beside build row brow[j]; build columns are NULL where ~bmatched
-    (a left join's unmatched row). Every column is invalid where
-    ~out_valid; the data there is whatever the gather met."""
+    """The output tile of an inner/left join, a side by ONE gather of
+    its columns' stacked lanes (sortops.gather_rows): slot j holds probe
+    row prow[j] (None: the probe tile as it stands, slot j = row j)
+    beside build row brow[j]; build columns are NULL where ~bmatched (a
+    left join's unmatched row; None: none is). `aligned`: brow and
+    bmatched are indexed by probe row, as a unique lookup gives them,
+    and move through prow as lanes beside the probe's columns. `keep`:
+    the (probe, build) column names something reads; None emits every
+    column. Every column is invalid where ~out_valid; the data there is
+    whatever the gather met."""
+    from tidb_tpu.executor.sortops import gather_rows
+
+    pnames, bnames = keep if keep is not None else (None, None)
+    pcols = {
+        n: c for n, c in probe.cols.items() if pnames is None or n in pnames
+    }
+    pdata = [c.data for c in pcols.values()]
+    pflag = [c.valid for c in pcols.values()]
+    if prow is not None:
+        if aligned:
+            pdata.append(brow)
+            if bmatched is not None:
+                pflag.append(bmatched)
+        pdata, pflag = gather_rows(pdata, pflag, prow)
+        if aligned:
+            brow = pdata.pop()
+            if bmatched is not None:
+                bmatched = pflag.pop()
+    bcols = gather_cols(build, brow, bnames)
+    bvalid = out_valid if bmatched is None else out_valid & bmatched
     cols: Dict[str, DevCol] = {}
-    for name, c in probe.cols.items():
-        data, valid = (
-            (c.data, c.valid) if prow is None
-            else (c.data[prow], c.valid[prow])
-        )
-        cols[probe_prefix + name] = DevCol(data, valid & out_valid)
-    for name, c in build.cols.items():
-        cols[build_prefix + name] = DevCol(
-            c.data[brow], c.valid[brow] & out_valid & bmatched
-        )
+    for name, d, v in zip(pcols, pdata, pflag):
+        cols[probe_prefix + name] = DevCol(d, v & out_valid)
+    for name, c in bcols.items():
+        cols[build_prefix + name] = DevCol(c.data, c.valid & bvalid)
     return Batch(cols, out_valid)
 
 
@@ -234,6 +274,7 @@ def equi_join(
     mark_three_valued: bool = True,
     build_bounds: Optional[Tuple[int, int]] = None,
     build_unique: bool = False,
+    keep=None,
 ) -> Tuple[Batch, jax.Array]:
     """Returns (joined batch, true output row count).
 
@@ -248,7 +289,12 @@ def equi_join(
     unique (build_unique: PK / unique index / GROUP BY output).
     Both bounds and uniqueness are runtime-verified; violations report
     the WIDTH_STALE sentinel in place of the row count and the executor
-    recompiles with fresh bounds."""
+    recompiles with fresh bounds.
+
+    keep: the (probe, build) column names the join's readers use
+    (JoinPlan.needs through the planner): an inner/left join emits only
+    those, since a side's columns move as ONE stacked operand from which
+    XLA can drop no unread column. None emits every column."""
 
     from tidb_tpu.utils.failpoint import inject
 
@@ -317,7 +363,7 @@ def equi_join(
         if not 0 < out_capacity < probe.capacity:
             out = _emit(
                 probe, build, None, brow, out_valid,
-                out_valid if inner else matched, probe_prefix, build_prefix,
+                None if inner else matched, probe_prefix, build_prefix, keep,
             )
             return out, total
         from tidb_tpu.executor.sortops import compaction_index
@@ -328,21 +374,24 @@ def equi_join(
         REGISTRY.counter(
             "tidbtpu_executor_join_compactions_total",
             "unique-build joins traced with an output tile smaller "
-            "than the probe tile (one compaction index + gathers)",
+            "than the probe tile (one compaction index + two gathers)",
         ).inc()
         with jax.named_scope("compact"):
-            # slot j <- probe row sel[j], in probe order; the build side
-            # is gathered once, at out_capacity rows, through the
-            # composed index. Slots from `total` on hold some dropped
-            # row's data under valid == False. The tile's validity is
-            # an iota compare, not the index's `filled`: that one hangs
-            # on the sort's output and the v5e compiler fuses it into
-            # a column's gather, 24 ms dearer at 2,097,152 rows (PR 28).
+            # slot j <- probe row sel[j], in probe order: the probe's
+            # columns, and beside them the lookup's build row (and a
+            # left join's match flag), by one gather through sel; the
+            # build's columns by one more, at out_capacity rows,
+            # through the build row that one brought. Slots from `total`
+            # on hold some dropped row's data under valid == False. The
+            # tile's validity is an iota compare, not the index's
+            # `filled`: that one hangs on the sort's output and the v5e
+            # compiler fuses it into a column's gather, 24 ms dearer at
+            # 2,097,152 rows (PR 28).
             sel, _filled = compaction_index(out_valid, out_capacity)
             rv = jnp.arange(out_capacity) < jnp.minimum(total, out_capacity)
             out = _emit(
-                probe, build, sel, brow[sel], rv,
-                rv if inner else matched[sel], probe_prefix, build_prefix,
+                probe, build, sel, brow, rv, None if inner else matched,
+                probe_prefix, build_prefix, keep, aligned=True,
             )
         return out, total
 
@@ -416,6 +465,6 @@ def equi_join(
 
     out = _emit(
         probe, build, prow_c, brow, out_valid, bmatched,
-        probe_prefix, build_prefix,
+        probe_prefix, build_prefix, keep,
     )
     return out, total

@@ -157,6 +157,93 @@ def compaction_index(valid: jax.Array, out_cap: int):
     return perm[:out_cap], unpack_lex(ops, where, 0)[:out_cap] == 0
 
 
+def pack_bits(bit0: jax.Array, flags) -> jax.Array:
+    """A u32 word a row: `bit0` (a u32 of 0 or 1), then one bit a flag."""
+    word = bit0
+    for i, flag in enumerate(flags):
+        word = word | (flag.astype(jnp.uint32) << (1 + i))
+    return word
+
+
+def to_lanes(arr: jax.Array):
+    """A column's values as u32 lanes, exactly: one lane for 32 bits or
+    fewer (a narrow value widened first), the high and the low limb of
+    a 64-bit integer. None for a float64, which the v5e compiler cannot
+    bitcast: it travels beside the lanes."""
+    dt = arr.dtype
+    if dt == jnp.float64:
+        return None
+    if dt.itemsize == 8:
+        u = jax.lax.bitcast_convert_type(arr, jnp.uint64)
+        return [(u >> jnp.uint64(32)).astype(jnp.uint32), u.astype(jnp.uint32)]
+    if dt == jnp.bool_ or jnp.issubdtype(dt, jnp.unsignedinteger):
+        return [arr.astype(jnp.uint32)]
+    wide = jnp.int32 if jnp.issubdtype(dt, jnp.integer) else jnp.float32
+    return [jax.lax.bitcast_convert_type(arr.astype(wide), jnp.uint32)]
+
+
+def from_lanes(lanes, dtype) -> jax.Array:
+    """Inverse of to_lanes."""
+    dt = jnp.dtype(dtype)
+    if dt.itemsize == 8:
+        hi, lo = (x.astype(jnp.uint64) for x in lanes)
+        return jax.lax.bitcast_convert_type((hi << jnp.uint64(32)) | lo, dt)
+    (u,) = lanes
+    if dt == jnp.bool_ or jnp.issubdtype(dt, jnp.unsignedinteger):
+        return u.astype(dt)
+    wide = jnp.int32 if jnp.issubdtype(dt, jnp.integer) else jnp.float32
+    return jax.lax.bitcast_convert_type(u, wide).astype(dt)
+
+
+def gather_rows(datas, flags, index: jax.Array):
+    """([d[index] for d in datas], [f[index] for f in flags]), bit for
+    bit, by ONE gather: the engine's one way to move the rows of a tile.
+    On the v5e a gather pays per OUTPUT row and no more for a row of
+    many lanes than for a row of one (5 ns at 524,288 rows with one u32
+    lane or eleven, PERF.md PR 30), so every array that moves through
+    the same index rides as u32 lanes of one stacked operand: an
+    integer, bool or float32 array as its exact limbs (to_lanes), the
+    boolean flags as the bits of u32 words, 32 a word. A float64 array
+    takes a gather of its own through the same index (to_lanes says
+    why). Index values past the tile are clipped, as plain indexing
+    clips them. Pass only arrays something reads: a stacked operand
+    keeps every lane alive where XLA drops an unread column's own
+    gather and the chain that made it (PERF.md PR 30).
+
+    Counted while the program is traced: once per call per compiled
+    program."""
+    from tidb_tpu.utils.metrics import REGISTRY
+
+    datas, flags = list(datas), list(flags)
+    lanes, spans = [], []
+    for d in datas:
+        limbs = to_lanes(d)
+        spans.append(None if limbs is None else (len(lanes), len(limbs)))
+        lanes += limbs or []
+    words_at = len(lanes)
+    lanes += [
+        pack_bits(flags[at].astype(jnp.uint32), flags[at + 1:at + 32])
+        for at in range(0, len(flags), 32)
+    ]
+    if lanes:
+        REGISTRY.counter(
+            "tidbtpu_executor_stacked_gathers_total",
+            "row gathers of stacked u32 lanes (sortops.gather_rows) in "
+            "traced programs",
+        ).inc()
+        got = jnp.stack(lanes)[:, index]
+    out = [
+        d[index] if span is None
+        else from_lanes([got[span[0] + k] for k in range(span[1])], d.dtype)
+        for d, span in zip(datas, spans)
+    ]
+    bits = [
+        ((got[words_at + i // 32] >> (i % 32)) & 1) != 0
+        for i in range(len(flags))
+    ]
+    return out, bits
+
+
 def int_sort_bits(d: jax.Array):
     """(unsigned order-preserving image of an integer/bool array, bits):
     the value less its dtype's minimum."""

@@ -165,44 +165,6 @@ def range_repartition(
     )
 
 
-def _pack_bits(bit0: jax.Array, flags) -> jax.Array:
-    """A u32 word a row: `bit0` (a u32 of 0 or 1), then one bit a flag."""
-    word = bit0
-    for i, flag in enumerate(flags):
-        word = word | (flag.astype(jnp.uint32) << (1 + i))
-    return word
-
-
-def _to_lanes(arr: jax.Array):
-    """A column's values as u32 lanes, exactly: one lane for 32 bits or
-    fewer (a narrow value widened first), the high and the low limb of
-    a 64-bit integer. None for a float64, which the v5e compiler cannot
-    bitcast: it travels beside the lanes."""
-    dt = arr.dtype
-    if dt == jnp.float64:
-        return None
-    if dt.itemsize == 8:
-        u = jax.lax.bitcast_convert_type(arr, jnp.uint64)
-        return [(u >> jnp.uint64(32)).astype(jnp.uint32), u.astype(jnp.uint32)]
-    if dt == jnp.bool_ or jnp.issubdtype(dt, jnp.unsignedinteger):
-        return [arr.astype(jnp.uint32)]
-    wide = jnp.int32 if jnp.issubdtype(dt, jnp.integer) else jnp.float32
-    return [jax.lax.bitcast_convert_type(arr.astype(wide), jnp.uint32)]
-
-
-def _from_lanes(lanes, dtype) -> jax.Array:
-    """Inverse of _to_lanes."""
-    dt = jnp.dtype(dtype)
-    if dt.itemsize == 8:
-        hi, lo = (x.astype(jnp.uint64) for x in lanes)
-        return jax.lax.bitcast_convert_type((hi << jnp.uint64(32)) | lo, dt)
-    (u,) = lanes
-    if dt == jnp.bool_ or jnp.issubdtype(dt, jnp.unsignedinteger):
-        return u.astype(dt)
-    wide = jnp.int32 if jnp.issubdtype(dt, jnp.integer) else jnp.float32
-    return jax.lax.bitcast_convert_type(u, wide).astype(dt)
-
-
 def exchange_by_target(
     batch: Batch,
     target: jax.Array,
@@ -230,7 +192,9 @@ def exchange_by_target(
     B = bucket_capacity
     cap = batch.capacity
 
-    from tidb_tpu.executor.sortops import bits_for, sort_rows, unpack_lex
+    from tidb_tpu.executor.sortops import (
+        bits_for, from_lanes, pack_bits, sort_rows, to_lanes, unpack_lex,
+    )
     from tidb_tpu.parallel.mesh import pmax
 
     with jax.named_scope("exchange"):
@@ -281,14 +245,14 @@ def exchange_by_target(
             filled = jnp.arange(B, dtype=jnp.int32)[None, :] < kept[:, None]
             present = jnp.ones((cap,), dtype=jnp.uint32)
             lanes = [
-                _pack_bits(
+                pack_bits(
                     present, [batch.cols[c].valid for c in names[at:at + 31]]
                 )
                 for at in range(0, max(len(names), 1), 31)
             ]
             span, apart = {}, {}
             for c in names:
-                limbs = _to_lanes(batch.cols[c].data)
+                limbs = to_lanes(batch.cols[c].data)
                 if limbs is None:
                     apart[c] = jnp.where(filled, batch.cols[c].data[row], 0)
                 else:
@@ -304,7 +268,7 @@ def exchange_by_target(
                     d = jax.lax.all_to_all(apart[name], axis, 0, 0).reshape(n * B)
                 else:
                     lo, hi = span[name]
-                    d = _from_lanes(
+                    d = from_lanes(
                         [got[k] for k in range(lo, hi)],
                         batch.cols[name].data.dtype,
                     )

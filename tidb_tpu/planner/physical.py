@@ -1876,7 +1876,7 @@ class PlanCompiler:
             ) and not (null_aware and kind == "anti"):
                 # (second disjunct: single-key correlated EXISTS whose
                 # build side is unique — same lookup, no demoted pairs)
-                from tidb_tpu.executor.join import lookup_build_rows
+                from tidb_tpu.executor.join import gather_cols, lookup_build_rows
 
                 part_nid = None
                 if mesh:
@@ -1914,15 +1914,17 @@ class PlanCompiler:
                     )
                     # joined namespace, probe-aligned: verify fns and the
                     # residual see probe cols + the matched build row's
-                    # cols (junk where unmatched — masked right after)
+                    # cols (junk where unmatched — masked right after),
+                    # those they read, moved by one stacked gather
+                    bcols = gather_cols(
+                        rb, brow, plan.needs and plan.needs[1]
+                    )
                     bb = Batch(
                         {
                             **lb.cols,
                             **{
-                                n: DevCol(
-                                    c.data[brow], c.valid[brow] & matched
-                                )
-                                for n, c in rb.cols.items()
+                                n: DevCol(c.data, c.valid & matched)
+                                for n, c in bcols.items()
                             },
                         },
                         lb.row_valid,
@@ -2118,15 +2120,20 @@ class PlanCompiler:
                 needs[part_nid] = xneed
             build_b, probe_b, build_k, probe_k = rb, lb, rkey, lkey
             build_props = rprops
+            # what the join, its verify and residual filters and its
+            # readers use of (probe, build): all the join emits
+            keep = plan.needs
             if forced_swap or (
                 kind == "inner" and not mesh and lb.capacity < rb.capacity
             ):
                 build_b, probe_b, build_k, probe_k = lb, rb, lkey, rkey
                 build_props = lprops
+                keep = keep and keep[::-1]
             cap = caps[nid] or pad_capacity(max(probe_b.capacity, 1024))
             out, total = equi_join(
                 build_b, probe_b, build_k, probe_k, cap, kind,
                 build_bounds=build_props[0], build_unique=build_props[1],
+                keep=keep,
             )
             if verify is not None:
                 lk, rk = verify
