@@ -9,10 +9,17 @@ refactor leaves the programs alone (PR 30, PR 31). About 20 s on the CPU.
     cmp /root/scratch/parent.json /root/scratch/change.json
 
 A tree from before PR 31 asked the platform for its kernels and needs
-steering first; `CHANGES.md`'s PR 31 entry has the three lines."""
+steering first; `CHANGES.md`'s PR 31 entry has the three lines.
+
+With `--q95 <sf> <seed> [<seed> ...]` after the tree it hashes instead
+the programs of TPC-DS Q95 (`tpcds_sf1`'s population at that scale
+factor) for each seed, under the labels `q95:<seed>`: the proof that
+one program serves every data set (PR 33). Two seeds' programs are the
+same where their lists are equal."""
 import hashlib, json, os, sys
 
 repo = os.path.abspath(sys.argv[1])
+Q95 = sys.argv[3:] if sys.argv[2:3] == ["--q95"] else None
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 os.chdir(repo)
@@ -54,8 +61,14 @@ def load(path, name):
 
 B = os.path.join(repo, "benchmarks")
 spec = json.load(open(os.path.join(repo, "BENCHMARK.json")))
-one_cfg = json.load(open(os.path.join(repo, spec["configs"][0]["file"])))
-mesh_cfg = json.load(open(os.path.join(repo, spec["configs"][-1]["file"])))
+
+
+def config(name):
+    (entry,) = [c for c in spec["configs"] if c["name"] == name]
+    return json.load(open(os.path.join(repo, entry["file"])))
+
+
+one_cfg, mesh_cfg = config("tpch_sf1"), config("tpch_sf1_mesh4")
 mesh_loader = load(os.path.join(B, "loaders", "tpch_mesh.py"), "bench_tpch_mesh")
 SF, SEED = 0.01, 3100000031
 
@@ -66,18 +79,25 @@ def sql_of(name):
 
 from tidb_tpu.session import Session
 
-for which, dep_cls, cfg, width, stmts in (
-    ("one", mesh_loader.tpch.Deployment, one_cfg, None, ("q1", "q5", "q6")),
-    ("mesh4", mesh_loader.Deployment, mesh_cfg, 4, ("q5",)),
-):
-    dep = dep_cls(cfg, SEED, SF)
-    sess = Session(dep.server.catalog, db="tpch", **({"mesh_devices": width} if width else {}))
+if Q95:
+    tpcds = load(os.path.join(B, "loaders", "tpcds.py"), "bench_tpcds")
+    SF = float(Q95[0])
+    runs = [(f"q95:{seed}", tpcds.Deployment, config("tpcds_sf1"), None, ("q95",), int(seed), "tpcds")
+            for seed in Q95[1:]]
+else:
+    runs = [
+        ("one", mesh_loader.tpch.Deployment, one_cfg, None, ("q1", "q5", "q6"), SEED, "tpch"),
+        ("mesh4", mesh_loader.Deployment, mesh_cfg, 4, ("q5",), SEED, "tpch"),
+    ]
+for which, dep_cls, cfg, width, stmts, seed, db in runs:
+    dep = dep_cls(cfg, seed, SF)
+    sess = Session(dep.server.catalog, db=db, **({"mesh_devices": width} if width else {}))
     label[0] = which + ":setup"
     print(which, "analyze", file=sys.stderr, flush=True)
     for s in dep.analyze_statements():
         sess.execute(s)
     for q in stmts:
-        label[0] = f"{which}:{q}"
+        label[0] = which if Q95 else f"{which}:{q}"
         print(label[0], file=sys.stderr, flush=True)
         r1 = sess.execute(sql_of(q))
         r2 = sess.execute(sql_of(q))  # steady

@@ -16,7 +16,8 @@ the devices that ran an op: the chips of a mesh run one program):
 - self time by innermost ``label#nid`` with each scope's largest ops
   and the source line that emitted them, how much of a scope lies in
   a named sub-scope an operator opens (``SUB_SCOPES``: a join's
-  ``/compact`` and ``/lookup``, executor/join.py; on a mesh an exchange's
+  ``/compact``, ``/lookup`` and ``/expand/search | gather``,
+  executor/join.py; on a mesh an exchange's
   ``/exchange/sort | pack | all-to-all`` and ``/broadcast/all-gather``,
   parallel/exchange.py), and who owns the custom
   fusions (``hlo_category`` "custom fusion": XLA's scatter-shaped
@@ -46,6 +47,8 @@ SCOPE = re.compile(r"([^/]*#\d+)(?=/|$)")
 SUB_SCOPES = (
     "compact",  # a join's output compaction (executor/join.py)
     "lookup",  # a sorted unique lookup's reads at lo (executor/join.py)
+    # an expanding join: lo/hi and the slot-to-probe search, the emit
+    "expand", "expand/search", "expand/gather",
     # a repartition and its stages, a broadcast (parallel/exchange.py)
     "exchange", "exchange/sort", "exchange/pack", "exchange/all-to-all",
     "broadcast/all-gather",

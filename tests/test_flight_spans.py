@@ -342,7 +342,10 @@ class TestNamesOnTheDevice:
         sess.execute(JOIN_AGG)
         monkeypatch.undo()
         kinds = [name for name, *_ in calls]
-        assert "discover" in kinds and "steady" in kinds
+        # the join expands (neither key is unique) and the aggregate is
+        # sorted: every knob has a first tile, so the first program may be
+        # the steady one (PR 33); else discovery runs before it
+        assert "steady" in kinds and set(kinds) <= {"discover", "steady"}
         _name, jitted, a, k = [c for c in calls if c[0] == "steady"][-1]
         hlo = jitted.lower(*a, **k).compile().as_text()
         assert hlo.startswith("HloModule jit_steady")

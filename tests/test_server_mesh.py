@@ -38,9 +38,10 @@ def bench():
     sys.path.extend(added)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         spec = json.load(f)
-    with open(os.path.join(ROOT, spec["configs"][-1]["file"])) as f:
+    files = {c["name"]: os.path.join(ROOT, c["file"]) for c in spec["configs"]}
+    with open(files["tpch_sf1_mesh4"]) as f:
         mesh_config = json.load(f)
-    with open(os.path.join(ROOT, spec["configs"][0]["file"])) as f:
+    with open(files["tpch_sf1"]) as f:
         one_config = json.load(f)
     assert mesh_config["mesh_devices"] == WIDTH and "mesh_devices" not in one_config
     harness = _load(os.path.join(BENCH, "run.py"), "bench_run_for_mesh_tests")

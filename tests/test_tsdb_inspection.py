@@ -258,18 +258,25 @@ class TestMetricsSchemaSQL:
         t_mid = time.time()
         SAMPLER.sample_once(now=t_mid - 30.0)
         SAMPLER.sample_once(now=t_mid)
-        r = sess.must_query(
-            "select time, instance, value from "
-            "metrics_schema.tidbtpu_session_statements_total "
-            f"where time >= {t_mid - 1.0}"
-        )
+        # `last_scan_points` is the store's LAST scan, whoever made it: a
+        # loop of a server another test of this process left up can scan
+        # between the statement and the read (seen in the driver's run of
+        # PR 29 and in PR 33's). Three tries; one clean one is the proof.
+        for _attempt in range(3):
+            r = sess.must_query(
+                "select time, instance, value from "
+                "metrics_schema.tidbtpu_session_statements_total "
+                f"where time >= {t_mid - 1.0}"
+            )
+            # the pushdown reached the store: only the bounded slice was
+            # materialized, not the whole ring (read the scan gauge BEFORE
+            # the unbounded count query overwrites it)
+            bounded = TSDB.last_scan_points
+            total = len(TSDB.query("tidbtpu_session_statements_total"))
+            if bounded < total:
+                break
         assert r.rows and all(row[0] >= t_mid - 1.0 for row in r.rows)
         assert all(row[1] == "coordinator" for row in r.rows)
-        # the pushdown reached the store: only the bounded slice was
-        # materialized, not the whole ring (read the scan gauge BEFORE
-        # the unbounded count query overwrites it)
-        bounded = TSDB.last_scan_points
-        total = len(TSDB.query("tidbtpu_session_statements_total"))
         assert bounded < total
 
     def test_label_columns_and_label_pushdown(self, sess):

@@ -1,10 +1,17 @@
-"""TPC-DS subset: the tables Q95 exercises + a synthetic generator.
+"""TPC-DS subset: the tables Q95 exercises + a TOY generator.
 
 Reference ladder config #5 (BASELINE.md): TPC-DS Q95 — correlated
 subqueries + multi-join over web_sales / web_returns / date_dim /
-customer_address / web_site. The generator mirrors tpch.py's approach:
-synthetic-but-faithful cardinalities/selectivities, with correctness
-checked against a numpy oracle over the SAME generated data.
+customer_address / web_site. `load_tpcds` is a toy population for the
+repo's own tests and `bench.py`, not the specification's: 72,000
+`web_sales` rows a scale factor where Table 3-2 has 719,384, about 3
+items an order where dsdgen draws 8 to 16 (the self-join of Q95 expands
+x3 here and x12.5 there), seven of the 34 columns, a 400-day `date_dim`,
+no NULL anywhere. Correctness is checked against a numpy oracle over the
+SAME generated data. The specification's population, its plain
+reference and the benchmark cell `tpcds_sf1.q95` live under
+`benchmarks/` (`datagen/tpcds.py`, `reference/q95.py`;
+`tests/test_tpcds_deployment.py`).
 """
 
 from __future__ import annotations
@@ -130,34 +137,19 @@ def load_tpcds(catalog: Catalog, sf: float = 0.01, seed: int = 7) -> Dict[str, i
     return counts
 
 
-#: Q95 in this engine's dialect (quoted aliases and `+ N days` replaced
-#: with standard forms; otherwise the official query shape: self-join
-#: CTE + two IN subqueries + COUNT(DISTINCT) + date window)
-Q95_SQL = """
-with ws_wh as (
-  select ws1.ws_order_number wh1, ws2.ws_warehouse_sk wh2
-  from web_sales ws1, web_sales ws2
-  where ws1.ws_order_number = ws2.ws_order_number
-    and ws1.ws_warehouse_sk <> ws2.ws_warehouse_sk
-)
-select count(distinct ws_order_number) as order_count,
-       sum(ws_ext_ship_cost) as total_shipping_cost,
-       sum(ws_net_profit) as total_net_profit
-from web_sales ws1, date_dim, customer_address, web_site
-where d_date between '1999-02-01' and date '1999-02-01' + interval 60 day
-  and ws1.ws_ship_date_sk = d_date_sk
-  and ws1.ws_ship_addr_sk = ca_address_sk
-  and ca_state = 'IL'
-  and ws1.ws_web_site_sk = web_site_sk
-  and web_company_name = 'pri'
-  and ws1.ws_order_number in (select wh1 from ws_wh)
-  and ws1.ws_order_number in (
-    select wr_order_number from web_returns, ws_wh
-    where wr_order_number = wh1
-  )
-order by order_count
-limit 100
-"""
+def _specification_q95() -> str:
+    """The one Q95 text of the repo: `benchmarks/queries/q95.sql`, the
+    specification's query95.tpl at its qualification values (MySQL's
+    dialect only where it differs: backtick aliases, `+ interval 60
+    day`). It runs over this toy schema as over the specification's."""
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmarks", "queries", "q95.sql")) as f:
+        return f.read()
+
+
+Q95_SQL = _specification_q95()
 
 
 def numpy_q95(catalog: Catalog):

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import re
 from typing import Optional
 
 import numpy as np
@@ -140,8 +141,15 @@ def common_type(a: SQLType, b: SQLType) -> SQLType:
     raise TypeError(f"no common type for {a} and {b}")
 
 
+_RELAXED_DATE = re.compile(r"^\s*(\d{4})-(\d{1,2})-(\d{1,2})\s*$")
+
+
 def date_to_days(s: str) -> int:
-    """'YYYY-MM-DD' -> int32 days since epoch."""
+    """'YYYY-MM-DD' -> int32 days since epoch. MySQL's relaxed form, a
+    month or day of one digit ('1999-2-01'), reads as the padded one."""
+    m = _RELAXED_DATE.match(s) if isinstance(s, str) else None
+    if m is not None:
+        s = f"{m.group(1)}-{int(m.group(2)):02d}-{int(m.group(3)):02d}"
     return (np.datetime64(s, "D") - np.datetime64("1970-01-01", "D")).astype(int)
 
 

@@ -27,6 +27,8 @@ layouts are chosen from column value ranges.
 
 from __future__ import annotations
 
+import contextlib
+import threading
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -36,6 +38,45 @@ from tidb_tpu.chunk import Batch, DevCol
 from tidb_tpu.executor.aggregate import WIDTH_STALE
 
 ExprFn = Callable[[Batch], DevCol]
+
+_TRACING = threading.local()
+
+
+@contextlib.contextmanager
+def expansion_ledger():
+    """What the expanding joins traced inside emit: one (rows, slots)
+    pair a join, `rows` the int64 scalar of its true output rows and
+    `slots` its output tile. The program opens it around the plan and
+    returns the sums beside its cardinality scalars
+    (planner/physical.py), so a statement's flight can say how many rows
+    its many-to-many joins made and how full their tiles were. One
+    opened inside another (the planner's, around a single join) hands
+    what it holds to the outer one when it closes."""
+    prev = getattr(_TRACING, "expansions", None)
+    _TRACING.expansions = out = []
+    try:
+        yield out
+    finally:
+        _TRACING.expansions = prev
+        if prev is not None:
+            prev.extend(out)
+
+
+def _note_expansion(total: jax.Array, slots: int) -> None:
+    """Count one traced expanding join (once per join per traced
+    program, as the compactions are counted) and put it on the open
+    ledger."""
+    from tidb_tpu.utils.metrics import REGISTRY
+
+    REGISTRY.counter(
+        "tidbtpu_executor_join_expansions_total",
+        "inner/left joins traced on the expanding path: a build key "
+        "that is not unique, so every probe row emits one row a match "
+        "into an output tile sized by discovery",
+    ).inc()
+    ledger = getattr(_TRACING, "expansions", None)
+    if ledger is not None:
+        ledger.append((total, int(slots)))
 
 
 def _use_merge_probe(m: int) -> bool:
@@ -56,36 +97,83 @@ def _probe_lo_hi(skey, pkey, need_hi: bool):
         lo = jnp.searchsorted(skey, pkey, side="left")
         hi = jnp.searchsorted(skey, pkey, side="right") if need_hi else None
         return lo, hi
-    from tidb_tpu.executor.sortops import merge_searchsorted, run_ends
+    from tidb_tpu.executor.sortops import (
+        gather_rows, merge_searchsorted, run_ends,
+    )
 
     n = skey.shape[0]
-    lo = merge_searchsorted(skey, pkey, side="left")
+    # int64 whatever the keys' width (narrowed keys are uint32)
+    lo = merge_searchsorted(skey, pkey, side="left").astype(jnp.int64)
     if not need_hi:
         return lo, None
     # hi differs from lo only where the probe key occurs in skey; the
-    # run of equal values starting at lo then ends at run_ends[lo]
+    # run of equal values starting at lo then ends at run_ends[lo]: the
+    # key and the run's end at lo by one stacked gather
     lo_c = jnp.clip(lo, 0, n - 1)
     hit = (lo < n) & (skey[lo_c] == pkey)
     hi = jnp.where(hit, run_ends(skey)[lo_c], lo)
     return lo, hi
 
 
-def _sort_build(bkey, bvalid, bcap: int):
+def _sort_build(bkey, bvalid, bcap: int, kbits: Optional[int] = None):
     """Build side sorted by key, NULL/invalid rows last: (skey carrying
-    the int64-max sentinel on invalid rows, svalid, perm). One packed
-    unstable sort of (key, invalid flag, row id) — three key limbs
-    (sortops module docstring: compile time follows key limbs)."""
+    the sentinel, the largest value of its width, on invalid rows,
+    svalid, perm). One packed unstable sort of (key, invalid flag, row
+    id): three key limbs for a full int64 key, two for a key narrowed to
+    `kbits` bits (_narrow_keys; sortops module docstring: compile time
+    follows key limbs)."""
     from tidb_tpu.executor.sortops import (
         int_from_sort_bits, int_sort_bits, sort_rows, unpack_lex,
     )
 
-    skey = jnp.where(bvalid, bkey, jnp.iinfo(jnp.int64).max)
-    ops, where, perm = sort_rows([int_sort_bits(skey), (~bvalid, 1)], bcap)
+    skey = jnp.where(bvalid, bkey, _sentinel(bkey.dtype, kbits))
+    comp = int_sort_bits(skey) if kbits is None else (skey, kbits)
+    ops, where, perm = sort_rows([comp, (~bvalid, 1)], bcap)
     return (
-        int_from_sort_bits(unpack_lex(ops, where, 0), jnp.int64),
+        int_from_sort_bits(unpack_lex(ops, where, 0), bkey.dtype),
         unpack_lex(ops, where, 1) == 0,
         perm,
     )
+
+
+def _sentinel(dtype, kbits: Optional[int]):
+    """The key an invalid build row carries: it sorts last."""
+    if kbits is None:
+        return jnp.iinfo(dtype).max
+    return jnp.asarray((1 << kbits) - 1, dtype)
+
+
+def _narrow_keys(bkey, bvalid, pkey, pvalid, build_bounds):
+    """Both sides' keys as uint32 offsets, where the build key's static
+    bounds (Table.col_bounds via the planner) lie between two multiples
+    of 2**31: (bkey, bvalid, pkey, pvalid, stale, kbits); without such
+    bounds the keys as they came, stale False and kbits None. A sort's
+    compile time on the
+    v5e grows with the square of its 32-bit key limbs and its run time
+    with their number: an order number below 60,000 sorts in 16 bits,
+    not 64. A valid build key outside the bounds means the data outgrew
+    them: `stale`, the WIDTH_STALE contract of the dense paths. A probe
+    key outside them matches nothing, as it must: it takes the value
+    one below the invalid build rows' sentinel, which no build key has."""
+    asis = bkey, bvalid, pkey, pvalid, False, None
+    if build_bounds is None:
+        return asis
+    # the bounds widened to what does not change with a data set's own
+    # smallest and largest key: from the multiple of 2**31 below the
+    # lower one, as many bits as hold the upper one
+    lo = (int(build_bounds[0]) >> 31) << 31
+    if not lo <= int(build_bounds[1]) < lo + (1 << 31) - 2:
+        return asis
+    from tidb_tpu.executor.sortops import bits_for
+
+    kbits = bits_for(int(build_bounds[1]) - lo + 3)
+    hi = lo + (1 << kbits) - 3
+    bin_ = bvalid & (bkey >= lo) & (bkey <= hi)
+    stale = jnp.any(bvalid & ~bin_)
+    pin = pvalid & (pkey >= lo) & (pkey <= hi)
+    b32 = jnp.where(bin_, bkey - lo, (1 << kbits) - 1).astype(jnp.uint32)
+    p32 = jnp.where(pin, pkey - lo, (1 << kbits) - 2).astype(jnp.uint32)
+    return b32, bin_, p32, pin, stale, kbits
 
 
 def _keys_of(batch: Batch, key_fn: ExprFn) -> Tuple[jax.Array, jax.Array]:
@@ -147,25 +235,30 @@ def _dense_unique_lookup(bkey, bvalid, lo: int, hi: int, span: int,
     return jnp.clip(brow_, 0, bcap - 1), matched, stale
 
 
-def _sorted_unique_lookup(bkey, bvalid, bcap: int, pkey, pvalid):
+def _sorted_unique_lookup(bkey, bvalid, bcap: int, pkey, pvalid,
+                          build_bounds=None):
     """Sorted 1:1 lookup into a planner-proven-unique build key:
     (brow, matched, stale) probe-aligned. ONE searchsorted + one gather
     of the sorted build's (key, validity, row) at lo (uniqueness makes
-    `hi` redundant: a hit is an equality at lo).
+    `hi` redundant: a hit is an equality at lo). Keys narrowed by the
+    build's static bounds where it has them (_narrow_keys).
     stale must be the build-side adjacent-duplicate check — a
     probe-derived hi-lo>1 would also fire on garbage probe lanes equal
-    to the invalid-row int64-max sentinel run, and a spurious stale is
-    a recompile livelock."""
+    to the invalid-row sentinel run, and a spurious stale is a
+    recompile livelock."""
     from tidb_tpu.executor.sortops import gather_rows
 
-    skey, svalid, sperm = _sort_build(bkey, bvalid, bcap)
+    bkey, bvalid, pkey, pvalid, outgrown, kbits = _narrow_keys(
+        bkey, bvalid, pkey, pvalid, build_bounds
+    )
+    skey, svalid, sperm = _sort_build(bkey, bvalid, bcap, kbits)
     lo, _hi = _probe_lo_hi(skey, pkey, need_hi=False)
     with jax.named_scope("lookup"):
         (key_at, brow), (valid_at,) = gather_rows(
             [skey, sperm], [svalid], jnp.clip(lo, 0, bcap - 1)
         )
     matched = pvalid & (lo < bcap) & valid_at & (key_at == pkey)
-    stale = jnp.any(svalid[1:] & (skey[1:] == skey[:-1]))
+    stale = jnp.any(svalid[1:] & (skey[1:] == skey[:-1])) | outgrown
     return brow, matched, stale
 
 
@@ -195,7 +288,9 @@ def lookup_build_rows(
             bkey, bvalid, lo, hi, span, bcap, pkey, pvalid
         )
         return brow, matched, stale
-    return _sorted_unique_lookup(bkey, bvalid, bcap, pkey, pvalid)
+    return _sorted_unique_lookup(
+        bkey, bvalid, bcap, pkey, pvalid, build_bounds
+    )
 
 
 def gather_cols(batch: Batch, index: jax.Array, names=None) -> Dict[str, DevCol]:
@@ -348,7 +443,7 @@ def equi_join(
             # probe-aligned with NO expansion pass (vs the generic
             # expand path below that pays cumsum + output re-gather)
             brow, matched, stale = _sorted_unique_lookup(
-                bkey, bvalid, bcap, pkey, pvalid
+                bkey, bvalid, bcap, pkey, pvalid, build_bounds
             )
         # 1:1 with the probe side: the output IS the probe batch (same
         # capacity, row_valid refined) plus gathered build columns — no
@@ -395,13 +490,29 @@ def equi_join(
             )
         return out, total
 
+    # no dense table from here on: the build is sorted, on keys narrowed
+    # by its static bounds where it has them. What the narrowing drops
+    # (a key outside the bounds) matches nothing, so the rows' own
+    # validity (pvalid, bvalid) still decides every NULL below.
+    skeys_b, sbvalid, skeys_p, spvalid, outgrown, kbits = _narrow_keys(
+        bkey, bvalid, pkey, pvalid, build_bounds
+    )
+
+    def _or_stale(total):
+        if outgrown is False:
+            return total
+        return jnp.where(outgrown, jnp.int64(WIDTH_STALE), total)
+
     if join_type in ("semi", "anti", "mark"):
         skey = jax.lax.sort(
-            [jnp.where(bvalid, bkey, jnp.iinfo(jnp.int64).max)],
+            [jnp.where(sbvalid, skeys_b, _sentinel(skeys_b.dtype, kbits))],
             is_stable=False,
         )[0]
-        lo, hi = _probe_lo_hi(skey, pkey, need_hi=True)
-        matched = (hi > lo) & pvalid
+        # existence needs no run length: the key at lo is the probe's
+        lo, _hi = _probe_lo_hi(skey, skeys_p, need_hi=False)
+        matched = spvalid & (lo < bcap) & (
+            skey[jnp.clip(lo, 0, bcap - 1)] == skeys_p
+        )
         if join_type == "mark":
             # mark join: every probe row survives and gains a boolean
             # column holding the (three-valued) IN/EXISTS result — the
@@ -423,7 +534,7 @@ def equi_join(
             cols = dict(probe.cols)
             cols[mark_name] = DevCol(matched, mvalid)
             out = Batch(cols, probe.row_valid)
-            return out, jnp.sum(out.row_valid.astype(jnp.int64))
+            return out, _or_stale(jnp.sum(out.row_valid.astype(jnp.int64)))
         keep = matched if join_type == "semi" else (~matched & probe.row_valid & pvalid)
         if join_type == "anti":
             # NULL probe key in NOT IN/anti: row never matches but with a
@@ -432,39 +543,53 @@ def equi_join(
             # NOT EXISTS keeps it; planner selects via null_aware flag.
             keep = keep | (~pvalid & probe.row_valid)
         out = Batch(probe.cols, probe.row_valid & keep)
-        return out, jnp.sum(out.row_valid.astype(jnp.int64))
+        return out, _or_stale(jnp.sum(out.row_valid.astype(jnp.int64)))
 
     # ---- inner / left: sort build side, carry permutation ----
-    skey, _svalid, sperm = _sort_build(bkey, bvalid, bcap)
+    skey, _svalid, sperm = _sort_build(skeys_b, sbvalid, bcap, kbits)
 
-    lo, hi = _probe_lo_hi(skey, pkey, need_hi=True)
-    counts = jnp.where(pvalid & probe.row_valid, hi - lo, 0)
-    if join_type == "left":
-        emit = jnp.where(probe.row_valid, jnp.maximum(counts, 1), 0)
-    else:
-        emit = counts
+    with jax.named_scope("expand"):
+        with jax.named_scope("search"):
+            lo, hi = _probe_lo_hi(skey, skeys_p, need_hi=True)
+            counts = jnp.where(spvalid & probe.row_valid, hi - lo, 0)
+            if join_type == "left":
+                emit = jnp.where(probe.row_valid, jnp.maximum(counts, 1), 0)
+            else:
+                emit = counts
 
-    cum = jnp.cumsum(emit)
-    total = cum[-1] if cum.shape[0] else jnp.zeros((), jnp.int64)
-    # out slot j -> probe row
-    slots = jnp.arange(out_capacity, dtype=jnp.int64)
-    if _use_merge_probe(out_capacity):
-        from tidb_tpu.executor.sortops import merge_searchsorted
+            # the true total in 64 bits; the running count in 32: where
+            # the total fits the tile (below 2**31 slots) no prefix
+            # passes it, and where it does not the tile is thrown away
+            # and the statement retried at the total. An int64 cumsum
+            # over 786,432 rows takes the v5e compiler 48 s, an int32
+            # one 2 s (described v5e:2x2, PR 33), and the slot search
+            # sorts one limb a key for it.
+            total = jnp.sum(emit.astype(jnp.int64))
+            cum = jnp.cumsum(emit.astype(jnp.int32))
+            # out slot j -> probe row
+            slots = jnp.arange(out_capacity, dtype=jnp.int32)
+            if _use_merge_probe(out_capacity):
+                from tidb_tpu.executor.sortops import merge_searchsorted
 
-        prow = merge_searchsorted(cum, slots, side="right")
-    else:
-        prow = jnp.searchsorted(cum, slots, side="right")
-    prow_c = jnp.clip(prow, 0, probe.capacity - 1)
-    base = cum[prow_c] - emit[prow_c]
-    offset = slots - base
-    out_valid = slots < total
+                prow = merge_searchsorted(cum, slots, side="right")
+            else:
+                prow = jnp.searchsorted(cum, slots, side="right")
+        _note_expansion(total, out_capacity)
+        with jax.named_scope("gather"):
+            # slot j of probe row p holds sorted build row lo[p] + (j -
+            # the slots before p's): one int32 a probe row says where a
+            # row's matches start, and every slot reads it by ONE gather
+            # (at 16,777,216 slots a plain gather is 144 ms on the v5e,
+            # an int64 one 348: cum, emit, lo and counts each took one)
+            prow_c = jnp.clip(prow, 0, probe.capacity - 1)
+            first = (lo - (cum - emit)).astype(jnp.int32)
+            out_valid = slots < total
+            brow = sperm[jnp.clip(slots + first[prow_c], 0, bcap - 1)]
+            # a left join's unmatched row emits one slot of NULLs
+            bmatched = None if join_type == "inner" else (counts > 0)[prow_c]
 
-    brow_sorted = jnp.clip(lo[prow_c] + offset, 0, bcap - 1)
-    brow = sperm[brow_sorted]
-    bmatched = offset < counts[prow_c]  # false only for left-join null row
-
-    out = _emit(
-        probe, build, prow_c, brow, out_valid, bmatched,
-        probe_prefix, build_prefix, keep,
-    )
-    return out, total
+            out = _emit(
+                probe, build, prow_c, brow, out_valid, bmatched,
+                probe_prefix, build_prefix, keep,
+            )
+    return out, _or_stale(total)

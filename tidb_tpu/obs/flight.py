@@ -171,6 +171,7 @@ class QueryFlight:
         "compile_bytes_accessed", "compile_output_bytes", "live_phase",
         "est_rows", "act_rows", "spans", "served_s", "background",
         "exchanges", "exchange_rows", "exchange_bytes",
+        "join_expansions", "join_expand_rows", "join_expand_slots",
     )
 
     def __init__(self, qid: int, conn_id: int, sql: str):
@@ -202,6 +203,13 @@ class QueryFlight:
         self.exchanges = 0
         self.exchange_rows = 0
         self.exchange_bytes = 0
+        #: what the expanding joins (a build key that is not unique:
+        #: executor/join.py) of the programs this statement ran did:
+        #: joins executed, the rows they had to emit, and the slots of
+        #: their output tiles (rows over slots is the tiles' fill)
+        self.join_expansions = 0
+        self.join_expand_rows = 0
+        self.join_expand_slots = 0
         self.device_mem_peak_bytes = 0
         # XLA cost analysis summed over this statement's compiles
         # (obs/engine_watch.py per-signature harvest)
@@ -619,6 +627,15 @@ class FlightRecorder:
             rec.exchange_rows += int(rows)
             rec.exchange_bytes += int(nbytes)
 
+    def note_expansions(self, count: int, rows: int, slots: int) -> None:
+        """One executed program's expanding joins (planner/physical.py
+        reads them beside the program's cardinality scalars)."""
+        rec = self.current()
+        if rec is not None:
+            rec.join_expansions += int(count)
+            rec.join_expand_rows += int(rows)
+            rec.join_expand_slots += int(slots)
+
     def note_cardinality(self, est: float, act: float) -> None:
         """Planner-estimated vs observed output rows of a routed
         statement (AQE): feeds the statements_summary est/act
@@ -699,6 +716,9 @@ class FlightRecorder:
                 "exchanges": r.exchanges,
                 "exchange_rows": r.exchange_rows,
                 "exchange_bytes": r.exchange_bytes,
+                "join_expansions": r.join_expansions,
+                "join_expand_rows": r.join_expand_rows,
+                "join_expand_slots": r.join_expand_slots,
                 "device_mem_peak_bytes": r.device_mem_peak_bytes,
                 "compile_flops": r.compile_flops,
                 "compile_bytes_accessed": r.compile_bytes_accessed,

@@ -43,6 +43,7 @@ from tidb_tpu.executor import (
     order_by,
 )
 from tidb_tpu.executor.aggregate import WIDTH_STALE as _WIDTH_STALE
+from tidb_tpu.executor.join import expansion_ledger
 from tidb_tpu.expression import compile_expr
 from tidb_tpu.expression.expr import ColumnRef, Expr
 from tidb_tpu.obs.engine_watch import ENGINE_WATCH, watched_jit
@@ -205,9 +206,24 @@ class CompiledQuery:
     # sized nodes that are an exchange's bucket tile (mesh mode): a
     # bump of one is counted as an exchange overflow retry
     exchange_nids: frozenset = frozenset()
-    # (mesh mode) the output's first compaction tile, from the root's
-    # estimated rows; 0 on one device
+    # sized nodes that are an expanding join's output tile (a build key
+    # that is not unique): a bump of one is counted as an expansion
+    # overflow retry
+    expand_nids: set = dataclasses.field(default_factory=set)
+    # sized node -> the smallest tile discovery shrinks it to. A tile
+    # that follows a count of a few dozen rows makes one program for a
+    # data set with 40 of them and another for one with 70; where a
+    # small tile buys nothing the node keeps this many slots
+    floors: Dict[int, int] = dataclasses.field(default_factory=dict)
+    # the output's first compaction tile, from the root's estimated
+    # rows: on a mesh always; on one device where first_caps is filled
     first_out_cap: int = 0
+    # (one device) every knob's first tile, where each has one without
+    # a run: an expanding join's from the planner's estimate of its
+    # rows, the others' their defaults. Empty where some knob has none
+    # (a unique-build join's tile is its probe's, known at the first
+    # run): discovery finds the tiles then, as it always has
+    first_caps: Dict[int, int] = dataclasses.field(default_factory=dict)
     # plan signature for the engine watch: a second jit trace for the
     # same sig is a retrace (obs/engine_watch.py)
     sig: Optional[object] = None
@@ -793,7 +809,10 @@ def build_agg_parts(plan: "L.Aggregate", dicts, compiler=None):
                         (lb.nid, lb.col, int(cb[0]), int(cb[1]))
                     )
                 wide = False
-                pack_bound = int(r[0])
+                # the bias the program bakes: the next 2**k - 1 at or
+                # above the bound, which packs in as many bits and does
+                # not change with a data set's largest value
+                pack_bound = (1 << int(r[0]).bit_length()) - 1
         # DISTINCT is a no-op for min/max (duplicate-insensitive); for
         # sum/avg/count the kernel dedupes via representative-row masks
         # (executor/aggregate._distinct_reps)
@@ -969,6 +988,16 @@ class PlanCompiler:
         self._tag = "shard"
         # sized nodes that are an exchange's bucket tile (mesh mode)
         self.exchange_nids: set = set()
+        # sized nodes that are an expanding join's output tile; filled
+        # while the program is traced (the executor picks the path)
+        self.expand_nids: set = set()
+        # sized node -> its smallest tile (CompiledQuery.floors)
+        self.floors: Dict[int, int] = {}
+        # (one device) sized node -> a first tile known without a run
+        # (CompiledQuery.first_caps): an expanding join's from the
+        # estimate, a scalar aggregate's one group
+        self.first_tiles: Dict[int, int] = {}
+        self.expanding_joins = 0  # joins neither side of which is unique
 
     def fresh_id(self) -> int:
         self._next_id += 1
@@ -999,6 +1028,9 @@ class PlanCompiler:
         from the dominant input tile, as it always has."""
         if not self.mesh_n:
             return 0
+        return self._estimated_tile(plan, parts)
+
+    def _estimated_tile(self, plan: L.LogicalPlan, parts: int = 1) -> int:
         from tidb_tpu.planner import cardinality as C
 
         return _cap_tile(int(1.25 * C.est_rows(plan, self.catalog)) // parts + 1)
@@ -1110,6 +1142,12 @@ class PlanCompiler:
         # consumers (materialization, the RPC seam) expect name ->
         # dictionary only (all reserved prefixes start with NUL)
         out = {k: v for k, v in dicts.items() if not k.startswith("\x00")}
+        first_caps = {
+            nid: self.first_tiles.get(nid) or self.defaults[nid]
+            for nid in self.sized
+        }
+        if self.mesh_n or not self.expanding_joins or not all(first_caps.values()):
+            first_caps = {}
         return CompiledQuery(
             fn=fn,
             out_tag=self._tag,
@@ -1122,7 +1160,10 @@ class PlanCompiler:
             nonnull=list(self.nonnull),
             bound_checks=list(self.bound_checks),
             exchange_nids=frozenset(self.exchange_nids),
-            first_out_cap=self._first_tile(plan),
+            expand_nids=self.expand_nids,
+            floors=dict(self.floors),
+            first_out_cap=self._estimated_tile(plan) if first_caps else self._first_tile(plan),
+            first_caps=first_caps,
         )
 
     # ------------------------------------------------------------------
@@ -1220,7 +1261,16 @@ class PlanCompiler:
             # validity loads/ANDs over columns that never hold NULLs).
             # The assumption is re-checked host-side at every fetch
             # (_run_pinned) and a violation recompiles via the stale path.
-            nonnull = [] if self.conservative else [
+            # A small table that holds a NULL anywhere is a table of
+            # nullable columns: that another of them holds none today
+            # (a 30-row dimension, 4 % NULL a column) is the data's
+            # accident, and a program that folded it would be another
+            # program for the next data set. Folding saves nothing
+            # there; such a table keeps every validity mask.
+            nullable_few = t.nrows < _FEW_ROWS and any(
+                t.col_has_nulls(n, _v) for n in t.schema.names
+            )
+            nonnull = [] if self.conservative or nullable_few else [
                 n for n in plan.columns if not t.col_has_nulls(n, _v)
             ]
             self.nonnull.extend((nid, n) for n in nonnull)
@@ -1505,6 +1555,13 @@ class PlanCompiler:
             plan, dicts, compiler=self
         )
         scalar = not plan.group_exprs
+        if scalar:
+            self.first_tiles[nid] = 16  # one group, whatever the data
+        elif not _dense_keys(key_widths):
+            # sorted groups: the table is the output tile and nothing
+            # else, so one of a few dozen slots is no cheaper than one
+            # of _FEW_ROWS, and does not follow the group count
+            self.floors[nid] = _FEW_ROWS
         agg_names = [(n, f) for n, f, _a, _d in plan.aggs]
         mesh_n = self.mesh_n if child_tag == "shard" else None
         post_fn = (
@@ -2105,6 +2162,13 @@ class PlanCompiler:
         self.defaults[nid] = self._first_tile(
             plan, self.mesh_n if mesh and self._tag == "shard" else 1
         )
+        if not mesh and kind == "inner" and not (lprops[1] or rprops[1]):
+            # neither side unique: the join expands, and its output has
+            # no tile of an input's to start from. The estimate's
+            # (n x m / NDV from ANALYZE's statistics) lets the first
+            # program be the steady one (PhysicalExecutor._steady_first)
+            self.first_tiles[nid] = self._estimated_tile(plan)
+            self.expanding_joins += 1
 
         def fn_join(inputs, caps):
             lb, n1 = left(inputs, caps)
@@ -2130,11 +2194,16 @@ class PlanCompiler:
                 build_props = lprops
                 keep = keep and keep[::-1]
             cap = caps[nid] or pad_capacity(max(probe_b.capacity, 1024))
-            out, total = equi_join(
-                build_b, probe_b, build_k, probe_k, cap, kind,
-                build_bounds=build_props[0], build_unique=build_props[1],
-                keep=keep,
-            )
+            with expansion_ledger() as expanded:
+                out, total = equi_join(
+                    build_b, probe_b, build_k, probe_k, cap, kind,
+                    build_bounds=build_props[0], build_unique=build_props[1],
+                    keep=keep,
+                )
+            if expanded:
+                # the executor took the expanding path: this tile is
+                # what an overflow of it retries at
+                self.expand_nids.add(nid)
             if verify is not None:
                 lk, rk = verify
 
@@ -2169,6 +2238,22 @@ class PlanCompiler:
 # ---------------------------------------------------------------------------
 
 _MAX_JOIN_CAP = 1 << 26
+
+
+# Rows below which a tile or a table is "a few": one 8 x 128 vector
+# register of 32-bit lanes. Nothing on the chip is cheaper for being
+# smaller than that, so no program is shaped by a count below it.
+_FEW_ROWS = 1024
+
+
+def _dense_keys(key_widths) -> bool:
+    """Whether group keys of these packed widths take the executor's
+    dense (masked) aggregation (executor/aggregate.group_aggregate)."""
+    from tidb_tpu.executor.aggregate import _DENSE_BITS
+
+    return all(w is not None for w in key_widths) and (
+        sum(w for w, _b in key_widths) <= _DENSE_BITS
+    )
 
 
 def _cap_tile(n: int) -> int:
@@ -2456,8 +2541,9 @@ class PhysicalExecutor:
             from tidb_tpu.expression.kernels import param_scope
 
             def prog(i, p, _f=fn, _c=frozen_caps):
-                with param_scope(p):
-                    return _f(i, _c)
+                with param_scope(p), expansion_ledger() as expanded:
+                    b, needs = _f(i, _c)
+                return b, _with_expansions(needs, expanded)
 
             return prog
         from jax.sharding import PartitionSpec as P
@@ -2469,11 +2555,15 @@ class PhysicalExecutor:
         n = self.mesh_n
 
         def local(i, _f=fn, _c=frozen_caps):
-            with sent_ledger() as sent:
+            with sent_ledger() as sent, expansion_ledger() as expanded:
                 b, needs = _f(i, _c)
             # pmax proves replication of the cardinality scalars to
             # shard_map AND takes the per-shard max for sizing knobs
             needs = {k: pmax(v, "d") for k, v in needs.items()}
+            # a shard's expanding joins, summed over the shards
+            needs = _with_expansions(
+                needs, [(jax.lax.psum(r, "d"), s * n) for r, s in expanded]
+            )
             # what the program's exchanges sent, beside them: how many
             # there are, their rows and their cross-chip bytes
             needs[_EXCHANGES] = jnp.int64(len(sent))
@@ -2618,7 +2708,7 @@ class PhysicalExecutor:
             with FLIGHT.span("device-wait"):
                 jax.block_until_ready((needs, out))
             with FLIGHT.span("fetch"):
-                needs_host = _take_exchange_stats(jax.device_get(needs))
+                needs_host = _take_program_stats(jax.device_get(needs))
             bumped = False
             for nid, true_n in needs_host.items():
                 n = int(true_n)
@@ -2637,6 +2727,8 @@ class PhysicalExecutor:
                             "whole-program recompiles because an "
                             "exchange's bucket tile overflowed",
                         ).inc()
+                    if nid in cq.expand_nids:
+                        _expand_overflow_retries().inc()
                     caps[nid] = _cap_tile(n)
                     if caps[nid] > _MAX_JOIN_CAP:
                         raise ExecError(f"result too large at node {nid}: {n} rows")
@@ -2649,7 +2741,10 @@ class PhysicalExecutor:
                 if not cq.no_shrink:
                     for nid, true_n in needs_host.items():
                         if nid in caps:
-                            caps[nid] = min(caps[nid], _cap_tile(int(true_n)))
+                            tile = max(
+                                _cap_tile(int(true_n)), cq.floors.get(nid, 0)
+                            )
+                            caps[nid] = min(caps[nid], tile)
                 return out, caps
 
     def run(self, plan: L.LogicalPlan) -> Tuple[Batch, Dicts]:
@@ -2791,8 +2886,10 @@ class PhysicalExecutor:
         return jitted, out, needs_host
 
     def _steady_first(self, cq: CompiledQuery, inputs, shape_key):
-        """A mesh plan's first execution: where every knob has a first
-        tile from the planner's estimates (PlanCompiler._first_tile),
+        """A plan's first execution where every knob has a first tile
+        without a run (a mesh plan's from the planner's estimates,
+        PlanCompiler._first_tile; on one device a plan whose joins all
+        expand, CompiledQuery.first_caps):
         compile the STEADY program at them and run it, in the discover
         program's place. It returns the knobs' true cardinalities like
         any steady run. Nothing overflowed and no tile is more than
@@ -2807,14 +2904,20 @@ class PhysicalExecutor:
         Returns (output, None, 0) when the first program was kept,
         (None, tight caps, tight output tile) when it ran clean and is
         to be tightened, (None, None, 0) when discovery has to run."""
-        first, first_out = cq.default_caps, cq.first_out_cap
-        if self.mesh is None or cq.caps or not first_out or not all(first.values()):
+        if self.mesh is not None:
+            first = cq.default_caps
+        elif cq.first_caps:
+            first = cq.first_caps
+        else:
+            return None, None, 0
+        first_out = cq.first_out_cap
+        if cq.caps or not first_out or not all(first.values()):
             return None, None, 0
         from tidb_tpu.utils.metrics import REGISTRY
 
         outcomes = REGISTRY.counter(
             "tidbtpu_executor_steady_first_total",
-            "mesh plans first compiled as their steady program at "
+            "plans first compiled as their steady program at "
             "estimated tiles: kept, tightened (recompiled at the tight "
             "tiles, no discovery), overflowed (discovery ran)",
             labels=("outcome",),
@@ -2829,6 +2932,9 @@ class PhysicalExecutor:
                 # baked key bounds no longer cover the data: no tile fixes that
                 raise StaleWidthsError()
             outcomes.labels(outcome="overflowed").inc()
+            for nid in cq.expand_nids:
+                if int(needs_host.get(nid, 0)) > full.get(nid, 0):
+                    _expand_overflow_retries().inc()
             # discovery starts from what this run saw (a lower bound
             # downstream of the overflow), not from the estimates again
             cq.caps = {
@@ -2837,7 +2943,8 @@ class PhysicalExecutor:
             }
             return None, None, 0
         tight = {
-            nid: _cap_tile(int(needs_host[nid])) if nid in needs_host else cap
+            nid: max(_cap_tile(int(needs_host[nid])), cq.floors.get(nid, 0))
+            if nid in needs_host else cap
             for nid, cap in full.items()
         }
         if all(cap <= 2 * tight[nid] for nid, cap in full.items()):
@@ -3156,33 +3263,72 @@ def _merge_shuffle_stats(lines: List[str], stage, infos) -> List[str]:
 _OUT_NODE = -1
 # pseudo node ids of what a mesh program's exchanges sent (never knobs)
 _EXCHANGES, _EXCHANGE_ROWS, _EXCHANGE_BYTES = -2, -3, -4
+# and of what a program's expanding joins emitted: how many there are,
+# their true output rows, their output tiles' slots
+_EXPANSIONS, _EXPAND_ROWS, _EXPAND_SLOTS = -5, -6, -7
 
 
-def _take_exchange_stats(needs_host: dict) -> dict:
-    """Take what the exchanges sent out of a mesh program's fetched
-    scalars, onto the statement's flight and the registry; what is left
-    are the cardinalities of the knobs."""
-    if _EXCHANGES not in needs_host:
-        return needs_host
-    needs_host = dict(needs_host)
-    count, rows, nbytes = (
-        int(needs_host.pop(k))
-        for k in (_EXCHANGES, _EXCHANGE_ROWS, _EXCHANGE_BYTES)
-    )
+def _expand_overflow_retries():
     from tidb_tpu.utils.metrics import REGISTRY
 
-    REGISTRY.counter(
-        "tidbtpu_executor_exchange_rows_total",
-        "valid rows the executed mesh programs' exchanges sent, "
-        "summed over shards",
-    ).inc(rows)
-    REGISTRY.counter(
-        "tidbtpu_executor_exchange_bytes_total",
-        "bytes those rows must carry between chips: rows x the "
-        "travelling columns' logical width x the share that leaves "
-        "its chip",
-    ).inc(nbytes)
-    FLIGHT.note_exchanges(count, rows, nbytes)
+    return REGISTRY.counter(
+        "tidbtpu_executor_join_expand_overflow_retries_total",
+        "whole-program recompiles because an expanding join's output "
+        "tile overflowed",
+    )
+
+
+def _with_expansions(needs: dict, expanded: list) -> dict:
+    """The program's cardinality scalars with what its expanding joins
+    (executor/join.expansion_ledger: one (rows, slots) a join) emitted
+    beside them: the same fetch brings both."""
+    if not expanded:
+        return needs
+    needs = dict(needs)
+    needs[_EXPANSIONS] = jnp.int64(len(expanded))
+    needs[_EXPAND_ROWS] = sum((r for r, _ in expanded), jnp.int64(0))
+    needs[_EXPAND_SLOTS] = jnp.int64(sum(s for _, s in expanded))
+    return needs
+
+
+def _take_program_stats(needs_host: dict) -> dict:
+    """Take what the exchanges sent and what the expanding joins
+    emitted out of a program's fetched scalars, onto the statement's
+    flight and the registry; what is left are the cardinalities of the
+    knobs."""
+    if _EXCHANGES not in needs_host and _EXPANSIONS not in needs_host:
+        return needs_host
+    needs_host = dict(needs_host)
+    from tidb_tpu.utils.metrics import REGISTRY
+
+    if _EXCHANGES in needs_host:
+        count, rows, nbytes = (
+            int(needs_host.pop(k))
+            for k in (_EXCHANGES, _EXCHANGE_ROWS, _EXCHANGE_BYTES)
+        )
+        REGISTRY.counter(
+            "tidbtpu_executor_exchange_rows_total",
+            "valid rows the executed mesh programs' exchanges sent, "
+            "summed over shards",
+        ).inc(rows)
+        REGISTRY.counter(
+            "tidbtpu_executor_exchange_bytes_total",
+            "bytes those rows must carry between chips: rows x the "
+            "travelling columns' logical width x the share that leaves "
+            "its chip",
+        ).inc(nbytes)
+        FLIGHT.note_exchanges(count, rows, nbytes)
+    if _EXPANSIONS in needs_host:
+        count, rows, slots = (
+            int(needs_host.pop(k))
+            for k in (_EXPANSIONS, _EXPAND_ROWS, _EXPAND_SLOTS)
+        )
+        REGISTRY.counter(
+            "tidbtpu_executor_join_expand_rows_total",
+            "rows the executed programs' expanding joins had to emit "
+            "(their true output, also where a tile overflowed)",
+        ).inc(rows)
+        FLIGHT.note_expansions(count, rows, slots)
     return needs_host
 
 
@@ -3235,7 +3381,7 @@ def _launch_and_fetch(jitted, inputs, params):
     with FLIGHT.span("device-wait"):
         jax.block_until_ready((needs, out))
     with FLIGHT.span("fetch"):
-        needs_host = _take_exchange_stats(jax.device_get((needs, out))[0])
+        needs_host = _take_program_stats(jax.device_get((needs, out))[0])
         ENGINE_WATCH.d2h_batch(out)
     return out, needs_host
 
