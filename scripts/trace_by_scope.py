@@ -15,7 +15,8 @@ the devices that ran an op: the chips of a mesh run one program):
 - the modules launched, by name (``jit_<kind>``, obs/engine_watch.py);
 - self time by innermost ``label#nid`` with each scope's largest ops
   and the source line that emitted them, how much of a scope lies in
-  a named sub-scope an operator opens (``SUB_SCOPES``: a join's
+  a named sub-scope an operator opens (``SUB_SCOPES``: a dense
+  aggregate's ``/contract``, executor/aggregate.py; a join's
   ``/compact``, ``/lookup`` and ``/expand/search | gather``,
   executor/join.py; on a mesh an exchange's
   ``/exchange/sort | pack | all-to-all`` and ``/broadcast/all-gather``,
@@ -45,6 +46,8 @@ import sys
 SCOPE = re.compile(r"([^/]*#\d+)(?=/|$)")
 #: ``jax.named_scope``s operators open inside their own scope
 SUB_SCOPES = (
+    # a dense aggregate's digit contraction (executor/aggregate.py)
+    "contract",
     "compact",  # a join's output compaction (executor/join.py)
     "lookup",  # a sorted unique lookup's reads at lo (executor/join.py)
     # an expanding join: lo/hi and the slot-to-probe search, the emit

@@ -101,7 +101,7 @@ def _fits_v5e(compiled):
 
 
 # ---------------------------------------------------------------------------
-# the flagship fragment, and Q1's aggregation through the masked reducer
+# the flagship fragment, and Q1's aggregation through the dense reducer
 # ---------------------------------------------------------------------------
 
 
@@ -119,40 +119,137 @@ def test_q1_fragment_compiles(one_chip):
     _fits_v5e(compiled)
 
 
-def test_masked_backend_q1_shape_compiles(one_chip, monkeypatch):
+LINEITEM_SF10_TILE = 67_108_864  # pad_capacity(59,986,052), `tpch_sf10.scan`'s
+
+
+@pytest.mark.parametrize("rows", [LINEITEM_SF1, LINEITEM_SF10_TILE], ids=["sf1", "sf10"])
+def test_dense_reducer_q1_shape_compiles(one_chip, monkeypatch, rows):
     """Q1's real plan carries planner widths for its two dictionary
     keys, so the 4-bit dense domain reduces through
-    aggregate._masked_backend (scatter-free)."""
+    aggregate._DenseReducer: the integer sums and the count ride ONE
+    digit contraction (a convolution a block of rows), the
+    min, which digits cannot carry, the masked reductions. At SF10's
+    tile the whole aggregation must leave the chip's memory to the
+    tables: temporaries under 2 GB."""
     import tidb_tpu.executor.aggregate as A
     from tidb_tpu.chunk import pad_capacity
 
-    used = []
-    real = A._masked_backend
+    contracted, masked = [], []
+    real_contract = A._DenseReducer._contract
+    real_masked = A._masked_backend
     monkeypatch.setattr(
-        A, "_masked_backend", lambda *a: used.append(1) or real(*a)
+        A._DenseReducer, "_contract",
+        lambda self, lanes: contracted.append(len(lanes)) or real_contract(self, lanes),
     )
+
+    def spy_masked(seg, slots):
+        red = real_masked(seg, slots)
+        return lambda op, *a: masked.append(op) or red(op, *a)
+
+    monkeypatch.setattr(A, "_masked_backend", spy_masked)
     batch = _batch(
         {"l_returnflag": np.int32, "l_linestatus": np.int32,
          "l_quantity": np.int64, "l_extendedprice": np.int64},
-        pad_capacity(LINEITEM_SF1), one_chip,
+        pad_capacity(rows), one_chip,
     )
+    sf10 = rows == LINEITEM_SF10_TILE
+    # the bounds the planner proves for the two columns (13 and 24 bits)
+    aggs = [
+        A.AggDesc("sum", lambda x: x.cols["l_quantity"], "sum_qty", pack_bound=8191),
+        A.AggDesc("avg", lambda x: x.cols["l_quantity"], "avg_qty", pack_bound=8191),
+        A.AggDesc("sum", lambda x: x.cols["l_extendedprice"], "sum_base",
+                  pack_bound=(1 << 24) - 1),
+        A.AggDesc("count", None, "count_order"),
+    ] + ([] if sf10 else [A.AggDesc("min", lambda x: x.cols["l_quantity"], "min_qty")])
 
     def q1_agg(b):
         return A.group_aggregate(
             b,
             [lambda x: x.cols["l_returnflag"], lambda x: x.cols["l_linestatus"]],
-            [
-                A.AggDesc("sum", lambda x: x.cols["l_quantity"], "sum_qty"),
-                A.AggDesc("sum", lambda x: x.cols["l_extendedprice"], "sum_base"),
-                A.AggDesc("count", None, "count_order"),
-            ],
+            aggs,
             16, key_names=["l_returnflag", "l_linestatus"],
             key_widths=[(2, 0), (2, 0)],  # 3 and 2 dictionary codes
         )
 
     compiled, _s = _compile(q1_agg, batch)
-    assert used, "the masked backend did not engage"
-    _fits_v5e(compiled)
+    # one contraction of four lanes: the two columns, their validity's
+    # count, the row count (sum_qty and avg_qty are one lane, count(*)
+    # and the occupancy another)
+    assert contracted == [5] and masked == ([] if sf10 else ["min"])
+    ma = _fits_v5e(compiled)
+    # one batched dot a piece of 2**23 rows
+    assert compiled.as_text().count(" convolution(") == -(-batch.capacity // (1 << 23))
+    if sf10:
+        assert ma.temp_size_in_bytes < 2 << 30, ma.temp_size_in_bytes
+
+
+def test_dense_reducer_q1_real_lanes_sf10_temporaries(one_chip, monkeypatch):
+    """Q1's own filter and eight aggregates with the planner's bounds
+    (the decimal products, the wide sum_charge) at SF10's tile: 17
+    requests, 10 lanes, 22 digit rows. Each piece's digits wait for the
+    piece before (_DenseReducer._contract's barrier), which is what
+    bounds the temporaries: 3.20 GiB by this compiler, 4.73 GiB without
+    the chain (PERF.md PR 34). Losing it fails here."""
+    import jax.numpy as jnp
+
+    import tidb_tpu.executor.aggregate as A
+    from tidb_tpu.chunk import Batch, DevCol
+
+    contracted = []
+    real_contract = A._DenseReducer._contract
+    monkeypatch.setattr(
+        A._DenseReducer, "_contract",
+        lambda self, lanes: contracted.append(
+            (len(lanes), sum(len(A._byte_digits(r.vals[:1], r.bits)) for r in lanes))
+        ) or real_contract(self, lanes),
+    )
+    batch = _batch(
+        {"l_returnflag": np.int32, "l_linestatus": np.int32, "l_shipdate": np.int32,
+         "l_quantity": np.int64, "l_extendedprice": np.int64,
+         "l_discount": np.int64, "l_tax": np.int64},
+        LINEITEM_SF10_TILE, one_chip,
+    )
+
+    def col(n):
+        return lambda b: b.cols[n]
+
+    def disc_price(b):
+        p, d = b.cols["l_extendedprice"], b.cols["l_discount"]
+        return DevCol(p.data * (100 - d.data), p.valid & d.valid)
+
+    def charge(b):
+        dp, t = disc_price(b), b.cols["l_tax"]
+        return DevCol(dp.data * (100 + t.data), dp.valid & t.valid)
+
+    aggs = [
+        A.AggDesc("sum", col("l_quantity"), "sum_qty", arg_scale=2, pack_bound=8191),
+        A.AggDesc("sum", col("l_extendedprice"), "sum_base_price", arg_scale=2,
+                  pack_bound=(1 << 24) - 1),
+        A.AggDesc("sum", disc_price, "sum_disc_price", arg_scale=4,
+                  pack_bound=(1 << 31) - 1),
+        A.AggDesc("sum", charge, "sum_charge", arg_scale=6, wide=True,
+                  pack_bound=(1 << 39) - 1),
+        A.AggDesc("avg", col("l_quantity"), "avg_qty", arg_scale=2, pack_bound=8191),
+        A.AggDesc("avg", col("l_extendedprice"), "avg_price", arg_scale=2,
+                  pack_bound=(1 << 24) - 1),
+        A.AggDesc("avg", col("l_discount"), "avg_disc", arg_scale=2, pack_bound=15),
+        A.AggDesc("count", None, "count_order"),
+    ]
+
+    def q1(b):
+        keep = b.row_valid & (b.cols["l_shipdate"].data <= jnp.int32(10471))
+        # as the scan hands them over: NOT NULL columns share one validity
+        cols = {n: DevCol(c.data, b.row_valid) for n, c in b.cols.items()}
+        return A.group_aggregate(
+            Batch(cols, keep), [col("l_returnflag"), col("l_linestatus")], aggs,
+            16, key_names=["l_returnflag", "l_linestatus"],
+            key_widths=[(2, 0), (2, 0)],
+        )
+
+    compiled, _s = _compile(q1, batch)
+    assert contracted == [(10, 22)]
+    ma = _fits_v5e(compiled)
+    assert ma.temp_size_in_bytes < int(3.5 * 2**30), ma.temp_size_in_bytes
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,8 @@
 """Numerics of the executor's lowerings, run here on the CPU.
 
 The executor lowers an operator one way, chosen from the shapes and
-widths it is handed (7 bits of packed key or fewer: masked reductions;
+widths it is handed (7 bits of packed key or fewer: a dense domain, its
+integer sums one digit contraction, min/max/float masked reductions;
 other keys: sorted; probes of 4,096 and more: merge; builds over 65,536:
 sorted lookup), so what runs here is what the chip runs. Each test
 compares a formulation with numpy (or a loop over the rows) on the same
@@ -286,7 +287,8 @@ _SHAPES = {
 def test_group_aggregate_is_its_numpy_reference(shape, mode, monkeypatch):
     """Every shape group_aggregate lowers, picked from keys and widths
     alone (no keys: one full-array reduction a lane; 7 bits or fewer:
-    dense domain, masked reductions; any other keys: sorted), plain,
+    dense domain, integer sums and counts one digit contraction, min,
+    max and the float sum masked; any other keys: sorted), plain,
     under a fused HAVING, and with DISTINCT aggregates (the claim-loop
     pair table), against a GROUP BY in plain Python: same groups, same
     NULLs, integers exact, float sums within 1e-10."""
@@ -296,7 +298,7 @@ def test_group_aggregate_is_its_numpy_reference(shape, mode, monkeypatch):
 
     keys, widths, capacity, dense = _SHAPES[shape]
     took = []
-    for module, name in ((A, "_masked_backend"), (A, "_scalar_backend"),
+    for module, name in ((A, "_DenseReducer"), (A, "_scalar_backend"),
                          (S, "sort_group_aggregate")):
         real = getattr(module, name)
         monkeypatch.setattr(
@@ -321,7 +323,7 @@ def test_group_aggregate_is_its_numpy_reference(shape, mode, monkeypatch):
     )(batch)
     assert took == [
         "_scalar_backend" if not keys
-        else "_masked_backend" if dense else "sort_group_aggregate"
+        else "_DenseReducer" if dense else "sort_group_aggregate"
     ]
     groups = _reference_groups(batch, keys, specs)
     want = [
@@ -354,29 +356,154 @@ def test_sorted_aggregation_reports_stale_widths():
     assert int(ng) >= WIDTH_STALE
 
 
-def test_small_dense_domain_takes_masked_backend(monkeypatch):
+def _spy_dense(monkeypatch):
+    """(lanes of each contraction, ops of each masked reduction) as the
+    dense reducer runs them."""
     import tidb_tpu.executor.aggregate as A
 
-    used = []
-    real = A._masked_backend
-    monkeypatch.setattr(A, "_masked_backend", lambda *a: used.append(1) or real(*a))
+    contracted, masked = [], []
+    real_contract, real_masked = A._DenseReducer._contract, A._masked_backend
+    monkeypatch.setattr(
+        A._DenseReducer, "_contract",
+        lambda self, lanes: contracted.append(lanes) or real_contract(self, lanes),
+    )
+
+    def spy_masked(seg, slots):
+        red = real_masked(seg, slots)
+        return lambda op, vals, *a: masked.append((op, str(vals.dtype))) or red(op, vals, *a)
+
+    monkeypatch.setattr(A, "_masked_backend", spy_masked)
+    return contracted, masked
+
+
+def test_small_dense_domain_contracts_integer_sums(monkeypatch):
+    """The dense rule: integer sums and counts of one aggregate are ONE
+    contraction, requests of the same (values, mask) one lane; min, max
+    and floating sums keep the masked reductions."""
+    import tidb_tpu.executor.aggregate as A
+    from tidb_tpu.utils.metrics import REGISTRY
+
+    contracted, masked = _spy_dense(monkeypatch)
+    ran = REGISTRY.counter("tidbtpu_executor_dense_contractions_total")
+    limbs = REGISTRY.counter("tidbtpu_executor_dense_contraction_limbs_total")
+    ran0, limbs0 = ran.value, limbs.value
     batch = _agg_batch()
-    aggs = [A.AggDesc("sum", _col("v"), "s"), A.AggDesc("count", None, "c")]
+    aggs = [
+        A.AggDesc("sum", _col("v"), "s", pack_bound=(1 << 20) - 1),
+        A.AggDesc("avg", _col("v"), "a", pack_bound=(1 << 20) - 1),
+        A.AggDesc("count", _col("v"), "cv"),
+        A.AggDesc("count", None, "c"),
+        A.AggDesc("min", _col("v"), "lo"),
+        A.AggDesc("sum", _col("f"), "sf"),
+    ]
     out, ng = jax.jit(
         lambda b: A.group_aggregate(
             b, [_col("k2")], aggs, 16, key_names=["k2"], key_widths=[(3, 0)]
         )
     )(batch)
-    assert used and int(ng) == 4  # 0, 1, 2 and NULL
+    # v under its mask (sum, avg), that mask's count (sum, avg, count(v),
+    # min), f's count, the row count (count(*), occupancy): four lanes,
+    # 21 bits of v in three digits and three counts of one
+    assert [len(lanes) for lanes in contracted] == [4]
+    assert sorted(r.bits for r in contracted[0]) == [2, 2, 2, 21]
+    assert ran.value - ran0 == 1 and limbs.value - limbs0 == 6
+    assert masked == [("min", "int64"), ("sum", "float64")]
+    assert int(ng) == 4  # 0, 1, 2 and NULL
     k2 = np.asarray(batch.cols["k2"].data)
     ok = np.asarray(batch.row_valid) & np.asarray(batch.cols["k2"].valid)
     vv = np.asarray(batch.cols["v"].valid)
     v = np.asarray(batch.cols["v"].data)
-    for key, s, c in _rows(out, ["k2", "s", "c"]):
+    for key, s, c, cv, lo in _rows(out, ["k2", "s", "c", "cv", "lo"]):
         m = (ok & (k2 == key)) if key is not None else (
             np.asarray(batch.row_valid) & ~np.asarray(batch.cols["k2"].valid)
         )
-        assert c == m.sum() and s == v[m & vv].sum()
+        assert c == m.sum() and cv == (m & vv).sum()
+        assert s == v[m & vv].sum() and lo == v[m & vv].min()
+
+
+def _lane(rng, n, bits, null_share=0.0):
+    """(int64 values of `bits` signed bits, contribution mask, bits)."""
+    lo, hi = -(1 << (bits - 1)), (1 << (bits - 1)) - 1
+    vals = rng.integers(lo, hi, n, dtype=np.int64, endpoint=True)
+    vals[:4] = [lo, hi, lo, hi][: min(4, n)]  # the extremes, twice: 64 bits wrap
+    return vals, rng.random(n) >= null_share, bits
+
+
+_REDUCER_CASES = {
+    # name: (rows, dense, piece, block, lanes as (bits, share of NULLs))
+    "negative values, each width": (5000, 16, 1 << 23, 1 << 16, [(b, 0.1) for b in (2, 8, 9, 14, 25, 32, 39, 63)]),
+    "int64 extremes wrap mod 2**64": (3000, 16, 1 << 23, 1 << 16, [(64, 0.0), (64, 0.3)]),
+    "an unbounded lane beside a 1-bit one": (3000, 16, 1 << 23, 1 << 16, [(64, 0.1), (2, 0.1)]),
+    "all-NULL lane and empty groups": (3000, 16, 1 << 23, 1 << 16, [(14, 1.0), (31, 0.0)]),
+    "a tile below one block": (100, 16, 1 << 23, 1 << 16, [(31, 0.1), (8, 0.0)]),
+    "whole blocks, whole pieces": (4096, 16, 2048, 512, [(31, 0.1), (64, 0.0)]),
+    "not a multiple of the block": (5000, 16, 2048, 512, [(31, 0.1), (64, 0.0), (2, 0.5)]),
+    "a last piece below one block": (4196, 16, 2048, 512, [(31, 0.1), (64, 0.0)]),
+    "dense 2": (3000, 2, 4096, 1024, [(25, 0.1), (64, 0.1)]),
+    "dense 128": (9000, 128, 8192, 4096, [(25, 0.1), (64, 0.1)]),
+}
+
+
+@pytest.mark.parametrize("case", list(_REDUCER_CASES))
+def test_dense_reducer_is_numpy_int64(case, monkeypatch):
+    """The reducer alone against numpy's int64 sums (which wrap mod
+    2**64 as jnp.sum's do): every slot of the domain, occupied or not;
+    rows of the out-of-domain slot `dense` counted nowhere."""
+    import tidb_tpu.executor.aggregate as A
+
+    rows, dense, piece, block, spec = _REDUCER_CASES[case]
+    monkeypatch.setattr(A, "_CONTRACT_BLOCK", block)
+    monkeypatch.setattr(A, "_CONTRACT_PIECE", piece)
+    rng = np.random.default_rng(len(case))
+    # slots 1 and dense - 1 stay empty; a tenth of the rows are of no slot
+    seg = rng.choice([s for s in range(dense + 1) if s not in (1, dense - 1)] + [dense] * (dense // 8 + 1), rows)
+    if case.startswith("all-NULL"):
+        seg[seg == 3] = dense
+    lanes = [_lane(rng, rows, bits, nulls) for bits, nulls in spec]
+
+    def run(seg, lanes):
+        red = A._DenseReducer(seg, dense)
+        return red.exec_all(
+            [A._Req("sum", v, c, jnp.int64(0), bits) for v, c, bits in lanes]
+        )
+
+    got = run(
+        jnp.asarray(seg, jnp.int32),
+        [(jnp.asarray(v), jnp.asarray(c), b) for v, c, b in lanes],
+    )
+    for (v, c, _b), g in zip(lanes, got):
+        want = np.array(
+            [v[c & (seg == s)].sum(dtype=np.int64) for s in range(dense)]
+        )
+        assert g.dtype == jnp.int64 and (np.asarray(g) == want).all(), case
+
+
+def test_dense_reducer_wide_lanes_equal_the_masked_form(monkeypatch):
+    """A wide sum's lo and hi lanes through the contraction are the
+    masked reductions' sums bit for bit, so the float64 that mk_s makes
+    of them is the same float64; and a narrower dtype's lane comes back
+    as int64 (the sorted reducer's rule)."""
+    import tidb_tpu.executor.aggregate as A
+
+    monkeypatch.setattr(A, "_CONTRACT_BLOCK", 1024)
+    monkeypatch.setattr(A, "_CONTRACT_PIECE", 4096)
+    rng = np.random.default_rng(34)
+    n, dense = 7000, 16
+    seg = jnp.asarray(rng.integers(0, dense + 1, n), jnp.int32)
+    d64 = jnp.asarray(rng.integers(-(1 << 38), 1 << 38, n, dtype=np.int64))
+    ok = jnp.asarray(rng.random(n) < 0.9)
+    lo, hi = d64 & ((1 << 30) - 1), d64 >> 30
+    i32 = jnp.asarray(rng.integers(-(1 << 31), (1 << 31) - 1, n, dtype=np.int64).astype(np.int32))
+    reqs = [
+        A._Req("sum", lo, ok, jnp.int64(0), 31),
+        A._Req("sum", hi, ok, jnp.int64(0), 10),
+        A._Req("sum", i32, ok, jnp.int32(0), 32),
+    ]
+    got = A._DenseReducer(seg, dense).exec_all(reqs)
+    masked = A._masked_backend(seg, dense)
+    for r, g in zip(reqs, got):
+        want = masked("sum", r.vals.astype(jnp.int64), r.contrib, jnp.int64(0))
+        assert g.dtype == jnp.int64 and (np.asarray(g) == np.asarray(want)).all()
 
 
 # ---------------------------------------------------------------------------

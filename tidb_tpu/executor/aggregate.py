@@ -10,8 +10,12 @@ a static-shape program has three shapes instead:
   no keys                  one group at slot 0, one full-array
                            reduction a lane (`_scalar_backend`)
   keys packing into        slot id == packed key over a dense domain of
-  _DENSE_BITS bits or      at most 128 slots, one fused masked reduction
-  fewer (Q1's flags)       per (slot, lane) (`_masked_backend`), then a
+  _DENSE_BITS bits or      at most 128 slots: every integer sum and count
+  fewer (Q1's flags)       of the aggregate rides ONE exact contraction
+                           of byte digits against the one-hot of the
+                           slot ids (`_DenseReducer`; min/max and
+                           floating sums: one masked reduction per
+                           (slot, lane), `_masked_backend`), then a
                            cumsum compaction of the occupied slots
   any other keys           sort rows by key, reduce runs by cumulative
                            sums and segmented scans
@@ -46,7 +50,7 @@ final aggregation (parallel/fragment.py), mirroring agg partial workers
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import Callable, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -60,9 +64,19 @@ ExprFn = Callable[[Batch], DevCol]
 _MAX_PROBES = 64
 
 # widest packed key domain the dense path takes: 2**7 = 128 slots, the
-# most that the masked reductions unroll over (Q1's two flags pack into
-# 4 bits). Wider keys sort.
+# most that the masked reductions unroll over and one MXU tile of one-hot
+# rows (Q1's two flags pack into 4 bits). Wider keys sort.
 _DENSE_BITS = 7
+
+# rows of one block of the dense contraction: a digit in [-128, 127]
+# times a 0/1 one-hot, summed into int32, stays exact while
+# 128 * block < 2**31; 2**14 to 2**18 time alike on the v5e, 2**20 three
+# times slower (PERF.md PR 34)
+_CONTRACT_BLOCK = 1 << 16
+# rows of one statically sliced piece, whose digits are made, contracted
+# (its blocks one batched dot) and dead before the next piece's: bounds
+# the program's temporaries whatever the tile
+_CONTRACT_PIECE = 1 << 23
 
 # reported in place of the group count when a row's key falls outside the
 # compile-time-baked packed-key bounds (int-column widths come from
@@ -123,7 +137,10 @@ class AggDesc:
     # fetch via CompiledQuery.bound_checks): lets the kernel pack the
     # (sum, count) lane pair into ONE biased int64 reduction —
     # (value + bound) << count_bits | 1 — halving the reduction passes
-    # (one lane instead of two, whatever the reducer).
+    # (one lane instead of two: the scalar and the sorted reducer). The
+    # dense reducer reads it as the lane's width: a sum rides as many
+    # byte digits as bound.bit_length() + 1 bits need, a `wide` sum's
+    # high part as many as the bits past its low 30.
     pack_bound: Optional[int] = None
 
 
@@ -276,66 +293,21 @@ def _dense_compact_group_aggregate(
 ):
     """Aggregation over the full dense packed-key domain (slot id ==
     packed key, at most 2**_DENSE_BITS slots: no assignment pass at
-    all), every lane a fused masked reduction per slot, followed by a
-    cumsum compaction of occupied slots into the `slots` output tile.
-    Reports the true group count — when it exceeds `slots` the host
-    bumps the capacity knob and re-jits exactly like the sorted path
-    (results here stay correct regardless; only the compaction tile was
-    too small)."""
+    all), every integer sum and count one contraction (_DenseReducer),
+    followed by a cumsum compaction of occupied slots into the `slots`
+    output tile. Reports the true group count — when it exceeds `slots`
+    the host bumps the capacity knob and re-jits exactly like the sorted
+    path (results here stay correct regardless; only the compaction tile
+    was too small)."""
     cap = batch.capacity
     dense = 1 << dense_bits
     packed, stale = _pack_keys(keys, key_widths, batch.row_valid)
-    # invalid / stale-width rows -> `dense`: no slot's mask matches them
-    # (and the `first` scatter below drops the out-of-range index)
+    # invalid / stale-width rows -> `dense`: no slot's one-hot row or
+    # mask matches them (and the `first` scatter below drops the
+    # out-of-range index)
     seg = jnp.where(
         batch.row_valid & (packed < dense), packed, dense
     ).astype(jnp.int32)
-
-    # a segment scatter costs the v5e ~45x a fused masked reduction at
-    # small domains (measured 64ms vs 1.4ms per lane at 1M rows)
-    red = _masked_backend(seg, dense)
-
-    # occupancy anchor: with a fused HAVING, a packed sum/avg lane whose
-    # contribution mask IS the row mask (nonnull-folded column — object
-    # identity is the trace-time proof) already carries the per-group
-    # row count, so the dedicated occupancy lane can be skipped: its
-    # output column's validity (count > 0) IS `occupied`.
-    anchor = None
-    if post_filter is not None and not any(a.func == "first" for a in aggs):
-        for i, (a, ac) in enumerate(zip(aggs, arg_cols)):
-            if (
-                a.func in ("sum", "avg")
-                and ac is not None
-                and _packs(a, ac, cap)
-                and ac.valid is batch.row_valid
-                and not (reps and i in reps)
-            ):
-                anchor = a.out_name
-                break
-    if anchor is not None:
-        occupied = jnp.ones(dense, dtype=bool)
-        ngroups = None  # derived from the anchor lane below
-    else:
-        occ_n = red(
-            "sum",
-            batch.row_valid.astype(jnp.int64),
-            batch.row_valid,
-            jnp.int64(0),
-        )
-        occupied = occ_n > 0
-        ngroups = jnp.sum(occupied.astype(jnp.int64))
-        ngroups = jnp.where(stale, jnp.int64(WIDTH_STALE), ngroups)
-
-    # dense-domain key reconstruction
-    sid = jnp.arange(dense, dtype=jnp.int64)
-    out_cols = {}
-    off = 0
-    for name, k, (w, b) in zip(key_names, keys, key_widths):
-        limb = (sid >> off) & ((1 << w) - 1)
-        off += w
-        kv = (limb != 0) & occupied
-        kd = (limb - (b + 1)).astype(k.data.dtype)
-        out_cols[name] = DevCol(jnp.where(kv, kd, jnp.zeros_like(kd)), kv)
 
     claimer = None
     if any(a.func == "first" for a in aggs):
@@ -350,10 +322,28 @@ def _dense_compact_group_aggregate(
         else jnp.zeros(dense, dtype=jnp.int32)
     )
 
+    # a slot is occupied where its row count is positive: that count is
+    # one more lane of the aggregate's contraction (count(*)'s own, where
+    # the statement has one), so _run_aggs derives group validity itself
     wide = _run_aggs(
-        batch, aggs, arg_cols, seg, dense, occupied, cl, out_cols, red,
-        reps=reps,
+        batch, aggs, arg_cols, seg, dense, None, cl, {},
+        _DenseReducer(seg, dense), reps=reps,
     )
+    occupied = wide.row_valid
+    ngroups = jnp.sum(occupied.astype(jnp.int64))
+    ngroups = jnp.where(stale, jnp.int64(WIDTH_STALE), ngroups)
+
+    # dense-domain key reconstruction
+    sid = jnp.arange(dense, dtype=jnp.int64)
+    key_cols = {}
+    off = 0
+    for name, k, (w, b) in zip(key_names, keys, key_widths):
+        limb = (sid >> off) & ((1 << w) - 1)
+        off += w
+        kv = (limb != 0) & occupied
+        kd = (limb - (b + 1)).astype(k.data.dtype)
+        key_cols[name] = DevCol(jnp.where(kv, kd, jnp.zeros_like(kd)), kv)
+    wide = Batch({**key_cols, **wide.cols}, occupied)
 
     if post_filter is not None:
         # fused HAVING: evaluate the predicate over the DENSE domain and
@@ -364,33 +354,42 @@ def _dense_compact_group_aggregate(
         # output tile never loses groups. (Reference: HAVING lowers to
         # a Selection above the agg, pkg/planner/core — here the dense
         # layout makes fusing it strictly cheaper.)
-        occ_true = (
-            wide.cols[anchor].valid if anchor is not None else wide.row_valid
-        )
         c = post_filter(wide)
-        keep = occ_true & c.valid & (c.data != 0)
-        occupied = keep
+        occupied = occupied & c.valid & (c.data != 0)
         ngroups = jnp.where(
             stale,
             jnp.int64(WIDTH_STALE),
-            jnp.sum(keep.astype(jnp.int64)),
+            jnp.sum(occupied.astype(jnp.int64)),
         )
-        wide = Batch(wide.cols, keep)
+        wide = Batch(wide.cols, occupied)
 
-    # compact occupied dense slots into the output tile, in slot-id
-    # (ascending key) order
+    cols = _compact_slots(wide.cols, occupied, slots)
+    row_valid = jnp.arange(slots) < jnp.minimum(ngroups, slots)
+    return Batch(cols, row_valid), fold_distinct_overflow(ngroups)
+
+
+def _compact_slots(cols, occupied, slots):
+    """Compact the occupied slots of a dense domain into a tile of
+    `slots` rows, in slot-id (ascending key) order: output row j takes
+    the one slot whose rank among the occupied is j, by select and sum
+    over the (at most 128 x 128) pairs. Not `.at[pos].set(...,
+    mode="drop")`: on the v5e that scatter of an int64 column returned
+    Q1's first group 2**31 - 655,360 short on two of four SF10 data
+    sets (PERF.md PR 34), where the dense-domain values before it were
+    exact. `chip_smoke.py` runs this function on the chip against numpy."""
     pos = jnp.where(
         occupied, jnp.cumsum(occupied.astype(jnp.int32)) - 1, slots
     )
-    cols = {}
-    for name, c in wide.cols.items():
-        nd = jnp.zeros(slots, dtype=c.data.dtype).at[pos].set(
-            c.data, mode="drop"
-        )
-        nv = jnp.zeros(slots, dtype=bool).at[pos].set(c.valid, mode="drop")
-        cols[name] = DevCol(nd, nv)
-    row_valid = jnp.arange(slots) < jnp.minimum(ngroups, slots)
-    return Batch(cols, row_valid), fold_distinct_overflow(ngroups)
+    take = pos[None, :] == jnp.arange(slots, dtype=jnp.int32)[:, None]
+    out = {}
+    for name, c in cols.items():
+        nd = jnp.sum(
+            jnp.where(take, c.data[None, :], jnp.zeros((), c.data.dtype)),
+            axis=1,
+        ).astype(c.data.dtype)
+        nv = jnp.any(take & c.valid[None, :], axis=1)
+        out[name] = DevCol(nd, nv)
+    return out
 
 
 def _needs_rep(a: AggDesc) -> bool:
@@ -555,14 +554,41 @@ def _scalar_backend(slots):
     return red
 
 
+class _Req(NamedTuple):
+    """One reduction _run_aggs asks of its reducer: `op` in {sum, min,
+    max} over `vals` where `contrib`, `ident` elsewhere."""
+
+    op: str
+    vals: jax.Array
+    contrib: jax.Array
+    ident: jax.Array
+    # signed bits that hold every value of an integer `vals`, as far as
+    # is known when the program is traced (a proven bound, the wide
+    # split, a count's 0/1; else the dtype's)
+    bits: int = 64
+    # what `contrib` was made of, where _run_aggs made it anew for each
+    # aggregate: (id(validity array), id(DISTINCT representatives)).
+    # A reducer that merges requests compares this; None: the object
+    mask: tuple | None = None
+
+
+def _int_bits(dtype) -> int:
+    """Signed bits that hold every value of an integer dtype."""
+    if dtype == jnp.bool_:
+        return 2
+    unsigned = jnp.issubdtype(dtype, jnp.unsignedinteger)
+    return min(64, dtype.itemsize * 8 + int(unsigned))
+
+
 def _masked_backend(seg, slots):
     """Aggregate reductions as fused masked full-array reductions, one
     accumulator per (slot, agg) — scatter-free; unrolled over the slots,
-    so for the dense path's few (at most 2**_DENSE_BITS) only. The
-    optimization barrier pins the reduction inputs: without it XLA fuses
-    the producer expression tree (decimal products, filters) into EVERY
-    per-slot reduction, recomputing it slots*aggs times — measured 35x
-    slowdown on whole-query Q1."""
+    so for the dense path's few (at most 2**_DENSE_BITS) only, and there
+    for what digits cannot carry exactly: min, max and floating sums.
+    The optimization barrier pins the reduction inputs: without it XLA
+    fuses the producer expression tree (decimal products, filters) into
+    EVERY per-slot reduction, recomputing it slots*aggs times — measured
+    35x slowdown on whole-query Q1."""
 
     def red(op, vals, contrib, ident):
         f = _REDUCE[op]
@@ -572,6 +598,128 @@ def _masked_backend(seg, slots):
         )
 
     return red
+
+
+def _byte_digits(vals, bits: int):
+    """Digits d[i] in [-128, 127], least significant first, of an
+    integer array whose values fit `bits` signed bits, with
+    sum(d[i] * 256**i) == vals (mod 2**64 at 64 bits): the bytes of
+    vals + 0x80..80, each less 128, under a signed top byte. Exact in
+    int8, the narrowest type the MXU multiplies."""
+    nl = 1 if bits <= 8 else min(8, -(-(bits + 1) // 8))
+    v = vals.astype(jnp.int64) + sum(128 << (8 * i) for i in range(nl - 1))
+    words = (v.astype(jnp.uint32), (v >> 32).astype(jnp.uint32))
+    out = []
+    for i in range(nl):
+        b = ((words[i // 4] >> (8 * (i % 4))) & 0xFF).astype(jnp.int32)
+        out.append((b ^ 0x80 if i == nl - 1 else b) - 128)
+    return out
+
+
+class _DenseReducer:
+    """Reduction backend over the dense key domain (seg in [0, dense),
+    `dense` for a row of no slot). ALL integer sums and counts of an
+    aggregate are one exact contraction over the row axis: requests of
+    the same values under the same mask (_Req.mask) are one lane, merged
+    here and nowhere else; a lane is as many int8 byte digits as its
+    statically known width needs (_byte_digits), laid out [digits,
+    rows] with rows minor, and the MXU contracts them against
+    the one-hot of seg, [dense, rows] x [digits, rows] -> [dense,
+    digits], in row blocks whose int32 partials cannot overflow; the
+    partials are widened to int64, summed, and the digits recombined by
+    shifts into the int64 sum a masked reduction gives (mod 2**64). The
+    v5e has no native int64: the masked form wrote every request as an
+    int64 array and passed over it once a slot (Q1 at SF10: 16 requests
+    of 512 MB x 16 slots, 169 ms; PERF.md PR 34). min, max and floating
+    sums, which digits cannot carry, keep the masked form."""
+
+    # a count is one shared one-digit lane here, so _run_aggs asks for
+    # (sum, count) pairs and never packs a count into its sum's lane
+    shares_counts = True
+
+    def __init__(self, seg, dense):
+        self.seg = seg
+        self.dense = dense
+        self.masked = _masked_backend(seg, dense)
+
+    def exec_all(self, reqs):
+        results: list = [None] * len(reqs)
+        lanes: dict = {}  # (id(vals), mask) -> indices into reqs
+        for i, r in enumerate(reqs):
+            if r.op == "sum" and not jnp.issubdtype(r.vals.dtype, jnp.floating):
+                mask = r.mask if r.mask is not None else (id(r.contrib),)
+                lanes.setdefault((id(r.vals), mask), []).append(i)
+            else:
+                results[i] = self.masked(r.op, r.vals, r.contrib, r.ident)
+        if lanes:
+            with jax.named_scope("contract"):
+                sums = self._contract(
+                    [
+                        reqs[ix[0]]._replace(
+                            bits=min(reqs[i].bits for i in ix), mask=mask
+                        )
+                        for (_vals, mask), ix in lanes.items()
+                    ]
+                )
+            for total, ix in zip(sums, lanes.values()):
+                for i in ix:
+                    results[i] = total
+        return results
+
+    def _contract(self, lanes):
+        from tidb_tpu.utils.metrics import REGISTRY
+
+        cap = self.seg.shape[0]
+        slot = jnp.arange(self.dense, dtype=jnp.int32)[None, :, None]
+        contribs = {r.mask: r.contrib for r in lanes}  # one a distinct mask
+        sums = None
+        for at in range(0, cap, _CONTRACT_PIECE):
+            rows = slice(at, min(at + _CONTRACT_PIECE, cap))
+            keep = [c[rows] for c in contribs.values()]
+            if sums is not None:
+                # a piece's digits wait for the sums of the piece before
+                # (every digit reads its mask): one piece's temporaries
+                # are live at a time, not as many as the scheduler likes
+                sums, keep = jax.lax.optimization_barrier((sums, keep))
+            keep = dict(zip(contribs, keep))
+            digits, spans = [], []
+            for r in lanes:
+                ds = _byte_digits(r.vals[rows], r.bits)
+                spans.append((len(digits), len(ds)))
+                digits += [
+                    jnp.where(keep[r.mask], d, 0).astype(jnp.int8)
+                    for d in ds
+                ]
+            seg, x = self.seg[rows], jnp.stack(digits)
+            # the ladder's tiles (2**k, 3 * 2**(k-1)) are whole blocks,
+            # or one short one; any other length is padded to them
+            block = min(_CONTRACT_BLOCK, seg.shape[0])
+            pad = -seg.shape[0] % block
+            if pad:
+                seg = jnp.pad(seg, (0, pad), constant_values=self.dense)
+                x = jnp.pad(x, ((0, 0), (0, pad)))
+            part = jax.lax.dot_general(
+                (seg.reshape(-1, 1, block) == slot).astype(jnp.int8),
+                x.reshape(len(digits), -1, block),
+                (((2,), (2,)), ((0,), (1,))),
+                preferred_element_type=jnp.int32,
+            )  # [blocks, dense, digits]
+            part = part.astype(jnp.int64).sum(axis=0)
+            sums = part if sums is None else sums + part
+        REGISTRY.counter(
+            "tidbtpu_executor_dense_contractions_total",
+            "aggregates over a dense key domain whose integer sums and "
+            "counts ride one digit contraction, in traced programs",
+        ).inc()
+        REGISTRY.counter(
+            "tidbtpu_executor_dense_contraction_limbs_total",
+            "int8 digit rows of those contractions, after requests of "
+            "the same (values, mask) were merged",
+        ).inc(len(digits))
+        return [
+            sum(sums[:, at + i] << (8 * i) for i in range(n))
+            for at, n in spans
+        ]
 
 
 class _SortedReducer:
@@ -598,7 +746,7 @@ class _SortedReducer:
         ends_i = jnp.clip(self.ends - 1, 0, self.cap - 1)
         # --- stack sum lanes by accumulation dtype ---
         groups: dict = {}
-        for i, (op, vals, contrib, ident) in enumerate(reqs):
+        for i, (op, vals, contrib, *_rest) in enumerate(reqs):
             if op == "sum":
                 acc = (
                     jnp.float64
@@ -629,7 +777,7 @@ class _SortedReducer:
                 )
                 results[i] = total[:, j].astype(out_dtype)
         # --- min/max lanes: segmented scan each ---
-        for i, (op, vals, contrib, ident) in enumerate(reqs):
+        for i, (op, vals, contrib, ident, *_rest) in enumerate(reqs):
             if op == "sum":
                 continue
             f = jnp.maximum if op == "max" else jnp.minimum
@@ -640,7 +788,7 @@ class _SortedReducer:
         return results
 
     def __call__(self, op, vals, contrib, ident):
-        return self.exec_all([(op, vals, contrib, ident)])[0]
+        return self.exec_all([_Req(op, vals, contrib, ident)])[0]
 
 
 def _run_sorted_aggs(
@@ -672,54 +820,71 @@ def _run_aggs(
     """Compute all aggregates into the slot table. One implementation of
     the MySQL aggregate semantics (NULL rules, AVG decimal scale),
     parameterized over the reducer `red(op, vals, contrib, ident)` ->
-    [slots] (_scalar_backend, _masked_backend, _SortedReducer). `reps`
+    [slots] (_scalar_backend, _DenseReducer, _SortedReducer). `reps`
     maps agg index to a DISTINCT representative-row mask
-    (_distinct_reps). Runs in three phases — collect reduction requests,
-    execute them (all at once where the reducer has `exec_all`), then
-    assemble output columns — so independent lanes share passes."""
+    (_distinct_reps). Runs in three phases — collect reduction requests
+    (_Req), execute them (all at once where the reducer has `exec_all`),
+    then assemble output columns — so independent lanes share passes.
+    `group_valid` None: a slot is a group where it holds a row, counted
+    by one more request."""
     srow_valid = seg < slots
     ones = jnp.ones_like(seg, dtype=jnp.int64)
     reqs = []
 
-    def req(op, vals, contrib, ident):
-        reqs.append((op, vals, contrib, ident))
+    def req(op, vals, contrib, ident, bits=64, mask=None):
+        reqs.append(_Req(op, vals, contrib, ident, bits, mask))
         return len(reqs) - 1
 
-    assemble = []  # callables taking the executed results list
+    def count(contrib, mask=None):
+        return req("sum", ones, contrib, jnp.int64(0), 2, mask)
+
+    assemble = []  # callables taking the executed results and group_valid
 
     def emit(name, fn):
         assemble.append((name, fn))
 
+    r_rows = count(srow_valid) if group_valid is None else None
+
     for i, (a, col) in enumerate(zip(aggs, arg_cols)):
         if a.func == "count" and col is None:
-            rid = req("sum", ones, srow_valid, jnp.int64(0))
-            emit(a.out_name, lambda R, rid=rid: DevCol(R[rid], group_valid))
+            rid = count(srow_valid)
+            emit(a.out_name, lambda R, gv, rid=rid: DevCol(R[rid], gv))
             continue
 
         data = col.data
+        bits = _int_bits(data.dtype)
         if data.dtype == jnp.bool_ and a.func in ("sum", "avg", "min", "max"):
             # SUM(bool_expr) etc.: MySQL treats booleans as 0/1 ints
             data = data.astype(jnp.int64)
+        rep = reps[i] if reps and i in reps else None
         valid = col.valid & srow_valid
-        if reps and i in reps:
-            valid = valid & reps[i]
+        if rep is not None:
+            valid = valid & rep
+        # what `valid` is made of (arg_cols and reps outlive the ids): a
+        # reducer that merges requests compares this, `valid` being new
+        mask = (id(col.valid), id(rep))
         if a.func == "count":
-            rid = req("sum", ones, valid, jnp.int64(0))
-            emit(a.out_name, lambda R, rid=rid: DevCol(R[rid], group_valid))
+            rid = count(valid, mask)
+            emit(a.out_name, lambda R, gv, rid=rid: DevCol(R[rid], gv))
         elif a.func in ("sum", "avg"):
+            if a.pack_bound is not None:
+                bits = min(bits, int(a.pack_bound).bit_length() + 1)
+            mk_cnt = None  # the packed lane brings its own
             if a.wide and not jnp.issubdtype(data.dtype, jnp.floating):
                 d64 = data.astype(jnp.int64)
                 lo = d64 & jnp.int64((1 << 30) - 1)
                 hi = d64 >> 30  # arithmetic shift: hi*2^30 + lo == d64
-                rlo = req("sum", lo, valid, jnp.int64(0))
-                rhi = req("sum", hi, valid, jnp.int64(0))
+                rlo = req("sum", lo, valid, jnp.int64(0), 31, mask)
+                rhi = req("sum", hi, valid, jnp.int64(0), max(bits - 30, 2), mask)
 
                 def mk_s(R, rlo=rlo, rhi=rhi):
                     return R[rhi].astype(jnp.float64) * float(1 << 30) + R[
                         rlo
                     ].astype(jnp.float64)
 
-            elif _packs(a, col, batch.capacity):
+            elif not getattr(red, "shares_counts", False) and _packs(
+                a, col, batch.capacity
+            ):
                 # packed (sum, count) single reduction: values biased
                 # non-negative so the count rides the low bits with no
                 # carry; bound re-verified at fetch (AggDesc.pack_bound)
@@ -727,7 +892,7 @@ def _run_aggs(
                 bias = int(a.pack_bound)
                 d64 = data.astype(jnp.int64)
                 pv = ((d64 + bias) << cb) | 1
-                rp = req("sum", pv, valid, jnp.int64(0))
+                rp = req("sum", pv, valid, jnp.int64(0), mask=mask)
                 mask = jnp.int64((1 << cb) - 1)
 
                 def mk_s(R, rp=rp, cb=cb, bias=bias, mask=mask):
@@ -736,51 +901,31 @@ def _run_aggs(
                 def mk_cnt(R, rp=rp, mask=mask):
                     return R[rp] & mask
 
-                if a.func == "sum":
-
-                    def fin(R, mk_s=mk_s, mk_cnt=mk_cnt):
-                        cnt = mk_cnt(R)
-                        return DevCol(mk_s(R), (cnt > 0) & group_valid)
-
-                else:
-                    scale = a.arg_scale
-
-                    def fin(R, mk_s=mk_s, mk_cnt=mk_cnt, scale=scale):
-                        cnt = mk_cnt(R)
-                        denom = jnp.where(cnt == 0, 1, cnt).astype(
-                            jnp.float64
-                        )
-                        if scale:
-                            denom = denom * (10**scale)
-                        return DevCol(
-                            mk_s(R).astype(jnp.float64) / denom,
-                            (cnt > 0) & group_valid,
-                        )
-
-                emit(a.out_name, fin)
-                continue
             else:
-                rs = req("sum", data, valid, jnp.zeros((), data.dtype))
+                rs = req(
+                    "sum", data, valid, jnp.zeros((), data.dtype), bits, mask
+                )
 
                 def mk_s(R, rs=rs):
                     return R[rs]
 
-            rc = req("sum", ones, valid, jnp.int64(0))
+            if mk_cnt is None:
+                rc = count(valid, mask)
 
-            def mk_cnt(R, rc=rc):
-                return R[rc]
+                def mk_cnt(R, rc=rc):
+                    return R[rc]
 
             if a.func == "sum":
 
-                def fin(R, mk_s=mk_s, mk_cnt=mk_cnt):
+                def fin(R, gv, mk_s=mk_s, mk_cnt=mk_cnt):
                     cnt = mk_cnt(R)
                     # SUM over an all-NULL / empty group is NULL (MySQL)
-                    return DevCol(mk_s(R), (cnt > 0) & group_valid)
+                    return DevCol(mk_s(R), (cnt > 0) & gv)
 
             else:
                 scale = a.arg_scale
 
-                def fin(R, mk_s=mk_s, mk_cnt=mk_cnt, scale=scale):
+                def fin(R, gv, mk_s=mk_s, mk_cnt=mk_cnt, scale=scale):
                     cnt = mk_cnt(R)
                     denom = jnp.where(cnt == 0, 1, cnt).astype(jnp.float64)
                     if scale:
@@ -790,34 +935,34 @@ def _run_aggs(
                         denom = denom * (10**scale)
                     return DevCol(
                         mk_s(R).astype(jnp.float64) / denom,
-                        (cnt > 0) & group_valid,
+                        (cnt > 0) & gv,
                     )
 
             emit(a.out_name, fin)
         elif a.func in ("min", "max"):
             ident = _type_max(data.dtype) if a.func == "min" else _type_min(data.dtype)
-            rs = req(a.func, data, valid, ident)
-            rc = req("sum", ones, valid, jnp.int64(0))
+            rs = req(a.func, data, valid, ident, mask=mask)
+            rc = count(valid, mask)
             emit(
                 a.out_name,
-                lambda R, rs=rs, rc=rc, p=a.post: DevCol(
-                    p(R[rs]) if p is not None else R[rs],
-                    (R[rc] > 0) & group_valid,
+                lambda R, gv, rs=rs, rc=rc, p=a.post: DevCol(
+                    p(R[rs]) if p is not None else R[rs], (R[rc] > 0) & gv
                 ),
             )
         elif a.func == "first":
-            d = data[cl]
-            out_cols[a.out_name] = DevCol(d, col.valid[cl] & group_valid)
+            emit(
+                a.out_name,
+                lambda R, gv, d=data[cl], v=col.valid[cl]: DevCol(d, v & gv),
+            )
         else:
             raise NotImplementedError(f"agg func {a.func!r}")
 
     exec_all = getattr(red, "exec_all", None)
-    results = (
-        exec_all(reqs) if exec_all is not None
-        else [red(op, v, c, ident) for (op, v, c, ident) in reqs]
-    )
+    results = exec_all(reqs) if exec_all is not None else [red(*r[:4]) for r in reqs]
+    if group_valid is None:
+        group_valid = results[r_rows] > 0
     for name, fn in assemble:
-        out_cols[name] = fn(results)
+        out_cols[name] = fn(results, group_valid)
     return Batch(out_cols, group_valid)
 
 

@@ -6,7 +6,10 @@ primitive costs at:
     lax.sort (1-3 operands)      ~3-6 ms      regular strided passes
     cumsum / segmented scan      ~3 ms        regular
     row-gather [N, L] matrix     ~4 ms        amortizes over lanes
-    masked reduction (<=128)     ~1.4 ms      fused, no data movement
+    masked reduction (<=128)     ~1.4 ms      one int64 lane, 64 slots, 1M rows;
+                                              16 lanes x 16 slots at 67M rows took
+                                              169 ms (PERF.md PR 34: integer sums
+                                              over a dense domain contract instead)
     jnp.searchsorted (N probes)  ~160 ms      log N rounds of random gather
     segment_sum scatter          ~64 ms       serialized scatter
     scatter-min                  ~130 ms      serialized scatter

@@ -800,7 +800,9 @@ def build_agg_parts(plan: "L.Aggregate", dicts, compiler=None):
             # The same proof funds the packed (sum,count) single-pass
             # reduction (AggDesc.pack_bound) for ALL integer sums —
             # re-verified against live storage bounds at every fetch.
-            if r is not None and r[0] < (1 << 31) and all(
+            # A wider proven bound keeps the wide accumulator and still
+            # tells the kernel how many digits the sum's lanes need.
+            if r is not None and r[0] < (1 << 62) and all(
                 lb.nid is not None for lb in r[1]
             ):
                 for lb in r[1]:
@@ -808,7 +810,7 @@ def build_agg_parts(plan: "L.Aggregate", dicts, compiler=None):
                     compiler.bound_checks.append(
                         (lb.nid, lb.col, int(cb[0]), int(cb[1]))
                     )
-                wide = False
+                wide = wide and r[0] >= (1 << 31)
                 # the bias the program bakes: the next 2**k - 1 at or
                 # above the bound, which packs in as many bits and does
                 # not change with a data set's largest value
