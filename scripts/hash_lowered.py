@@ -1,7 +1,7 @@
 """sha256 of the lowered StableHLO (no debug locations) of every program
-`planner/physical.py` jit-compiles for Q1, Q5, Q6 at SF 0.01 on one device
-and for Q5 on a 4-device CPU mesh, as JSON on stdout: the proof that a
-refactor leaves the programs alone (PR 30, PR 31). About 20 s on the CPU.
+`planner/physical.py` jit-compiles for Q1, Q5, Q6, Q18 at SF 0.01 on one
+device and for Q5 on a 4-device CPU mesh, as JSON on stdout: the proof that
+a refactor leaves the programs alone (PR 30, PR 31). About 30 s on the CPU.
 
     python scripts/hash_lowered.py . > /root/scratch/change.json
     mkdir -p /root/scratch/parent && git archive HEAD | tar -x -C /root/scratch/parent
@@ -15,11 +15,13 @@ With `--q95 <sf> <seed> [<seed> ...]` after the tree it hashes instead
 the programs of TPC-DS Q95 (`tpcds_sf1`'s population at that scale
 factor) for each seed, under the labels `q95:<seed>`: the proof that
 one program serves every data set (PR 33). Two seeds' programs are the
-same where their lists are equal."""
+same where their lists are equal. `--q18 <sf> <seed> [<seed> ...]` does
+the same for TPC-H Q18 over `tpch_sf1`'s population, under `q18:<seed>`
+(PR 35)."""
 import hashlib, json, os, sys
 
 repo = os.path.abspath(sys.argv[1])
-Q95 = sys.argv[3:] if sys.argv[2:3] == ["--q95"] else None
+PER_SEED = sys.argv[2][2:] if sys.argv[2:3] in (["--q95"], ["--q18"]) else None
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 os.chdir(repo)
@@ -79,14 +81,18 @@ def sql_of(name):
 
 from tidb_tpu.session import Session
 
-if Q95:
-    tpcds = load(os.path.join(B, "loaders", "tpcds.py"), "bench_tpcds")
-    SF = float(Q95[0])
-    runs = [(f"q95:{seed}", tpcds.Deployment, config("tpcds_sf1"), None, ("q95",), int(seed), "tpcds")
-            for seed in Q95[1:]]
+if PER_SEED:
+    SF = float(sys.argv[3])
+    if PER_SEED == "q95":
+        tpcds = load(os.path.join(B, "loaders", "tpcds.py"), "bench_tpcds")
+        dep_cls, cfg, db = tpcds.Deployment, config("tpcds_sf1"), "tpcds"
+    else:
+        dep_cls, cfg, db = mesh_loader.tpch.Deployment, one_cfg, "tpch"
+    runs = [(f"{PER_SEED}:{seed}", dep_cls, cfg, None, (PER_SEED,), int(seed), db)
+            for seed in sys.argv[4:]]
 else:
     runs = [
-        ("one", mesh_loader.tpch.Deployment, one_cfg, None, ("q1", "q5", "q6"), SEED, "tpch"),
+        ("one", mesh_loader.tpch.Deployment, one_cfg, None, ("q1", "q5", "q6", "q18"), SEED, "tpch"),
         ("mesh4", mesh_loader.Deployment, mesh_cfg, 4, ("q5",), SEED, "tpch"),
     ]
 for which, dep_cls, cfg, width, stmts, seed, db in runs:
@@ -97,7 +103,7 @@ for which, dep_cls, cfg, width, stmts, seed, db in runs:
     for s in dep.analyze_statements():
         sess.execute(s)
     for q in stmts:
-        label[0] = which if Q95 else f"{which}:{q}"
+        label[0] = which if PER_SEED else f"{which}:{q}"
         print(label[0], file=sys.stderr, flush=True)
         r1 = sess.execute(sql_of(q))
         r2 = sess.execute(sql_of(q))  # steady

@@ -18,7 +18,8 @@ the devices that ran an op: the chips of a mesh run one program):
   a named sub-scope an operator opens (``SUB_SCOPES``: a dense
   aggregate's ``/contract``, executor/aggregate.py; a join's
   ``/compact``, ``/lookup`` and ``/expand/search | gather``,
-  executor/join.py; on a mesh an exchange's
+  executor/join.py; a sorted aggregate's ``/group/sort | reduce``,
+  executor/sortops.py; on a mesh an exchange's
   ``/exchange/sort | pack | all-to-all`` and ``/broadcast/all-gather``,
   parallel/exchange.py), and who owns the custom
   fusions (``hlo_category`` "custom fusion": XLA's scatter-shaped
@@ -52,6 +53,9 @@ SUB_SCOPES = (
     "lookup",  # a sorted unique lookup's reads at lo (executor/join.py)
     # an expanding join: lo/hi and the slot-to-probe search, the emit
     "expand", "expand/search", "expand/gather",
+    # a sorted group-by: the sort and the boundaries, the stacked gather
+    # and the cumulative sums (executor/sortops.py)
+    "group", "group/sort", "group/reduce",
     # a repartition and its stages, a broadcast (parallel/exchange.py)
     "exchange", "exchange/sort", "exchange/pack", "exchange/all-to-all",
     "broadcast/all-gather",

@@ -45,6 +45,8 @@ stability moot.
 
 from __future__ import annotations
 
+import contextlib
+import threading
 from typing import Sequence, Tuple
 
 import jax
@@ -53,6 +55,43 @@ import jax.numpy as jnp
 from tidb_tpu.chunk import Batch, DevCol
 
 _I64_MAX = jnp.iinfo(jnp.int64).max
+
+_TRACING = threading.local()
+
+
+@contextlib.contextmanager
+def grouping_ledger():
+    """What the sorted group-bys traced inside did: one (rows, groups,
+    slots) a group-by, `rows` the scalar of the valid rows that entered
+    it, `groups` that of the groups it found and `slots` its group
+    table. The program opens it around the plan and returns the sums
+    beside its cardinality scalars (planner/physical.py), as it does
+    the expanding joins' (join.expansion_ledger)."""
+    prev = getattr(_TRACING, "groupings", None)
+    _TRACING.groupings = out = []
+    try:
+        yield out
+    finally:
+        _TRACING.groupings = prev
+        if prev is not None:
+            prev.extend(out)
+
+
+def _note_grouping(rows: jax.Array, groups: jax.Array, slots: int) -> None:
+    """Count one traced sorted group-by (once per aggregate per traced
+    program, as the dense contractions are counted) and put it on the
+    open ledger."""
+    from tidb_tpu.utils.metrics import REGISTRY
+
+    REGISTRY.counter(
+        "tidbtpu_executor_sorted_groupings_total",
+        "keyed aggregates traced on the sorted path: a packed key wider "
+        "than the dense domain, so the rows are sorted by it and "
+        "reduced between the segment boundaries",
+    ).inc()
+    ledger = getattr(_TRACING, "groupings", None)
+    if ledger is not None:
+        ledger.append((rows, groups, int(slots)))
 
 
 def bits_for(n: int) -> int:
@@ -428,54 +467,60 @@ def sort_group_aggregate(
         if bad is not None:
             stale = stale | jnp.any(batch.row_valid & bad)
     rowid_at = len(comps)
-    sorted_ops, where, perm = sort_rows(comps, cap)
-    valid_s = unpack_lex(sorted_ops, where, 0) == 0  # invalid rows sort last
+    # the sort, the boundaries and the keys at the segment starts
+    with jax.named_scope("group"), jax.named_scope("sort"):
+        sorted_ops, where, perm = sort_rows(comps, cap)
+        valid_s = unpack_lex(sorted_ops, where, 0) == 0  # invalid rows sort last
 
-    # a new group starts where any key bit differs from the row before:
-    # compare whole operands, the row id's bits (the tail of the last
-    # packed run) shifted out
-    rid_first, rid_limbs, _off, rid_bits = where[rowid_at]
-    diff = jnp.zeros(cap, dtype=bool).at[0].set(True)
-    for oi, op in enumerate(sorted_ops):
-        if oi >= rid_first:
-            tail = rid_bits - 32 * (rid_first + rid_limbs - 1 - oi)
-            if tail >= 32:
-                continue  # nothing but row-id bits in this limb
-            if tail > 0:
-                op = op >> jnp.uint32(tail)
-        diff = diff | jnp.concatenate(
-            [jnp.ones(1, dtype=bool), op[1:] != op[:-1]]
+        # a new group starts where any key bit differs from the row before:
+        # compare whole operands, the row id's bits (the tail of the last
+        # packed run) shifted out
+        rid_first, rid_limbs, _off, rid_bits = where[rowid_at]
+        diff = jnp.zeros(cap, dtype=bool).at[0].set(True)
+        for oi, op in enumerate(sorted_ops):
+            if oi >= rid_first:
+                tail = rid_bits - 32 * (rid_first + rid_limbs - 1 - oi)
+                if tail >= 32:
+                    continue  # nothing but row-id bits in this limb
+                if tail > 0:
+                    op = op >> jnp.uint32(tail)
+            diff = diff | jnp.concatenate(
+                [jnp.ones(1, dtype=bool), op[1:] != op[:-1]]
+            )
+        boundary = valid_s & diff
+        ngroups = jnp.sum(boundary.astype(jnp.int64))
+        nvalid = jnp.sum(valid_s.astype(jnp.int32))
+
+        # segment start positions, compacted into the `slots` tile by a sort
+        # (scatter-free); ends follow by shifting, the last real group ending
+        # at nvalid
+        spos = jnp.where(boundary, jnp.arange(cap, dtype=jnp.int32), cap)
+        if slots > cap:
+            spos = jnp.concatenate(
+                [spos, jnp.full(slots - cap, cap, dtype=jnp.int32)]
+            )
+        starts = jax.lax.sort([spos], num_keys=1, is_stable=False)[0][:slots]
+        ends = jnp.minimum(
+            jnp.concatenate([starts[1:], jnp.full(1, cap, dtype=jnp.int32)]),
+            nvalid,
         )
-    boundary = valid_s & diff
-    ngroups = jnp.sum(boundary.astype(jnp.int64))
-    nvalid = jnp.sum(valid_s.astype(jnp.int32))
+        group_valid = jnp.arange(slots) < jnp.minimum(ngroups, slots)
+        starts_c = jnp.minimum(starts, cap - 1)
 
-    # segment start positions, compacted into the `slots` tile by a sort
-    # (scatter-free); ends follow by shifting, the last real group ending
-    # at nvalid
-    spos = jnp.where(boundary, jnp.arange(cap, dtype=jnp.int32), cap)
-    if slots > cap:
-        spos = jnp.concatenate(
-            [spos, jnp.full(slots - cap, cap, dtype=jnp.int32)]
+        # key output columns: component values at segment starts
+        at_starts = [op[starts_c] for op in sorted_ops]
+        out_cols = {}
+        for name, (ci, decode) in zip(key_names, key_at):
+            kv = (unpack_lex(at_starts, where, ci) == 0) & group_valid
+            kd = decode(lambda j, ci=ci: unpack_lex(at_starts, where, ci + j))
+            out_cols[name] = DevCol(jnp.where(group_valid, kd, jnp.zeros_like(kd)), kv)
+
+    # the stacked gather through the permutation, the cumulative sums
+    # cut at the segment ends, the segmented scans
+    with jax.named_scope("group"), jax.named_scope("reduce"):
+        out = _run_sorted_aggs(
+            batch, aggs, arg_cols, perm, valid_s, boundary,
+            starts_c, ends, group_valid, out_cols, reps=reps,
         )
-    starts = jax.lax.sort([spos], num_keys=1, is_stable=False)[0][:slots]
-    ends = jnp.minimum(
-        jnp.concatenate([starts[1:], jnp.full(1, cap, dtype=jnp.int32)]),
-        nvalid,
-    )
-    group_valid = jnp.arange(slots) < jnp.minimum(ngroups, slots)
-    starts_c = jnp.minimum(starts, cap - 1)
-
-    # key output columns: component values at segment starts
-    at_starts = [op[starts_c] for op in sorted_ops]
-    out_cols = {}
-    for name, (ci, decode) in zip(key_names, key_at):
-        kv = (unpack_lex(at_starts, where, ci) == 0) & group_valid
-        kd = decode(lambda j, ci=ci: unpack_lex(at_starts, where, ci + j))
-        out_cols[name] = DevCol(jnp.where(group_valid, kd, jnp.zeros_like(kd)), kv)
-
-    out = _run_sorted_aggs(
-        batch, aggs, arg_cols, perm, valid_s, boundary,
-        starts_c, ends, group_valid, out_cols, reps=reps,
-    )
+    _note_grouping(nvalid.astype(jnp.int64), ngroups, slots)
     return out, jnp.where(stale, jnp.int64(WIDTH_STALE), ngroups)

@@ -172,6 +172,8 @@ class QueryFlight:
         "est_rows", "act_rows", "spans", "served_s", "background",
         "exchanges", "exchange_rows", "exchange_bytes",
         "join_expansions", "join_expand_rows", "join_expand_slots",
+        "sorted_groupings", "sorted_group_rows", "sorted_groups",
+        "sorted_group_slots",
     )
 
     def __init__(self, qid: int, conn_id: int, sql: str):
@@ -210,6 +212,15 @@ class QueryFlight:
         self.join_expansions = 0
         self.join_expand_rows = 0
         self.join_expand_slots = 0
+        #: what the sorted group-bys (a packed key wider than the dense
+        #: domain: executor/sortops.py) of the programs this statement
+        #: ran did: group-bys executed, the valid rows that entered
+        #: them, the groups they found and their group tables' slots
+        #: (groups over slots is the tables' fill)
+        self.sorted_groupings = 0
+        self.sorted_group_rows = 0
+        self.sorted_groups = 0
+        self.sorted_group_slots = 0
         self.device_mem_peak_bytes = 0
         # XLA cost analysis summed over this statement's compiles
         # (obs/engine_watch.py per-signature harvest)
@@ -636,6 +647,16 @@ class FlightRecorder:
             rec.join_expand_rows += int(rows)
             rec.join_expand_slots += int(slots)
 
+    def note_groupings(self, count: int, rows: int, groups: int, slots: int) -> None:
+        """One executed program's sorted group-bys (planner/physical.py
+        reads them beside the program's cardinality scalars)."""
+        rec = self.current()
+        if rec is not None:
+            rec.sorted_groupings += int(count)
+            rec.sorted_group_rows += int(rows)
+            rec.sorted_groups += int(groups)
+            rec.sorted_group_slots += int(slots)
+
     def note_cardinality(self, est: float, act: float) -> None:
         """Planner-estimated vs observed output rows of a routed
         statement (AQE): feeds the statements_summary est/act
@@ -719,6 +740,10 @@ class FlightRecorder:
                 "join_expansions": r.join_expansions,
                 "join_expand_rows": r.join_expand_rows,
                 "join_expand_slots": r.join_expand_slots,
+                "sorted_groupings": r.sorted_groupings,
+                "sorted_group_rows": r.sorted_group_rows,
+                "sorted_groups": r.sorted_groups,
+                "sorted_group_slots": r.sorted_group_slots,
                 "device_mem_peak_bytes": r.device_mem_peak_bytes,
                 "compile_flops": r.compile_flops,
                 "compile_bytes_accessed": r.compile_bytes_accessed,

@@ -1873,6 +1873,7 @@ def build_select(
     # _try_push_agg) — before pruning so the narrowed sides prune harder
     plan = push_aggs_through_joins(plan, catalog)
     plan = sink_selections(plan)
+    plan = narrow_group_keys(plan, catalog)
     # column pruning over the finished tree (reference columnPruner)
     plan = prune_plan(plan, {c.internal for c in plan.schema.cols}, catalog)
     return plan
@@ -2071,6 +2072,112 @@ def push_aggs_through_joins(plan: LogicalPlan, catalog) -> LogicalPlan:
         if pushed is not None:
             return pushed
     return plan
+
+
+def _key_facts(plan: LogicalPlan, catalog, tables: list, equal: list) -> None:
+    """What `plan`'s rows say about which of its columns determine
+    which, gathered from the operators that keep such a dependency
+    true of every row they emit: a scan with a PRIMARY KEY (storage
+    keeps it unique and NOT NULL) adds (key internals, all its column
+    internals) to `tables`; an inner join adds its column-to-column
+    equi keys to `equal` and passes both sides on, a filter, an
+    additive projection and the probe side of a semi, anti, mark or
+    left join pass theirs on (a row of the side a left join extends
+    with NULLs is not one of that table's)."""
+    if isinstance(plan, Scan):
+        try:
+            pk = catalog.table(plan.db, plan.table).schema.primary_key
+        except Exception:
+            pk = None
+        if pk:
+            tables.append((
+                frozenset(f"{plan.alias}.{c}" for c in pk),
+                {c.internal for c in plan.schema.cols},
+            ))
+    elif isinstance(plan, Selection) or (
+        isinstance(plan, Projection) and plan.additive
+    ):
+        _key_facts(plan.child, catalog, tables, equal)
+    elif isinstance(plan, JoinPlan):
+        _key_facts(plan.left, catalog, tables, equal)
+        if plan.kind in ("inner", "cross"):
+            _key_facts(plan.right, catalog, tables, equal)
+            equal.extend(
+                (le.name, re_.name) for le, re_ in plan.equi_keys
+                if isinstance(le, ColumnRef) and isinstance(re_, ColumnRef)
+            )
+
+
+def narrow_group_keys(
+    plan: LogicalPlan, catalog, _feeds_agg: bool = False
+) -> LogicalPlan:
+    """A GROUP BY key that the other keys determine stops being a key:
+    it is read off the group's first row instead. TPC-H Q18 groups by
+    c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice; o_orderkey
+    is orders' primary key, so it determines that row's date, price and
+    o_custkey, which the join sets equal to customer's primary key, which
+    determines c_name: the groups are o_orderkey's. A sorted group-by
+    then sorts one key and not five (the v5e compiler's time for a sort
+    grows with the square of its key limbs, executor/sortops.py), and
+    every later operator sees the same columns. (Reference: the
+    functional dependencies behind only_full_group_by,
+    pkg/planner/funcdep; there they admit a select list, here they
+    also shorten the key.) An aggregate that feeds another one keeps
+    its keys: the stacked DISTINCT rewrite and the fragment planner
+    read the pair's keys against each other."""
+    feeds = isinstance(plan, Aggregate) or (
+        _feeds_agg and isinstance(plan, (Projection, Selection))
+    )
+    plan = _rebuild_children(
+        plan, lambda c: narrow_group_keys(c, catalog, feeds)
+    )
+    if (
+        not isinstance(plan, Aggregate)
+        or _feeds_agg
+        or catalog is None
+        or plan.gc_meta
+        or len(plan.group_exprs) < 2
+        or any(d for _n, _f, _a, d in plan.aggs)
+        or not all(isinstance(e, ColumnRef) for _n, e in plan.group_exprs)
+    ):
+        return plan
+    tables: list = []
+    equal: list = []
+    _key_facts(plan.child, catalog, tables, equal)
+    if not tables:
+        return plan
+
+    def determined(cols) -> set:
+        known = set(cols)
+        while True:
+            more = set()
+            for key, columns in tables:
+                if key <= known:
+                    more |= columns
+            for a, b in equal:
+                if a in known:
+                    more.add(b)
+                if b in known:
+                    more.add(a)
+            if more <= known:
+                return known
+            known |= more
+
+    kept = [e.name for _n, e in plan.group_exprs]
+    for name in list(kept):
+        rest = [k for k in kept if k != name]
+        if rest and name in determined(rest):
+            kept = rest
+    if len(kept) == len(plan.group_exprs):
+        return plan
+    return dataclasses.replace(
+        plan,
+        group_exprs=[(n, e) for n, e in plan.group_exprs if e.name in kept],
+        aggs=list(plan.aggs) + [
+            (n, "first", e, False)
+            for n, e in plan.group_exprs if e.name not in kept
+        ],
+    )
 
 
 def sink_selections(plan: LogicalPlan) -> LogicalPlan:

@@ -44,6 +44,7 @@ from tidb_tpu.executor import (
 )
 from tidb_tpu.executor.aggregate import WIDTH_STALE as _WIDTH_STALE
 from tidb_tpu.executor.join import expansion_ledger
+from tidb_tpu.executor.sortops import grouping_ledger
 from tidb_tpu.expression import compile_expr
 from tidb_tpu.expression.expr import ColumnRef, Expr
 from tidb_tpu.obs.engine_watch import ENGINE_WATCH, watched_jit
@@ -216,17 +217,18 @@ class CompiledQuery:
     # small tile buys nothing the node keeps this many slots
     floors: Dict[int, int] = dataclasses.field(default_factory=dict)
     # the output's first compaction tile, from the root's estimated
-    # rows: on a mesh always; on one device where first_caps is filled
+    # rows (default_caps holds the knobs' first tiles, from the
+    # estimates of theirs)
     first_out_cap: int = 0
-    # (one device) every knob's first tile, where each has one without
-    # a run: an expanding join's from the planner's estimate of its
-    # rows, the others' their defaults. Empty where some knob has none
-    # (a unique-build join's tile is its probe's, known at the first
-    # run): discovery finds the tiles then, as it always has
-    first_caps: Dict[int, int] = dataclasses.field(default_factory=dict)
     # plan signature for the engine watch: a second jit trace for the
     # same sig is a retrace (obs/engine_watch.py)
     sig: Optional[object] = None
+
+    @property
+    def first_caps(self) -> Dict[int, int]:
+        """Every knob's first tile: default_caps, under the name the
+        benchmark's tests read it by."""
+        return self.default_caps
 
 
 
@@ -931,6 +933,11 @@ def agg_out_dicts(plan: "L.Aggregate", dicts) -> Dicts:
             d = _expr_dict(arg, dicts)
             if d is not None:
                 out_dicts[name] = d
+            elif isinstance(arg, ColumnRef):
+                # one of the column's own values: its bounds hold
+                cb = dicts.get(_BOUNDS_PREFIX + arg.name)
+                if cb is not None:
+                    out_dicts[_BOUNDS_PREFIX + name] = cb
     return out_dicts
 
 
@@ -993,13 +1000,9 @@ class PlanCompiler:
         # sized nodes that are an expanding join's output tile; filled
         # while the program is traced (the executor picks the path)
         self.expand_nids: set = set()
-        # sized node -> its smallest tile (CompiledQuery.floors)
-        self.floors: Dict[int, int] = {}
-        # (one device) sized node -> a first tile known without a run
-        # (CompiledQuery.first_caps): an expanding join's from the
-        # estimate, a scalar aggregate's one group
-        self.first_tiles: Dict[int, int] = {}
-        self.expanding_joins = 0  # joins neither side of which is unique
+        # sized node -> its smallest tile (CompiledQuery.floors); the
+        # output's compaction tile has one like any join's
+        self.floors: Dict[int, int] = {_OUT_NODE: _FEW_ROWS}
 
     def fresh_id(self) -> int:
         self._next_id += 1
@@ -1019,20 +1022,14 @@ class PlanCompiler:
         return nid
 
     def _first_tile(self, plan: L.LogicalPlan, parts: int = 1) -> int:
-        """(mesh mode) A knob's first tile from the planner's estimate
-        of `plan`'s rows (exact table counts and ANALYZE's statistics,
+        """A knob's first tile from the planner's estimate of `plan`'s
+        rows (exact table counts and ANALYZE's statistics,
         planner/cardinality.py), a `parts`-th of them, with a quarter
         of room for the estimate's error and a partition's unevenness,
-        to the next tile. Where the estimates hold the first program a
-        mesh statement compiles is already its steady one
-        (PhysicalExecutor._run_pinned); a tile too small retries at the
-        exact need like any other. 0 off the mesh: one device starts
-        from the dominant input tile, as it always has."""
-        if not self.mesh_n:
-            return 0
-        return self._estimated_tile(plan, parts)
-
-    def _estimated_tile(self, plan: L.LogicalPlan, parts: int = 1) -> int:
+        to the next tile. Where the estimates hold, the first program a
+        statement compiles is already its steady one
+        (PhysicalExecutor._steady_first); a tile too small retries at
+        the exact need like any other."""
         from tidb_tpu.planner import cardinality as C
 
         return _cap_tile(int(1.25 * C.est_rows(plan, self.catalog)) // parts + 1)
@@ -1050,6 +1047,19 @@ class PlanCompiler:
         self.defaults[nid] = max(
             self._first_tile(side, self.mesh_n**2) for side in sides
         )
+        return nid
+
+    def _join_knob(self, plan: L.LogicalPlan, first: int = 0) -> int:
+        """The sized node of a join's output tile, starting at `first`
+        (0: at the probe's tile, known when the program is traced).
+        Like a sorted group table it keeps _FEW_ROWS slots however few
+        rows the data puts in it, so that no program is shaped by such
+        a count."""
+        nid = self.fresh_id()
+        self.sized.append(nid)
+        self.widths[nid] = _schema_width(plan.schema)
+        self.defaults[nid] = max(first, _FEW_ROWS) if first else 0
+        self.floors[nid] = _FEW_ROWS
         return nid
 
     def _gathered(self, fn, tag):
@@ -1144,12 +1154,6 @@ class PlanCompiler:
         # consumers (materialization, the RPC seam) expect name ->
         # dictionary only (all reserved prefixes start with NUL)
         out = {k: v for k, v in dicts.items() if not k.startswith("\x00")}
-        first_caps = {
-            nid: self.first_tiles.get(nid) or self.defaults[nid]
-            for nid in self.sized
-        }
-        if self.mesh_n or not self.expanding_joins or not all(first_caps.values()):
-            first_caps = {}
         return CompiledQuery(
             fn=fn,
             out_tag=self._tag,
@@ -1164,8 +1168,7 @@ class PlanCompiler:
             exchange_nids=frozenset(self.exchange_nids),
             expand_nids=self.expand_nids,
             floors=dict(self.floors),
-            first_out_cap=self._estimated_tile(plan) if first_caps else self._first_tile(plan),
-            first_caps=first_caps,
+            first_out_cap=self._first_tile(plan),
         )
 
     # ------------------------------------------------------------------
@@ -1551,19 +1554,18 @@ class PlanCompiler:
         child_tag = self._tag
         nid = self.fresh_id()
         self.sized.append(nid)
-        self.defaults[nid] = self._first_tile(plan) or 1024
+        self.defaults[nid] = self._first_tile(plan)
         self.widths[nid] = _schema_width(plan.schema)
         key_fns, key_names, key_widths, descs = build_agg_parts(
             plan, dicts, compiler=self
         )
         scalar = not plan.group_exprs
-        if scalar:
-            self.first_tiles[nid] = 16  # one group, whatever the data
-        elif not _dense_keys(key_widths):
+        if not scalar and not _dense_keys(key_widths):
             # sorted groups: the table is the output tile and nothing
             # else, so one of a few dozen slots is no cheaper than one
             # of _FEW_ROWS, and does not follow the group count
             self.floors[nid] = _FEW_ROWS
+            self.defaults[nid] = max(self.defaults[nid], _FEW_ROWS)
         agg_names = [(n, f) for n, f, _a, _d in plan.aggs]
         mesh_n = self.mesh_n if child_tag == "shard" else None
         post_fn = (
@@ -2017,10 +2019,7 @@ class PlanCompiler:
             if mesh:
                 # row-id re-join must see both sides whole: run replicated
                 _gather_both()
-            nid = self.fresh_id()
-            self.sized.append(nid)
-            self.widths[nid] = _schema_width(plan.schema)
-            self.defaults[nid] = 0
+            nid = self._join_knob(plan)
             lks_rks = verify
 
             def fn_semi_multi(inputs, caps):
@@ -2071,14 +2070,8 @@ class PlanCompiler:
             # conditions never filter the outer side).
             if mesh:
                 _gather_both()
-            nid = self.fresh_id()
-            self.sized.append(nid)
-            self.widths[nid] = _schema_width(plan.schema)
-            self.defaults[nid] = 0
-            nid2 = self.fresh_id()
-            self.sized.append(nid2)
-            self.widths[nid2] = _schema_width(plan.schema)
-            self.defaults[nid2] = 0
+            nid = self._join_knob(plan)
+            nid2 = self._join_knob(plan)
             lks_rks = verify
 
             def fn_left_multi(inputs, caps):
@@ -2156,21 +2149,14 @@ class PlanCompiler:
             else:
                 # rtag repl: build side already everywhere (broadcast join)
                 self._tag = ltag
-        nid = self.fresh_id()
-        self.sized.append(nid)
-        self.widths[nid] = _schema_width(plan.schema)
         # one device: resolved at first execution from the probe's tile;
         # a mesh: from the estimate, a shard's share where it is sharded
-        self.defaults[nid] = self._first_tile(
-            plan, self.mesh_n if mesh and self._tag == "shard" else 1
+        nid = self._join_knob(
+            plan,
+            self._first_tile(
+                plan, self.mesh_n if mesh and self._tag == "shard" else 1
+            ),
         )
-        if not mesh and kind == "inner" and not (lprops[1] or rprops[1]):
-            # neither side unique: the join expands, and its output has
-            # no tile of an input's to start from. The estimate's
-            # (n x m / NDV from ANALYZE's statistics) lets the first
-            # program be the steady one (PhysicalExecutor._steady_first)
-            self.first_tiles[nid] = self._estimated_tile(plan)
-            self.expanding_joins += 1
 
         def fn_join(inputs, caps):
             lb, n1 = left(inputs, caps)
@@ -2543,9 +2529,12 @@ class PhysicalExecutor:
             from tidb_tpu.expression.kernels import param_scope
 
             def prog(i, p, _f=fn, _c=frozen_caps):
-                with param_scope(p), expansion_ledger() as expanded:
+                with param_scope(p), expansion_ledger() as expanded, \
+                        grouping_ledger() as grouped:
                     b, needs = _f(i, _c)
-                return b, _with_expansions(needs, expanded)
+                return b, _with_groupings(
+                    _with_expansions(needs, expanded), grouped
+                )
 
             return prog
         from jax.sharding import PartitionSpec as P
@@ -2557,7 +2546,8 @@ class PhysicalExecutor:
         n = self.mesh_n
 
         def local(i, _f=fn, _c=frozen_caps):
-            with sent_ledger() as sent, expansion_ledger() as expanded:
+            with sent_ledger() as sent, expansion_ledger() as expanded, \
+                    grouping_ledger() as grouped:
                 b, needs = _f(i, _c)
             # pmax proves replication of the cardinality scalars to
             # shard_map AND takes the per-shard max for sizing knobs
@@ -2565,6 +2555,14 @@ class PhysicalExecutor:
             # a shard's expanding joins, summed over the shards
             needs = _with_expansions(
                 needs, [(jax.lax.psum(r, "d"), s * n) for r, s in expanded]
+            )
+            # and its sorted group-bys
+            needs = _with_groupings(
+                needs,
+                [
+                    (jax.lax.psum(r, "d"), jax.lax.psum(g, "d"), s * n)
+                    for r, g, s in grouped
+                ],
             )
             # what the program's exchanges sent, beside them: how many
             # there are, their rows and their cross-chip bytes
@@ -2655,14 +2653,16 @@ class PhysicalExecutor:
 
         failpoint.inject("executor/before-discover")
         caps = dict(cq.caps or cq.default_caps)
-        defaulted = []
+        # tiles no run has proven: all of them on a first discovery
+        # (the planner's estimates, and 0 for a join re-joined on a row
+        # id), none once an execution has left its caps
+        defaulted = [] if cq.caps else list(caps)
         for nid, c in caps.items():
-            if c == 0:  # join knobs start at the dominant input tile
+            if c == 0:  # such a join starts at the dominant input tile
                 d = _join_default(inputs, cq)
                 if jit and self.mesh_n:
                     d = _cap_tile(max(d // self.mesh_n, 1024))
                 caps[nid] = d
-                defaulted.append(nid)
         if self.quota_bytes and defaulted:
             # under a memory quota, DEFAULT tiles must not fail
             # admission on their own: start small enough to fit and let
@@ -2670,10 +2670,11 @@ class PhysicalExecutor:
             # necessary — every growth re-admits, so a genuinely
             # over-quota cardinality still errors with the tracker
             # report (reference: quota actions escalate before failing,
-            # pkg/util/memory/action.go). Only _join_default guesses are
-            # clamped — capacities a previous execution DISCOVERED are
-            # known-needed; re-clamping them would force a re-discovery
-            # launch on every run
+            # pkg/util/memory/action.go). Only guesses are clamped (the
+            # planner's estimates, _join_default) — capacities a
+            # previous execution DISCOVERED are known-needed;
+            # re-clamping them would force a re-discovery launch on
+            # every run
             share = max(int(self.quota_bytes) // (4 * len(caps)), 1)
             for nid in defaulted:
                 w = cq.widths.get(nid, 64)
@@ -2889,29 +2890,23 @@ class PhysicalExecutor:
 
     def _steady_first(self, cq: CompiledQuery, inputs, shape_key):
         """A plan's first execution where every knob has a first tile
-        without a run (a mesh plan's from the planner's estimates,
-        PlanCompiler._first_tile; on one device a plan whose joins all
-        expand, CompiledQuery.first_caps):
-        compile the STEADY program at them and run it, in the discover
-        program's place. It returns the knobs' true cardinalities like
-        any steady run. Nothing overflowed and no tile is more than
-        twice its tight one: that program is published as the steady
-        one and the statement has compiled one whole program, not two
-        (on the v5e host a mesh Q5's take 90 s each, PERF.md PR 29).
-        A looser tile: the tight tiles are known, the steady program is
-        compiled at them, and discovery is skipped all the same. An
-        overflow, which cuts short what lies downstream of it: the
-        discover loop, as before.
+        without a run, from the planner's estimates
+        (PlanCompiler._first_tile; a join re-joined on a row id has
+        none): compile the STEADY program at them and run it, in the
+        discover program's place. It returns the knobs' true
+        cardinalities like any steady run. Nothing overflowed and no
+        tile is more than twice its tight one: that program is
+        published as the steady one and the statement has compiled one
+        whole program, not two (on the v5e host a mesh Q5's take 90 s
+        each, PERF.md PR 29). A looser tile: the tight tiles are known,
+        the steady program is compiled at them, and discovery is
+        skipped all the same. An overflow, which cuts short what lies
+        downstream of it: the discover loop, as before.
 
         Returns (output, None, 0) when the first program was kept,
         (None, tight caps, tight output tile) when it ran clean and is
         to be tightened, (None, None, 0) when discovery has to run."""
-        if self.mesh is not None:
-            first = cq.default_caps
-        elif cq.first_caps:
-            first = cq.first_caps
-        else:
-            return None, None, 0
+        first = cq.default_caps
         first_out = cq.first_out_cap
         if cq.caps or not first_out or not all(first.values()):
             return None, None, 0
@@ -2924,9 +2919,25 @@ class PhysicalExecutor:
             "tiles, no discovery), overflowed (discovery ran)",
             labels=("outcome",),
         )
+        from tidb_tpu.utils import failpoint
+
+        # a plan's first program, in the discover program's place
+        failpoint.inject("executor/before-discover")
         if self.kill_check is not None:
             self.kill_check()
-        self._admit(cq, inputs, first)
+        else:
+            # worker-side executors: the thread's current killer, at
+            # the safepoint _discover gives them
+            from tidb_tpu.utils.sqlkiller import current_check
+
+            current_check()
+        try:
+            self._admit(cq, inputs, first)
+        except ExecError:
+            # estimated tiles must not fail admission on their own:
+            # discovery starts them small enough and grows them as the
+            # data proves (_discover)
+            return None, None, 0
         jitted, out, needs_host = self._steady_at(cq, first, first_out, inputs)
         full = {**first, _OUT_NODE: first_out}
         if _overflowed(needs_host, full):
@@ -3008,7 +3019,10 @@ class PhysicalExecutor:
             if caps is None:
                 out, caps = self._discover(cq, inputs)
                 nvalid = int(jax.device_get(_count_valid(out.row_valid)))
-                out_cap = min(_cap_tile(max(nvalid, 1)), out.capacity)
+                out_cap = min(
+                    max(_cap_tile(nvalid), cq.floors.get(_OUT_NODE, 0)),
+                    out.capacity,
+                )
             full_caps = dict(caps)
             full_caps[_OUT_NODE] = out_cap
             cq.caps = dict(full_caps)  # warm-start hint for _discover
@@ -3268,6 +3282,9 @@ _EXCHANGES, _EXCHANGE_ROWS, _EXCHANGE_BYTES = -2, -3, -4
 # and of what a program's expanding joins emitted: how many there are,
 # their true output rows, their output tiles' slots
 _EXPANSIONS, _EXPAND_ROWS, _EXPAND_SLOTS = -5, -6, -7
+# and of what its sorted group-bys did: how many there are, the valid
+# rows that entered them, the groups they found, their tables' slots
+_GROUPINGS, _GROUP_ROWS, _GROUPS, _GROUP_SLOTS = -8, -9, -10, -11
 
 
 def _expand_overflow_retries():
@@ -3293,12 +3310,26 @@ def _with_expansions(needs: dict, expanded: list) -> dict:
     return needs
 
 
+def _with_groupings(needs: dict, grouped: list) -> dict:
+    """The program's cardinality scalars with what its sorted group-bys
+    (executor/sortops.grouping_ledger: one (rows, groups, slots) a
+    group-by) did beside them: the same fetch brings both."""
+    if not grouped:
+        return needs
+    needs = dict(needs)
+    needs[_GROUPINGS] = jnp.int64(len(grouped))
+    needs[_GROUP_ROWS] = sum((r for r, _g, _s in grouped), jnp.int64(0))
+    needs[_GROUPS] = sum((g for _r, g, _s in grouped), jnp.int64(0))
+    needs[_GROUP_SLOTS] = jnp.int64(sum(s for _r, _g, s in grouped))
+    return needs
+
+
 def _take_program_stats(needs_host: dict) -> dict:
-    """Take what the exchanges sent and what the expanding joins
-    emitted out of a program's fetched scalars, onto the statement's
-    flight and the registry; what is left are the cardinalities of the
-    knobs."""
-    if _EXCHANGES not in needs_host and _EXPANSIONS not in needs_host:
+    """Take what the exchanges sent, what the expanding joins emitted
+    and what the sorted group-bys did out of a program's fetched
+    scalars, onto the statement's flight and the registry; what is
+    left are the cardinalities of the knobs."""
+    if not any(k in needs_host for k in (_EXCHANGES, _EXPANSIONS, _GROUPINGS)):
         return needs_host
     needs_host = dict(needs_host)
     from tidb_tpu.utils.metrics import REGISTRY
@@ -3331,6 +3362,17 @@ def _take_program_stats(needs_host: dict) -> dict:
             "(their true output, also where a tile overflowed)",
         ).inc(rows)
         FLIGHT.note_expansions(count, rows, slots)
+    if _GROUPINGS in needs_host:
+        count, rows, groups, slots = (
+            int(needs_host.pop(k))
+            for k in (_GROUPINGS, _GROUP_ROWS, _GROUPS, _GROUP_SLOTS)
+        )
+        REGISTRY.counter(
+            "tidbtpu_executor_sorted_group_rows_total",
+            "valid rows that entered the executed programs' sorted "
+            "group-bys",
+        ).inc(rows)
+        FLIGHT.note_groupings(count, rows, groups, slots)
     return needs_host
 
 
@@ -3524,8 +3566,13 @@ def _key_width(e: Expr, dicts: Dicts):
     static bound exists (enables the scatter-free packed aggregation
     path); None otherwise. Integer-typed plain columns take their width
     from the storage layer's value bounds (Table.col_bounds, riding the
-    dicts map) — these are exact at compile time and runtime-verified in
-    the kernel, so growth past them re-plans instead of mis-grouping."""
+    dicts map), widened to what two data sets of one scale share: the
+    lower bound down to a multiple of the power of two over the span,
+    as many bits as then hold the upper one (one more than the exact
+    span's at most). A program bakes the bias, so an exact one
+    (-min(o_totalprice)) made Q18 a program per data set (PERF.md
+    PR 35). The kernel verifies the widened bounds at run time, so
+    growth past them re-plans instead of mis-grouping."""
     kind = e.type.kind if e.type is not None else None
     if kind == Kind.STRING:
         d = _expr_dict(e, dicts)
@@ -3535,8 +3582,10 @@ def _key_width(e: Expr, dicts: Dicts):
     if isinstance(e, ColumnRef):
         cb = _resolve_bounds(dicts.get(_BOUNDS_PREFIX + e.name))
         if cb is not None:
-            lo, hi = cb
-            w = int(hi - lo + 1).bit_length()
+            lo, hi = int(cb[0]), int(cb[1])
+            step = (hi - lo + 1).bit_length()
+            lo = (lo >> step) << step
+            w = (hi - lo + 1).bit_length()
             if w <= 40:
                 return (w, -lo)
     if kind == Kind.DATE:

@@ -13,6 +13,7 @@ variants are the planned path for >HBM tables.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 from typing import Dict, List, Optional, Tuple
 
@@ -52,6 +53,30 @@ class ColumnStats:
         if self.ndv <= 0:
             return 1.0
         return 1.0 / self.ndv
+
+
+#: the smallest tile the statistics kernel runs on. A cold server
+#: compiled one kernel a (table size, storage dtype): 16 programs of
+#: about 10 s each on the v5e for TPC-H's eight tables, the largest
+#: single part of every first run's set-up (PERF.md section 7). Columns
+#: are widened to int64 and padded with invalid rows to the power of two
+#: at or over their rows, at least this many: three programs at SF1.
+_STATS_FLOOR = 1 << 18
+
+
+@functools.partial(jax.jit, static_argnums=3)
+def _padded(data, valid, row_valid, cap: int):
+    if not jnp.issubdtype(data.dtype, jnp.floating):
+        data = data.astype(jnp.int64)
+    pad = ((0, cap - data.shape[0]),)
+    return jnp.pad(data, pad), jnp.pad(valid, pad), jnp.pad(row_valid, pad)
+
+
+def _stats_input(data, valid, row_valid):
+    """The kernel's arguments in the few shapes it is compiled for."""
+    n = data.shape[0]
+    cap = max(_STATS_FLOOR, 1 << max(n - 1, 0).bit_length())
+    return _padded(data, valid, row_valid, cap)
 
 
 @jax.jit
@@ -168,17 +193,17 @@ def _analyze_at_version(table, version, columns, stats):
             )
             dicts = {name: pinned_dict} if pinned_dict is not None else {}
             nulls, count, ndv, bounds, bcounts, topf, top_vals, mn, mx, f1 = (
-                _column_stats_kernel(
-                    jnp.asarray(data_h),
-                    jnp.asarray(valid_h),
-                    jnp.ones(len(data_h), dtype=bool),
-                )
+                _column_stats_kernel(*_stats_input(
+                    data_h, valid_h, np.ones(len(data_h), dtype=bool)
+                ))
             )
         else:
             batch, dicts = scan_table(table, [name], version=version)
             col = batch.cols[name]
             nulls, count, ndv, bounds, bcounts, topf, top_vals, mn, mx, f1 = (
-                _column_stats_kernel(col.data, col.valid, batch.row_valid)
+                _column_stats_kernel(
+                    *_stats_input(col.data, col.valid, batch.row_valid)
+                )
             )
         count_i = int(count)
         dictionary = dicts.get(name)
