@@ -46,6 +46,8 @@ import sys
 #: tidbtpu_shuffle_filter_bytes, tidbtpu_shuffle_filter_dropped_rows_total
 #: (parallel/shuffle.py) and the tidbtpu_shuffle_filter_selectivity
 #: histogram (parallel/dcn.py — observed keep-rate per filtered stage).
+#: planner = the logical planner's own decisions (PR 36,
+#: planner/logical.py — where the join order put an IN's semi join).
 SUBSYSTEMS = frozenset({
     "admission",
     "aqe",
@@ -57,6 +59,7 @@ SUBSYSTEMS = frozenset({
     "flight",
     "inspection",
     "link",
+    "planner",
     "session",
     "shuffle",
     "stats",

@@ -126,6 +126,27 @@ def test_mesh_server_answers_as_the_reference_and_as_one_device(bench, mesh, one
                 assert g == s, (name, kind)
 
 
+@pytest.mark.parametrize("quantity", [300, 250])
+def test_a_q18_on_the_mesh_server_answers_as_one_device(bench, mesh, one, quantity):
+    """Q18's semi join sits inside the join order (PR 36): the mesh
+    repartitions or gathers its sides where it stands. This population
+    has no order over 300 (both servers answer nothing); 68 are over 250."""
+    sql = bench.harness.Statement("q18").sql
+    assert "> 300" in sql
+    sql = sql.replace("> 300", f"> {quantity}")
+    plan = [row[0] for row in mesh[1].query("explain " + sql)]
+    semi = [i for i, line in enumerate(plan) if "JoinPlan kind=semi" in line]
+    inner = [i for i, line in enumerate(plan) if "JoinPlan kind=inner" in line]
+    assert len(semi) == 1 and inner[0] < semi[0] < inner[1], plan
+    got, single = mesh[1].query(sql), one[1].query(sql)
+    assert got == single
+    data = mesh[0].data
+    sums = np.bincount(data.col("lineitem", "l_orderkey"), weights=data.col("lineitem", "l_quantity"))
+    orders = np.nonzero(sums > quantity * 100)[0]
+    assert sorted(int(row[2]) for row in got) == sorted(orders.tolist())
+    assert (len(orders) > 0) == (quantity == 250)
+
+
 @pytest.mark.parametrize("name", STATEMENTS)
 def test_the_float32_reference_fails_on_the_mesh_server_s_data(bench, mesh, name):
     st = bench.statements[name]

@@ -1668,6 +1668,9 @@ def build_select(
         plan = OneRow(Schema([]))
     else:
         plan = b.build_from(sel.from_)
+    # `*` lists the FROM clause's columns in its order, whatever order
+    # the WHERE's join reordering leaves them in
+    from_order = {c.internal: i for i, c in enumerate(plan.schema)}
 
     # ---- WHERE ----
     if sel.where is not None and not isinstance(plan, OneRow):
@@ -1718,7 +1721,9 @@ def build_select(
     items: List[ast.SelectItem] = []
     for it in sel.items:
         if isinstance(it.expr, ast.Star):
-            for c in plan.schema:
+            for c in sorted(
+                plan.schema, key=lambda c: from_order.get(c.internal, len(from_order))
+            ):
                 if c.name == ROWID_NAME:
                     continue  # DML row handles are never star-visible
                 if it.expr.table is None or (c.qualifier or "").lower() == it.expr.table.lower():
@@ -2470,7 +2475,9 @@ def _apply_where(b, plan, where, subquery_value_fn, catalog, db):
     through cross-join elimination (ppdSolver + joinReOrderSolver):
     single-relation conjuncts sink onto their relation, eq-conjuncts
     linking two relations of a comma-join become inner-join keys, the
-    rest filter on top."""
+    rest filter on top. An uncorrelated IN over ONE relation of a
+    comma-join is a reducing edge on it (`_in_reducers`): the join
+    order places it by its estimate, beside the inner joins."""
     plain: List = []
     subq: List = []
     corr_scalar: List = []
@@ -2488,13 +2495,70 @@ def _apply_where(b, plan, where, subquery_value_fn, catalog, db):
             corr_scalar.append(c)
         else:
             plain.append(c)
-    if plain:
-        plan = _reorder_joins(plan, plain, subquery_value_fn, catalog)
+
+    def semijoin(cur, sq, inner=None):
+        return _subquery_semijoin(b, cur, sq, subquery_value_fn, catalog, db, inner)
+
+    reducers = _in_reducers(b, plan, subq, subquery_value_fn, catalog, db)
+    if plain or reducers:
+        plan = _reorder_joins(
+            plan, plain, subquery_value_fn, catalog, reducers, semijoin
+        )
+    reducer_of = {id(r.sq): r for r in reducers}
     for c in subq:
-        plan = _subquery_semijoin(b, plan, c, subquery_value_fn, catalog, db)
+        r = reducer_of.get(id(c))
+        if r is None:
+            plan = semijoin(plan, c)
+        elif not r.placed:
+            plan = semijoin(plan, c, r.inner)
+    if reducers:
+        from tidb_tpu.utils.metrics import REGISTRY
+
+        placements = REGISTRY.counter(
+            "tidbtpu_planner_semi_join_placements_total",
+            "uncorrelated IN subqueries over one relation of a "
+            "comma-join, by where the join order put their semi join: "
+            "early (before a relation was joined: its estimate was under "
+            "every join's) or last (over the finished join tree)",
+            labels=("placed",),
+        )
+        for r in reducers:
+            placements.labels(placed="early" if r.placed else "last").inc()
     for c in corr_scalar:
         plan = _decorrelate_scalar(b, plan, c, subquery_value_fn, catalog, db)
     return plan
+
+
+@dataclasses.dataclass
+class _Reducer:
+    """An uncorrelated `IN (subquery)` whose left side reads ONE
+    relation of a comma-join: a semi join `_reorder_joins` may place as
+    soon as that relation is joined."""
+
+    sq: ast.SubqueryExpr
+    rel: int  # index into _flatten_cross(plan)
+    inner: LogicalPlan  # the subquery's plan, built once
+    placed: bool = False  # inside the join order, not over it
+
+
+def _in_reducers(b, plan, subq, subquery_value_fn, catalog, db) -> List[_Reducer]:
+    rels = _flatten_cross(plan)
+    out: List[_Reducer] = []
+    if len(rels) == 1:
+        return out
+    for c in subq:
+        if c.modifier != "in" or _is_correlated(c.query, plan.schema, b):
+            continue
+        sides = c.lhs.items if isinstance(c.lhs, ast.RowExpr) else [c.lhs]
+        rs: Optional[set] = set()
+        for side in sides:
+            got = _rels_of(side, rels)
+            rs = None if rs is None or got is None else rs | got
+        if rs is None or len(rs) != 1:
+            continue
+        inner = build_query(c.query, catalog, db, subquery_value_fn, b.ctes)
+        out.append(_Reducer(c, next(iter(rs)), inner))
+    return out
 
 
 def _flatten_cross(p: LogicalPlan) -> List[LogicalPlan]:
@@ -2539,7 +2603,12 @@ def _broadcast_choice(est_left: float, est_right: float) -> Optional[str]:
     return None
 
 
-def _reorder_joins(plan, conjuncts, subquery_value_fn, catalog=None) -> LogicalPlan:
+def _reorder_joins(
+    plan, conjuncts, subquery_value_fn, catalog=None, reducers=(), semijoin=None
+) -> LogicalPlan:
+    """`reducers` (`_Reducer`s, with `semijoin(cur, sq, inner)` to build
+    their node) are placed where their estimate says; one left unplaced
+    is the caller's to put over the result."""
     rels = _flatten_cross(plan)
     if len(rels) == 1:
         binder = ExprBinder(plan.schema, _scalar_subq(subquery_value_fn))
@@ -2590,6 +2659,18 @@ def _reorder_joins(plan, conjuncts, subquery_value_fn, catalog=None) -> LogicalP
         rel_est[i] = (
             C.est_rows(r, catalog, smap) if catalog is not None else 1000.0
         )
+    # a reducer's build side and the statistics its key is read by: the
+    # subquery's own columns beside the relations' (which keep theirs)
+    reducer_est: List[Tuple[float, object]] = []
+    for rd in reducers:
+        rmap = C.StatsMap()
+        if catalog is not None:
+            rmap.cols.update(C.gather_stats(rd.inner, catalog).cols)
+        rmap.cols.update(smap.cols)
+        reducer_est.append((
+            C.est_rows(rd.inner, catalog) if catalog is not None else 1000.0,
+            rmap,
+        ))
 
     start = min(range(len(rels)), key=lambda i: (rel_est[i], i))
     joined = {start}
@@ -2604,15 +2685,6 @@ def _reorder_joins(plan, conjuncts, subquery_value_fn, catalog=None) -> LogicalP
                 candidates.setdefault(rj, []).append((ei, ej))
             elif rj in joined and ri in remaining:
                 candidates.setdefault(ri, []).append((ej, ei))
-        if not candidates:
-            nxt = min(remaining, key=lambda i: (rel_est[i], i))
-            r = rels[nxt]
-            schema = Schema(list(cur.schema.cols) + list(r.schema.cols))
-            cur = JoinPlan(schema, "cross", cur, r, [], None)
-            cur_est = cur_est * rel_est[nxt]
-            joined.add(nxt)
-            remaining.discard(nxt)
-            continue
         # bind each candidate's keys and estimate its join size; pick min
         bound: Dict[int, List[Tuple[Expr, Expr]]] = {}
         cand_est: Dict[int, float] = {}
@@ -2622,15 +2694,35 @@ def _reorder_joins(plan, conjuncts, subquery_value_fn, catalog=None) -> LogicalP
             keys = [(lb.bind(ei), rb.bind(ej)) for ei, ej in pairs]
             bound[k] = keys
             cand_est[k] = C.est_join(cur_est, rel_est[k], keys, "inner", smap)
-        nxt = min(
-            candidates,
-            key=lambda k: (cand_est[k], -len(candidates[k]), k),
-        )
+        if candidates:
+            nxt = min(
+                candidates,
+                key=lambda k: (cand_est[k], -len(candidates[k]), k),
+            )
+            kind, keys = "inner", bound[nxt]
+            bcast = _broadcast_choice(cur_est, rel_est[nxt])
+        else:
+            nxt = min(remaining, key=lambda i: (rel_est[i], i))
+            cand_est[nxt] = cur_est * rel_est[nxt]
+            kind, keys, bcast = "cross", [], None
+        # a reducer on a joined relation is one more edge: taken when it
+        # is estimated to leave strictly fewer rows than every join
+        # would (a tie goes to the join)
+        best = None
+        for rd, (inner_est, rmap) in zip(reducers, reducer_est):
+            if rd.placed or rd.rel not in joined:
+                continue
+            node = semijoin(cur, rd.sq, rd.inner)
+            est = C.est_join(cur_est, inner_est, node.equi_keys, "semi", rmap)
+            if est < cand_est[nxt] and (best is None or est < best[0]):
+                best = (est, rd, node)
+        if best is not None:
+            cur_est, rd, cur = best
+            rd.placed = True
+            continue
         r = rels[nxt]
-        keys = bound[nxt]
         schema = Schema(list(cur.schema.cols) + list(r.schema.cols))
-        bcast = _broadcast_choice(cur_est, rel_est[nxt])
-        cur = JoinPlan(schema, "inner", cur, r, keys, None, broadcast=bcast)
+        cur = JoinPlan(schema, kind, cur, r, keys, None, broadcast=bcast)
         cur_est = cand_est[nxt]
         joined.add(nxt)
         remaining.discard(nxt)
@@ -2975,9 +3067,12 @@ def _make_mark(b, plan, sq: ast.SubqueryExpr, subquery_value_fn, catalog, db, co
     return plan, maybe_not(ast.Name(None, mark))
 
 
-def _subquery_semijoin(b, plan, sq: ast.SubqueryExpr, subquery_value_fn, catalog, db):
+def _subquery_semijoin(
+    b, plan, sq: ast.SubqueryExpr, subquery_value_fn, catalog, db, inner=None
+):
     """IN/EXISTS (correlated or not) -> semi/anti join (reference:
-    decorrelation + semi-join rewrite in expression_rewriter.go)."""
+    decorrelation + semi-join rewrite in expression_rewriter.go).
+    `inner`: an uncorrelated IN's subquery, already planned."""
     q = sq.query
     correlated = _is_correlated(q, plan.schema, b)
 
@@ -3061,7 +3156,8 @@ def _subquery_semijoin(b, plan, sq: ast.SubqueryExpr, subquery_value_fn, catalog
         )
     else:
         residuals, extra = [], []
-    inner = build_query(inner_q, catalog, db, subquery_value_fn, b.ctes)
+    if inner is None:
+        inner = build_query(inner_q, catalog, db, subquery_value_fn, b.ctes)
     ob = ExprBinder(plan.schema, _scalar_subq(subquery_value_fn))
     kind = "semi" if sq.modifier == "in" else "anti"
     if isinstance(sq.lhs, ast.RowExpr):
