@@ -31,13 +31,29 @@ the devices that ran an op: the chips of a mesh run one program):
 
     python scripts/trace_by_scope.py <file.xplane.pb | dir> [--top 4]
 
-PERF.md section 5 pastes its output; ROADMAP S0 queues the
-``benchmark`` PR that moves the sum into ``benchmarks/trace_reduce.py``.
+``--idle`` prints instead where the device waits: every device-idle
+interval inside the traced window (the benchmark's ``bench/`` spans,
+else the device ops' extent) is cut at the edges of the ``tidbtpu/``
+annotations it overlaps, and each piece goes to the innermost program
+span that covers it, by overlap and not by midpoint: a collector pass
+(``gc/gen2``) before any statement span, a piece under a statement's
+root and no deeper span to ``<root>/self``, a piece under no statement
+span to ``between statements``. Milliseconds a statement by span path,
+their total (the trace's idle), and a bound on how far the host and
+device clocks disagree: the most negative (first device op of a module)
+less (the start of the ``launch`` span nearest it).
+
+    python scripts/trace_by_scope.py <file.xplane.pb | dir> --idle
+
+PERF.md section 5 pastes its output; ROADMAP B9 queues the
+``benchmark`` PR that moves the sum and the apportioning into
+``benchmarks/trace_reduce.py``.
 """
 
 from __future__ import annotations
 
 import argparse
+import bisect
 import collections
 import glob
 import os
@@ -123,29 +139,159 @@ def self_times(ops):
     return out
 
 
+BETWEEN = "between statements"
+GC = "gc/gen2"
+
+
+def _label(active) -> str:
+    """The label of a moment covered by the annotations ``active``
+    ((path, start, end, qid)): a collector pass, else the deepest
+    statement span (the latest opened of equal depth), else none."""
+    if any(path == GC for path, *_rest in active):
+        return GC
+    spans = [n for n in active if n[3]]
+    if not spans:
+        return BETWEEN
+    path = max(spans, key=lambda n: (n[0].count("/"), n[1]))[0]
+    return path if "/" in path else path + "/self"
+
+
+def labelled_segments(notes) -> list:
+    """[(start, end, label)] laid end to end from the first annotation
+    edge to the last, cut at every edge: each segment lies wholly
+    inside or outside each annotation."""
+    order = sorted(notes, key=lambda n: n[1])
+    points = sorted({t for n in notes for t in (n[1], n[2])})
+    out, active, i = [], [], 0
+    for a, b in zip(points, points[1:]):
+        while i < len(order) and order[i][1] <= a:
+            active.append(order[i])
+            i += 1
+        active = [n for n in active if n[2] > a]
+        out.append((a, b, _label(active)))
+    return out
+
+
+def apportion(idle, notes) -> dict:
+    """{label: ns} of the idle intervals ``idle`` ((start, end), sorted,
+    disjoint), each cut by the annotations ``notes`` ((path, start, end,
+    qid); qid 0 for an annotation of no statement, ``gc/gen2`` for a
+    collector pass) it overlaps and every piece given to the innermost
+    span covering it. The values sum to the idle."""
+    segments = labelled_segments(notes)
+    out = collections.Counter()
+    j = 0
+    for x, y in idle:
+        while j < len(segments) and segments[j][1] <= x:
+            j += 1
+        covered, k = 0.0, j
+        while k < len(segments) and segments[k][0] < y:
+            a, b, label = segments[k]
+            piece = min(b, y) - max(a, x)
+            if piece > 0:
+                out[label] += piece
+                covered += piece
+            k += 1
+        if y - x - covered > 0:
+            out[BETWEEN] += y - x - covered
+    return dict(out)
+
+
+def idle_of(ops, lo: float, hi: float) -> list:
+    """The gaps between the union of ``ops`` ((name, start, end, ...))
+    inside [lo, hi]."""
+    gaps, edge = [], lo
+    for _n, start, end, *_rest in sorted(ops, key=lambda o: o[1]):
+        if end <= edge:
+            continue
+        if start >= hi:
+            break
+        if start > edge:
+            gaps.append((edge, start))
+        edge = max(edge, end)
+    if edge < hi:
+        gaps.append((edge, hi))
+    return gaps
+
+
+def idle_report(by_device, modules_by_device, notes, bench) -> None:
+    """``--idle``: see the module docstring."""
+    ops = [op for dev_ops in by_device.values() for op in dev_ops]
+    if bench:
+        lo, hi = min(b[1] for b in bench), max(b[2] for b in bench)
+    else:
+        lo, hi = min(o[1] for o in ops), max(o[2] for o in ops)
+    program = [
+        (name[len("tidbtpu/"):], start, end, int(st.get("qid") or 0))
+        for name, start, end, st in notes
+    ]
+    n_dev = len(by_device)
+    table = collections.Counter()
+    for dev_ops in by_device.values():
+        table.update(apportion(idle_of(dev_ops, lo, hi), program))
+    idle = sum(table.values()) / n_dev
+    roots = [n for n in program if n[3] and "/" not in n[0] and lo <= n[1] < hi]
+    stmts = max(len(roots), 1)
+    busy = (hi - lo) - idle
+    print(f"window {(hi - lo) / 1e9:.4f} s on {n_dev} device(s), {len(roots)} statements; "
+          f"busy {busy / 1e9:.4f} s, idle {idle / 1e9:.4f} s "
+          f"({100 * idle / (hi - lo):.2f} %): {idle / stmts / 1e6:.3f} ms a statement")
+    print("idle by the innermost program span (ms a statement, share of the idle):")
+    for label, ns in table.most_common():
+        ns /= n_dev
+        print(f"  {ns / stmts / 1e6:9.3f}  {100 * ns / idle:5.1f} %  {label}")
+    launches = sorted(n[1] for n in program if n[0].endswith("/dispatch/launch"))
+    leads = []
+    for dev, mods in modules_by_device.items():
+        starts = sorted(o[1] for o in by_device.get(dev, ()))
+        for _n, m_start, m_end, *_rest in mods:
+            if not launches or not lo <= m_start < hi:
+                continue
+            i = bisect.bisect_left(starts, m_start)
+            first = starts[i] if i < len(starts) and starts[i] < m_end else m_start
+            k = bisect.bisect_left(launches, first)
+            near = min(launches[max(k - 1, 0):k + 1], key=lambda t: abs(first - t))
+            leads.append(first - near)
+    if leads:
+        leads.sort()
+        print(f"clock bound: (first device op of a module) less (its launch span's start) over "
+              f"{len(leads)} module(s): least {leads[0] / 1e6:.3f} ms, median "
+              f"{leads[len(leads) // 2] / 1e6:.3f} ms (a negative least bounds the clocks' "
+              f"disagreement from below)")
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("trace")
     ap.add_argument("--top", type=int, default=4, help="ops shown per scope")
     ap.add_argument("--tolerance-ms", type=float, default=0.6)
+    ap.add_argument("--idle", action="store_true",
+                    help="apportion the device's idle over the program's spans")
     args = ap.parse_args(argv)
     space = load(args.trace)
 
-    by_device, modules, notes = collections.defaultdict(list), [], []
+    by_device, modules_by_device = collections.defaultdict(list), collections.defaultdict(list)
+    notes, bench = [], []
     for plane in space.planes:
         for line in plane.lines:
             if plane.name.startswith("/device:TPU") and line.name == "XLA Ops":
                 by_device[plane.name] += list(events(plane, line))
             elif plane.name.startswith("/device:TPU") and line.name == "XLA Modules":
-                modules += list(events(plane, line))
+                modules_by_device[plane.name] += list(events(plane, line))
             elif plane.name.startswith("/host:"):
-                notes += [
-                    e for e in events(plane, line) if e[0].startswith("tidbtpu/")
-                ]
+                for e in events(plane, line):
+                    if e[0].startswith("tidbtpu/"):
+                        notes.append(e)
+                    elif e[0].startswith("bench/"):
+                        bench.append(e)
+    modules = [m for mods in modules_by_device.values() for m in mods]
     ops = [op for dev_ops in by_device.values() for op in dev_ops]
     if not ops:
         print("no device op in the trace")
         return 1
+    if args.idle:
+        idle_report(by_device, modules_by_device, notes, bench)
+        return 0
     lo, hi = min(o[1] for o in ops), max(o[2] for o in ops)
     n_dev = len(by_device)
     print(f"device ops {len(ops)} on {n_dev} device(s) over {(hi - lo) / 1e9:.4f} s; modules:",

@@ -103,8 +103,12 @@ class TestSpanTree:
         spans = _by_path(flight)
         assert set(spans) == {
             "stmt", "stmt/session", "stmt/session/parse",
+            "stmt/session/resolve",
             "stmt/session/plan", "stmt/session/execute",
+            "stmt/session/execute/plan-cache",
             "stmt/session/execute/inputs", "stmt/session/execute/dispatch",
+            "stmt/session/execute/dispatch/launch",
+            "stmt/session/execute/dispatch/enqueue-fetch",
             "stmt/session/execute/device-wait", "stmt/session/execute/fetch",
             "stmt/session/final-merge", "stmt/session/observe",
             "stmt/wire/write",
@@ -141,8 +145,43 @@ class TestSpanTree:
             )
             _assert_additive(flight)
         assert cold["jit_compilations"] >= 1 and "compile" in cold["phases"]
-        assert any(p.endswith("/dispatch/compile") for p in _by_path(cold))
+        assert any(p.endswith("/dispatch/launch/compile") for p in _by_path(cold))
         assert "compile" not in warm["phases"]
+
+    def test_dispatch_is_the_launch_then_the_queued_copies(self, served):
+        """``dispatch`` holds two spans one after the other: ``launch``
+        (the jitted call) and ``enqueue-fetch`` (the copies queued
+        behind the program); ``dispatch_ms`` keeps its meaning."""
+        query, _srv = served
+        query(JOIN_AGG)
+        query(JOIN_AGG)
+        spans = _by_path(FLIGHT.rows()[-1])
+        dispatch = spans["stmt/session/execute/dispatch"]
+        launch = spans["stmt/session/execute/dispatch/launch"]
+        queued = spans["stmt/session/execute/dispatch/enqueue-fetch"]
+        assert launch["start_s"] + launch["seconds"] <= queued["start_s"]
+        assert launch["seconds"] + queued["seconds"] <= dispatch["seconds"]
+        # the statement's own Python has names: resolving and the plan
+        # cache, each a leaf where it belongs
+        for path in ("stmt/session/resolve", "stmt/session/execute/plan-cache"):
+            assert not any(p.startswith(path + "/") for p in spans), path
+        assert spans["stmt/session/resolve"]["start_s"] < spans["stmt/session/plan"]["start_s"]
+        cache = spans["stmt/session/execute/plan-cache"]
+        assert cache["start_s"] + cache["seconds"] <= dispatch["start_s"]
+
+    def test_a_nested_statement_resolves_nothing(self):
+        """TRACE's inner statement runs nested: its rows start at
+        ``plan``, as the outer statement's ``resolve`` is the top
+        statement's."""
+        sess = Session(Catalog())
+        _load(sess.execute)
+        sess.execute(JOIN_AGG)
+        rows = sess.execute("trace " + JOIN_AGG).rows
+        names = [row[0].strip() for row in rows]
+        assert "resolve" not in names
+        assert names[0] == "plan" and {"plan-cache", "launch"} <= set(names)
+        spans = _by_path(FLIGHT.rows()[-1])
+        assert "session/resolve" in spans
 
     def test_session_is_the_root_without_a_server(self):
         sess = Session(Catalog())
@@ -240,6 +279,59 @@ class TestSpanTree:
         assert getattr(f._tls, "trip", None) is None
 
 
+class TestCollector:
+    """Python's collector on every flight (obs/flight.py ``_on_gc``):
+    a pass inside a statement's root span shows in its ``gc_s`` and
+    ``gc_passes``; a statement with none reads 0."""
+
+    def test_a_pass_inside_the_root_is_on_the_flight(self, served):
+        import gc
+
+        from tidb_tpu.utils import failpoint
+
+        query, _srv = served
+        query(JOIN_AGG)
+        gc.disable()  # no pass but the one the hook forces
+        try:
+            failpoint.enable("server/dispatch-query", lambda: gc.collect())
+            try:
+                query(JOIN_AGG)
+            finally:
+                failpoint.disable("server/dispatch-query")
+            collected = FLIGHT.rows()[-1]
+            query(JOIN_AGG)
+            quiet = FLIGHT.rows()[-1]
+        finally:
+            gc.enable()
+        assert collected["sql"] == quiet["sql"] == JOIN_AGG
+        assert collected["gc_passes"][2] >= 1 and collected["gc_s"] > 0
+        assert collected["gc_s"] < collected["served_s"]
+        assert quiet["gc_s"] == 0.0 and quiet["gc_passes"] == [0, 0, 0]
+
+    def test_one_hook_counts_every_generation(self):
+        import gc
+
+        from tidb_tpu.obs import flight
+
+        assert gc.callbacks.count(flight._on_gc) == 1
+        before = list(flight._GC)
+        gc.collect(0)
+        gc.collect(1)
+        gc.collect(2)
+        after = flight._GC
+        assert [after[i] - before[i] for i in (1, 2, 3)] == [1, 1, 1]
+        assert after[0] > before[0]
+
+    def test_a_flight_reads_no_pass_until_its_root_closes(self):
+        f = FlightRecorder()
+        with f.span("session"):
+            rec = f.begin("select 1")
+            f.finish(0.0)
+            assert rec.gc_s == 0.0 and rec.gc_passes == [0, 0, 0]
+        assert f.rows()[-1]["gc_s"] >= 0.0
+        assert len(f.rows()[-1]["gc_passes"]) == 3
+
+
 class TestProfilerClock:
     def test_annotations_match_the_flight_spans(self, served, tmp_path):
         """(a) under jax.profiler the served statement leaves
@@ -259,6 +351,9 @@ class TestProfilerClock:
         try:
             query(JOIN_AGG)
             query(JOIN_AGG)
+            import gc
+
+            gc.collect()  # a pass of generation 2, on this thread
         finally:
             jax.profiler.stop_trace()
         flights = [f for f in FLIGHT.rows() if f["sql"] == JOIN_AGG][-2:]
@@ -267,15 +362,18 @@ class TestProfilerClock:
         )
         assert found
         data = ProfileData.from_file(found[-1])
-        notes, launches = {}, []  # (qid, path) -> (line, start, end)
+        notes, launches, passes = {}, [], []  # (qid, path) -> (line, start, end)
         for plane in data.planes:
             if not plane.name.startswith("/host:"):
                 continue
             for line in plane.lines:
                 for e in line.events:
                     start, end = e.start_ns, e.start_ns + e.duration_ns
-                    if e.name.startswith("tidbtpu/"):
-                        qid = dict(e.stats)["qid"]
+                    stats = dict(e.stats)
+                    if e.name == "tidbtpu/gc/gen2":
+                        passes.append((start, end))
+                    if e.name.startswith("tidbtpu/") and "qid" in stats:
+                        qid = stats["qid"]
                         notes[(qid, e.name[len("tidbtpu/"):])] = (
                             line.name, start, end
                         )
@@ -302,6 +400,7 @@ class TestProfilerClock:
                 if parent is not None:
                     _l, p_start, p_end = notes[(qid, parent)]
                     assert p_start <= start and end <= p_end, path
+        assert passes and all(end > start for start, end in passes)
         # every launch of the statement's program lies inside a dispatch
         steady = [x for x in launches if x[1] == "PjitFunction(steady)"]
         executes = [x for x in launches if x[1].endswith("Executable::Execute")]
@@ -474,7 +573,7 @@ class TestBackground:
 
 
 def test_span_call_is_cheap_without_a_profiler():
-    """Thirteen spans a statement: bounded loosely here (a shared CPU);
+    """Nineteen spans a statement: bounded loosely here (a shared CPU);
     PERF.md holds the measured cost."""
     f = FlightRecorder()
     n = 2000

@@ -36,6 +36,13 @@ profiler session the statement's spans lie on the device trace's clock.
 ``FLIGHT.background(name)`` does the same for one tick of a background
 loop; a finishing flight copies the ticks that ran beside it.
 
+Python's collector is observed on every flight: one ``gc.callbacks``
+hook, installed at import, adds each pass's seconds and a count of its
+generation to process-wide totals (a pass of generation 2 also enters
+the annotation ``tidbtpu/gc/gen2``), and a statement's root span keeps
+the totals as they stood when it opened: at its close the flight gains
+``gc_s`` and ``gc_passes``, the passes of any thread while it was open.
+
 Phase and span names are DECLARED registries (``PHASES``, ``SPANS``),
 the failpoint-SITES pattern: ``note_phase`` and ``span`` reject
 undeclared names at runtime and scripts/check_flight_phases.py
@@ -55,6 +62,7 @@ per fleet.
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import threading
 import time
@@ -97,10 +105,13 @@ _PHASE_SET = frozenset(PHASES)
 
 #: every span ``FLIGHT.span`` may open. A served statement nests them as
 #: stmt (server.py: command packet read -> answer written) > session
-#: (Session.execute) > parse | plan | execute > compile | inputs |
-#: dispatch | device-wait | fetch, then final-merge and observe under
-#: session and wire/write under stmt. A span named like a phase charges
-#: that phase; the others cut time the phases lump together or leave out.
+#: (Session.execute) > parse | resolve | plan | execute, execute >
+#: plan-cache | inputs | dispatch | device-wait | fetch,
+#: dispatch > launch (the jitted call; a first call compiles inside it)
+#: | enqueue-fetch (the device->host copies queued behind the program),
+#: then final-merge and observe under session and wire/write under
+#: stmt. A span named like a phase charges that phase; the others cut
+#: time the phases lump together or leave out.
 SPANS = (
     "stmt",
     "session",
@@ -115,6 +126,10 @@ SPANS = (
     "final-merge",
     "observe",
     "wire/write",
+    "launch",
+    "enqueue-fetch",
+    "resolve",
+    "plan-cache",
 )
 
 _SPAN_SET = frozenset(SPANS)
@@ -129,11 +144,33 @@ _ROOTS = frozenset(("stmt", "session"))
 #: background ticks remembered for the flights that finish after them
 _TICK_RING = 512
 
+#: Python's collector since the process started: seconds of every pass
+#: on any thread, then passes of generation 0, 1 and 2 (``_on_gc``)
+_GC = [0.0, 0, 0, 0]
+#: the open pass: its start on perf_counter, its annotation (gen 2)
+_GC_OPEN = [0.0, None]
 
-def _c_queries():
-    return REGISTRY.counter(
-        "tidbtpu_flight_queries_total", "statements the flight recorder closed"
-    )
+
+def _on_gc(phase: str, info: dict, _clock=time.perf_counter) -> None:
+    """The ``gc.callbacks`` hook: two clock reads and two additions a
+    pass. The collector runs one pass at a time under the GIL, so the
+    start and stop of a pass pair up without a lock."""
+    if phase == "start":
+        if info["generation"] == 2:
+            _GC_OPEN[1] = TraceAnnotation("tidbtpu/gc/gen2")
+            _GC_OPEN[1].__enter__()
+        _GC_OPEN[0] = _clock()
+        return
+    _GC[0] += _clock() - _GC_OPEN[0]
+    _GC[1 + info["generation"]] += 1
+    ann = _GC_OPEN[1]
+    if ann is not None:
+        _GC_OPEN[1] = None
+        ann.__exit__(None, None, None)
+
+
+if _on_gc not in gc.callbacks:
+    gc.callbacks.append(_on_gc)
 
 
 def _c_phase_seconds():
@@ -142,6 +179,18 @@ def _c_phase_seconds():
         "cumulative seconds charged per flight phase",
         labels=("phase",),
     )
+
+
+#: the phase counter's series of each phase, looked up once: a span
+#: named like a phase charges it at every close
+_PHASE_SERIES: Dict[str, object] = {}
+
+
+def _phase_series(name: str):
+    series = _PHASE_SERIES.get(name)
+    if series is None:
+        series = _PHASE_SERIES[name] = _c_phase_seconds().labels(phase=name)
+    return series
 
 
 def _c_slow_captures():
@@ -173,7 +222,7 @@ class QueryFlight:
         "exchanges", "exchange_rows", "exchange_bytes",
         "join_expansions", "join_expand_rows", "join_expand_slots",
         "sorted_groupings", "sorted_group_rows", "sorted_groups",
-        "sorted_group_slots",
+        "sorted_group_slots", "gc_s", "gc_passes",
     )
 
     def __init__(self, qid: int, conn_id: int, sql: str):
@@ -252,6 +301,11 @@ class QueryFlight:
         #: [name, overlap seconds] of the background ticks that ran
         #: between the root span's start and this flight's finish
         self.background: List[list] = []
+        #: Python's collector between the root span's open and close,
+        #: on any thread: seconds, and passes of generation 0, 1, 2
+        #: (set when the root closes, as served_s is)
+        self.gc_s = 0.0
+        self.gc_passes = [0, 0, 0]
 
     def phase_row(self, name: str) -> list:
         row = self.phases.get(name)
@@ -280,12 +334,15 @@ class _Trip:
     both: nothing is copied at ``begin`` or after ``finish``."""
 
     __slots__ = (
-        "qid", "t0", "stack", "rows", "flight", "tracer", "tracer_depth",
+        "qid", "t0", "gc0", "stack", "rows", "flight", "tracer",
+        "tracer_depth",
     )
 
     def __init__(self, qid: int, flight: Optional[QueryFlight]):
         self.qid = qid
         self.t0 = 0.0
+        #: the collector's totals (``_GC``) when the root span opened
+        self.gc0 = (0.0, 0, 0, 0)
         self.stack: List["_Span"] = []
         #: the flight that began inside this trip (or was open when it
         #: started), and the list every closing span is appended to
@@ -334,6 +391,7 @@ class _Span:
         self._t0 = time.perf_counter()
         if not stack:
             trip.t0 = self._t0
+            trip.gc0 = tuple(_GC)
         stack.append(self)
         return self
 
@@ -360,8 +418,12 @@ class _Span:
                 name, self._t0, seconds, len(stack) + 1 - trip.tracer_depth
             )
         if not stack:
-            if trip.flight is not None:
-                trip.flight.served_s = seconds
+            rec = trip.flight
+            if rec is not None:
+                rec.served_s = seconds
+                gc0 = trip.gc0
+                rec.gc_s = _GC[0] - gc0[0]
+                rec.gc_passes = [_GC[i] - gc0[i] for i in (1, 2, 3)]
             fr._tls.trip = None
 
 
@@ -466,7 +528,6 @@ class FlightRecorder:
         rec.background = self._ticks_beside(
             trip.t0 if trip is not None else t1 - rec.duration_s, t1
         )
-        _c_queries().inc()
         _h_query_seconds().observe(rec.duration_s)
         with self._lock:
             self._recent.append(rec)
@@ -575,7 +636,7 @@ class FlightRecorder:
                 f"undeclared flight phase {name!r} (declare it in "
                 "tidb_tpu/obs/flight.py PHASES)"
             )
-        _c_phase_seconds().labels(phase=name).inc(max(float(seconds), 0.0))
+        _phase_series(name).inc(max(float(seconds), 0.0))
         rec = self.current()
         if rec is not None:
             self._charge(rec, name, seconds, nbytes, retries)
@@ -755,6 +816,8 @@ class FlightRecorder:
                 ],
                 "served_s": r.served_s,
                 "background": [list(b) for b in r.background],
+                "gc_s": r.gc_s,
+                "gc_passes": list(r.gc_passes),
             }
             for r in recs
         ]

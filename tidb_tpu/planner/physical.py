@@ -2706,8 +2706,10 @@ class PhysicalExecutor:
                 fn = cq.fn
                 jitted = lambda i, _p, _f=fn, _c=frozen: _f(i, _c)
             with FLIGHT.span("dispatch"):
-                out, needs = jitted(inputs, self._params())
-                _enqueue_fetch(needs)
+                with FLIGHT.span("launch"):
+                    out, needs = jitted(inputs, self._params())
+                with FLIGHT.span("enqueue-fetch"):
+                    _enqueue_fetch(needs)
             with FLIGHT.span("device-wait"):
                 jax.block_until_ready((needs, out))
             with FLIGHT.span("fetch"):
@@ -2785,55 +2787,58 @@ class PhysicalExecutor:
                     if parted is not None:
                         return parted
 
-                key = self._cache_key(plan)
-                cq = None if conservative else self._cache.get(key)
-                shareable = not conservative and _plan_shareable(plan)
-                claimed = False
-                if cq is None and shareable:
-                    # cross-session tier: another session/connection may
-                    # already have compiled this exact plan shape (the
-                    # serving-tier reuse — one compile serves the
-                    # fleet). A miss CLAIMS the key (singleflight):
-                    # publish or abandon below, or waiters stall
-                    cq = SHARED_PLAN_CACHE.get(
-                        self.mesh_n, key, owner=id(self)
-                    )
-                    claimed = cq is None
+                # the compiled plan for this shape: the key, the
+                # session's cache, the shared one, or a new compile
+                with FLIGHT.span("plan-cache"):
+                    key = self._cache_key(plan)
+                    cq = None if conservative else self._cache.get(key)
+                    shareable = not conservative and _plan_shareable(plan)
+                    claimed = False
+                    if cq is None and shareable:
+                        # cross-session tier: another session/connection may
+                        # already have compiled this exact plan shape (the
+                        # serving-tier reuse — one compile serves the
+                        # fleet). A miss CLAIMS the key (singleflight):
+                        # publish or abandon below, or waiters stall
+                        cq = SHARED_PLAN_CACHE.get(
+                            self.mesh_n, key, owner=id(self)
+                        )
+                        claimed = cq is None
+                        if cq is not None:
+                            # imported entries honor the same LRU bound as
+                            # compiles, or cross-session hits would grow
+                            # the private cache without limit
+                            while len(self._cache) >= 256:
+                                self._cache.popitem(last=False)
+                            self._cache[key] = cq
+                    # flight recorder: plan-cache outcome + plan digest for
+                    # the statements_summary attribution (obs/flight.py)
+                    FLIGHT.note_plan_cache(cq is not None, key=key)
                     if cq is not None:
-                        # imported entries honor the same LRU bound as
-                        # compiles, or cross-session hits would grow
-                        # the private cache without limit
+                        self._cache.move_to_end(key)
+                        REGISTRY.counter("tidbtpu_executor_plan_cache_hits_total").inc()
+                    else:
+                        REGISTRY.counter("tidbtpu_executor_plan_cache_misses_total").inc()
+                        try:
+                            compiler = PlanCompiler(
+                                self.catalog, resolver=self._resolve,
+                                mesh_n=self.mesh_n, conservative=conservative,
+                            )
+                            cq = compiler.compile(plan)
+                            cq.sig = self.watch_sig(key)
+                        except BaseException:
+                            if claimed:
+                                SHARED_PLAN_CACHE.abandon(
+                                    self.mesh_n, key, id(self)
+                                )
+                            raise
                         while len(self._cache) >= 256:
                             self._cache.popitem(last=False)
                         self._cache[key] = cq
-                # flight recorder: plan-cache outcome + plan digest for
-                # the statements_summary attribution (obs/flight.py)
-                FLIGHT.note_plan_cache(cq is not None, key=key)
-                if cq is not None:
-                    self._cache.move_to_end(key)
-                    REGISTRY.counter("tidbtpu_executor_plan_cache_hits_total").inc()
-                else:
-                    REGISTRY.counter("tidbtpu_executor_plan_cache_misses_total").inc()
-                    try:
-                        compiler = PlanCompiler(
-                            self.catalog, resolver=self._resolve,
-                            mesh_n=self.mesh_n, conservative=conservative,
-                        )
-                        cq = compiler.compile(plan)
-                        cq.sig = self.watch_sig(key)
-                    except BaseException:
-                        if claimed:
-                            SHARED_PLAN_CACHE.abandon(
-                                self.mesh_n, key, id(self)
+                        if shareable:
+                            SHARED_PLAN_CACHE.put(
+                                self.mesh_n, key, cq, owner=id(self)
                             )
-                        raise
-                    while len(self._cache) >= 256:
-                        self._cache.popitem(last=False)
-                    self._cache[key] = cq
-                    if shareable:
-                        SHARED_PLAN_CACHE.put(
-                            self.mesh_n, key, cq, owner=id(self)
-                        )
 
                 pins = []
                 try:
@@ -3412,16 +3417,19 @@ def _enqueue_fetch(tree) -> None:
 
 def _launch_and_fetch(jitted, inputs, params):
     """One launch, one fetch, each boundary its own span: ``dispatch``
-    until the jitted call returns (the enqueue, of the program and of
-    the copies of what it will produce; a first call traces and
-    compiles inside it), ``device-wait`` until the program has
+    until the program and the copies of what it will produce are
+    enqueued, in two parts: ``launch``, the jitted call (a first call
+    traces and compiles inside it), and ``enqueue-fetch``, the copies
+    queued behind it; ``device-wait`` until the program has
     finished, ``fetch`` for the ONE device->host round trip of output
     batch + cardinality scalars together, which also warms each
     array's host-value cache so the session's materialization re-reads
     are free. Returns (out, needs on the host)."""
     with FLIGHT.span("dispatch"):
-        out, needs = jitted(inputs, params)
-        _enqueue_fetch((needs, out))
+        with FLIGHT.span("launch"):
+            out, needs = jitted(inputs, params)
+        with FLIGHT.span("enqueue-fetch"):
+            _enqueue_fetch((needs, out))
     with FLIGHT.span("device-wait"):
         jax.block_until_ready((needs, out))
     with FLIGHT.span("fetch"):

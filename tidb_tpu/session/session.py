@@ -1917,7 +1917,11 @@ class Session(DDLMixin):
             return tuple(self._resolve_session_funcs(x) for x in node)
         return node
 
-    def _execute_stmt_inner(self, s, t0) -> Result:
+    def _resolve_stmt(self, s):
+        """What every statement does before it runs: the killer's
+        deadline, the privilege check, AS OF, the session functions
+        folded to constants, the executor's memory and streaming knobs.
+        Returns the statement as it will run."""
         from tidb_tpu.utils import failpoint
 
         try:
@@ -1975,6 +1979,18 @@ class Session(DDLMixin):
             ) or None
         except Exception:
             pass
+        return s
+
+    def _execute_stmt_inner(self, s, t0) -> Result:
+        from tidb_tpu.utils import failpoint
+
+        if self._stmt_depth == 1:
+            from tidb_tpu.obs.flight import FLIGHT
+
+            with FLIGHT.span("resolve"):
+                s = self._resolve_stmt(s)
+        else:
+            s = self._resolve_stmt(s)
         if isinstance(s, (ast.Select, ast.Union, ast.With, ast.SetOp)):
             # SELECT ... INTO OUTFILE (reference: SelectIntoExec,
             # pkg/executor/select_into.go). The clause parses on the
