@@ -393,22 +393,26 @@ def test_flight_and_registry_count_the_expansions_of_one_q95(served):
     tables, session = served[0]
     session.execute(Q95)  # steady by now: one program a statement
     before = {n: counter(n) for n in (
-        "tidbtpu_executor_join_expansions_total", "tidbtpu_executor_join_expand_rows_total",
-        "tidbtpu_executor_join_expand_overflow_retries_total")}
+        "tidbtpu_executor_join_expansions_total", "tidbtpu_executor_join_expand_rows_total")}
     session.execute(Q95)
     flight = FLIGHT.rows()[-1]
-    order = tables["web_sales"]["ws_order_number"].data
-    self_join = int((np.bincount(order).astype(np.int64) ** 2).sum())
-    assert flight["join_expansions"] == 2
-    assert self_join < flight["join_expand_rows"] < 2.2 * self_join
+    # the one expansion left: the self-join of distinct (order, warehouse)
+    # rows (a NULL warehouse one of them), shared by both INs
+    ws = tables["web_sales"]
+    warehouse = ws["ws_warehouse_sk"]
+    pairs = np.unique(np.stack([
+        ws["ws_order_number"].data, np.where(warehouse.valid, warehouse.data + 1, 0)]), axis=1)
+    self_join = int((np.bincount(pairs[0]).astype(np.int64) ** 2).sum())
+    assert flight["join_expansions"] == 1
+    assert flight["join_expand_rows"] == self_join
     assert flight["join_expand_rows"] <= flight["join_expand_slots"] < 4 * flight["join_expand_rows"]
     assert counter("tidbtpu_executor_join_expand_rows_total") - before[
         "tidbtpu_executor_join_expand_rows_total"] == flight["join_expand_rows"]
     # counted while a program is traced: the steady statement traces none
     assert counter("tidbtpu_executor_join_expansions_total") == before[
-        "tidbtpu_executor_join_expansions_total"] >= 2
-    # the first program, at the estimated tiles, overflowed at this size
-    assert before["tidbtpu_executor_join_expand_overflow_retries_total"] >= 1
+        "tidbtpu_executor_join_expansions_total"] >= 1
+    # the first program, at the estimated tiles, was kept as the steady one
+    assert counter('tidbtpu_executor_steady_first_total{outcome="kept"}') >= 1
 
 
 def test_two_seeds_share_one_program():
@@ -444,9 +448,10 @@ def test_two_seeds_share_one_program():
             session.execute(Q95)
     finally:
         physical.watched_jit = real
-    # the published program, compiled last. (At this size the first one, at
-    # the estimated tiles, overflows, and its 65,536-row tile takes the dense
-    # join path, which still bakes a build side's exact bounds: ROADMAP M1.)
+    # the published program, compiled last. (The second IN's build is the
+    # distinct returned orders, a tile small enough for the dense semi join,
+    # which bakes the bounds it is handed: a distinct hands it its key's
+    # bounds on the grid two data sets share, not their own: ROADMAP M1.)
     assert hashes[101][-1] == hashes[202][-1]
 
 

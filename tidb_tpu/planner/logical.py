@@ -2556,7 +2556,9 @@ def _in_reducers(b, plan, subq, subquery_value_fn, catalog, db) -> List[_Reducer
             rs = None if rs is None or got is None else rs | got
         if rs is None or len(rs) != 1:
             continue
-        inner = build_query(c.query, catalog, db, subquery_value_fn, b.ctes)
+        inner = _set_build(
+            build_query(c.query, catalog, db, subquery_value_fn, b.ctes), catalog
+        )
         out.append(_Reducer(c, next(iter(rs)), inner))
     return out
 
@@ -3067,6 +3069,148 @@ def _make_mark(b, plan, sq: ast.SubqueryExpr, subquery_value_fn, catalog, db, co
     return plan, maybe_not(ast.Name(None, mark))
 
 
+# -- set context: a semi or anti join reads its build side as a set ---------
+
+
+def _identity_equal(t: Optional[SQLType]) -> bool:
+    """Whether two values of type `t` that SQL calls equal are the same
+    value, so that keeping one row of each distinct tuple hides nothing
+    a reader could tell apart: not FLOAT/DOUBLE (-0.0 = 0.0), not a
+    string under a collation other than binary ('a' = 'A ')."""
+    if t is None or t.kind in (Kind.FLOAT, Kind.NULL):
+        return False
+    if t.kind == Kind.STRING:
+        from tidb_tpu.utils import collate
+
+        return collate.is_binary(t.collation)
+    return True
+
+
+def _distinct_on(plan: LogicalPlan, need: set, catalog) -> Optional[Aggregate]:
+    """`plan` made distinct on its `need` columns (the node SELECT
+    DISTINCT plans to, keys named as their columns, so that nothing
+    above is renamed), or None where that is not sound or not needed:
+    a column whose equality is not identity, or `plan` already unique
+    on them."""
+    cols = [c for c in plan.schema.cols if c.internal in need]
+    if (
+        not cols  # no keys: one row even from no rows
+        or not all(_identity_equal(c.type) for c in cols)
+        or _key_unique_on(plan, need, catalog)
+    ):
+        return None
+    keys = [(c.internal, ColumnRef(type=c.type, name=c.internal)) for c in cols]
+    return Aggregate(Schema(cols), plan, keys, [])
+
+
+def _set_dedupe(plan: LogicalPlan, needed: set, catalog, smap, inserted: list) -> LogicalPlan:
+    """`plan` as a reader that sees only the SET of its `needed`
+    columns' value tuples would have it: duplicates cannot change what
+    such a reader answers, so an inner join under it that the estimates
+    say expands (`cardinality.est_join` over its inputs' estimates above
+    the larger of them) joins each input made distinct on the columns
+    read of it above (`_distinct_on`; each new node is appended to
+    `inserted`). The context passes down through a Selection (plus its
+    predicate's columns), a Projection (the columns its items read; an
+    additive one passes the rest through), an Aggregate with keys and
+    no functions (its keys' columns) and both inputs of an inner join
+    (what is read of that side, its key and residual columns); it stops
+    anywhere else: an aggregate function reads multiplicity, and Limit,
+    windows, outer, semi and anti joins and unions are left alone.
+    (Reference: TiDB plans an IN subquery as an inner join over an
+    aggregate of its side, tidb_opt_insubq_to_join_and_agg, and pushes
+    aggregates below joins, rule_aggregation_push_down.go.)"""
+    from tidb_tpu.expression.expr import walk_columns
+    from tidb_tpu.planner import cardinality as C
+
+    def down(child, need):
+        return _set_dedupe(child, need, catalog, smap, inserted)
+
+    if isinstance(plan, Selection):
+        child = down(plan.child, needed | walk_columns(plan.predicate))
+        if child is plan.child:
+            return plan
+        return Selection(child.schema, child, plan.predicate)
+    if isinstance(plan, Projection):
+        produced = {n for n, _e in plan.exprs}
+        need = {n for n in needed if n not in produced}
+        for _n, e in plan.exprs:
+            need |= walk_columns(e)
+        child = down(plan.child, need)
+        if child is plan.child:
+            return plan
+        schema = plan.schema
+        if plan.additive:
+            kept = {c.internal for c in child.schema.cols} | produced
+            schema = Schema([c for c in plan.schema.cols if c.internal in kept])
+        return Projection(schema, child, plan.exprs, plan.additive)
+    if isinstance(plan, Aggregate) and plan.group_exprs and not plan.aggs and not plan.gc_meta:
+        need = set()
+        for _n, e in plan.group_exprs:
+            need |= walk_columns(e)
+        child = down(plan.child, need)
+        return plan if child is plan.child else dataclasses.replace(plan, child=child)
+    if not (
+        isinstance(plan, JoinPlan)
+        and plan.kind == "inner"
+        and plan.equi_keys
+        and not plan.null_aware
+        and plan.mark_name is None
+    ):
+        return plan
+    lnames = {c.internal for c in plan.left.schema.cols}
+    rnames = {c.internal for c in plan.right.schema.cols}
+    read = set(needed)
+    if plan.residual is not None:
+        read |= walk_columns(plan.residual)
+    lneed, rneed = read & lnames, read & rnames
+    for le, re_ in plan.equi_keys:
+        lneed |= walk_columns(le)
+        rneed |= walk_columns(re_)
+    nl = C.est_rows(plan.left, catalog, smap)
+    nr = C.est_rows(plan.right, catalog, smap)
+    expands = C.est_join(nl, nr, plan.equi_keys, "inner", smap) > max(nl, nr)
+    sides = []
+    for side, need in ((plan.left, lneed), (plan.right, rneed)):
+        side = down(side, need)
+        distinct = _distinct_on(side, need, catalog) if expands else None
+        if distinct is not None:
+            inserted.append(distinct)
+            side = distinct
+        sides.append(side)
+    left, right = sides
+    if left is plan.left and right is plan.right:
+        return plan
+    return dataclasses.replace(
+        plan, schema=Schema(list(left.schema.cols) + list(right.schema.cols)),
+        left=left, right=right,
+    )
+
+
+def _set_build(inner: LogicalPlan, catalog) -> LogicalPlan:
+    """A semi or anti join's build plan, planned once: its rows are read
+    as a set of its columns (the join's keys, the correlation columns,
+    the residual's), so `_set_dedupe` may make the expanding joins under
+    it join distinct rows. Counts each distinct it put in."""
+    if catalog is None:
+        return inner
+    from tidb_tpu.planner import cardinality as C
+    from tidb_tpu.utils.metrics import REGISTRY
+
+    inserted: list = []
+    out = _set_dedupe(
+        inner, {c.internal for c in inner.schema.cols}, catalog,
+        C.gather_stats(inner, catalog), inserted,
+    )
+    REGISTRY.counter(
+        "tidbtpu_planner_set_dedupes_total",
+        "distincts the planner put under a semi or anti join's build "
+        "side, on an input of an inner join there that its estimates "
+        "say expands (planned statements; a cached plan counts nothing)",
+    ).inc(len(inserted))
+    return out
+
+
 def _subquery_semijoin(
     b, plan, sq: ast.SubqueryExpr, subquery_value_fn, catalog, db, inner=None
 ):
@@ -3115,7 +3259,9 @@ def _subquery_semijoin(
             where=kept,
             distinct=False,
         )
-        inner = build_query(inner_q, catalog, db, subquery_value_fn, b.ctes)
+        inner = _set_build(
+            build_query(inner_q, catalog, db, subquery_value_fn, b.ctes), catalog
+        )
         ob = ExprBinder(plan.schema, _scalar_subq(subquery_value_fn))
         keys = _bind_corr_keys(ob, corr_pairs, inner.schema.cols)
         res = _bind_residuals(plan.schema, inner.schema, residuals, subquery_value_fn)
@@ -3157,7 +3303,9 @@ def _subquery_semijoin(
     else:
         residuals, extra = [], []
     if inner is None:
-        inner = build_query(inner_q, catalog, db, subquery_value_fn, b.ctes)
+        inner = _set_build(
+            build_query(inner_q, catalog, db, subquery_value_fn, b.ctes), catalog
+        )
     ob = ExprBinder(plan.schema, _scalar_subq(subquery_value_fn))
     kind = "semi" if sq.modifier == "in" else "anti"
     if isinstance(sq.lhs, ast.RowExpr):

@@ -122,10 +122,37 @@ class _LazyBounds:
         return self.table.col_bounds(self.col, self.version)
 
 
+class _GridBounds:
+    """A key's bounds as a DISTINCT publishes them: on `_grid`, not the
+    data set's own extremes. Which values of a column a distinct keeps
+    (the returned orders of a set of sales) is the data set's, and a
+    dense join above it bakes the bounds it is handed, so exact ones
+    would make a program per data set (ROADMAP M1). Resolved lazily,
+    like the storage bounds it widens."""
+
+    __slots__ = ("entry",)
+
+    def __init__(self, entry):
+        self.entry = entry
+
+    def get(self):
+        cb = _resolve_bounds(self.entry)
+        return None if cb is None else _grid(int(cb[0]), int(cb[1]))
+
+
+def _grid(lo: int, hi: int) -> Tuple[int, int]:
+    """[lo, hi] widened to what two data sets of one scale share: the
+    lower bound down to a multiple of the power of two over the span,
+    the upper one to the last value as many bits then hold."""
+    step = (hi - lo + 1).bit_length()
+    lo = (lo >> step) << step
+    return lo, lo + (1 << (hi - lo + 1).bit_length()) - 1
+
+
 def _resolve_bounds(entry):
     if entry is None:
         return None
-    if isinstance(entry, _LazyBounds):
+    if isinstance(entry, (_LazyBounds, _GridBounds)):
         return entry.get()
     return entry
 
@@ -922,7 +949,12 @@ def agg_out_dicts(plan: "L.Aggregate", dicts) -> Dicts:
         if isinstance(e, ColumnRef):
             cb = dicts.get(_BOUNDS_PREFIX + e.name)
             if cb is not None:
-                out_dicts[_BOUNDS_PREFIX + kname] = cb
+                # a distinct's keys on the shared grid; an aggregate with
+                # functions keeps the column's own (Q18's semi join reads
+                # them: its program is left as it was)
+                out_dicts[_BOUNDS_PREFIX + kname] = (
+                    cb if plan.aggs else _GridBounds(cb)
+                )
     if len(plan.group_exprs) == 1:
         # a single GROUP BY key is unique in the aggregate's output
         out_dicts[_UNIQ_PREFIX + plan.group_exprs[0][0]] = True
@@ -1917,10 +1949,16 @@ class PlanCompiler:
                     if null_aware and kind == "anti":
                         bk = rkey(rb)
                         has_null = jnp.any(~bk.valid & rb.row_valid)
+                        # NULL NOT IN (an empty set) is true
+                        has_rows = jnp.any(rb.row_valid)
                         if mesh and build_sharded:
                             has_null = jax.lax.pmax(has_null, "d")
+                            has_rows = jax.lax.pmax(has_rows, "d")
                         pk = lkey(out)
-                        out = Batch(out.cols, out.row_valid & ~has_null & pk.valid)
+                        out = Batch(
+                            out.cols,
+                            out.row_valid & ~has_null & (pk.valid | ~has_rows),
+                        )
                     return out, needs
 
                 return fn_semi, {**ldicts}
@@ -3590,10 +3628,8 @@ def _key_width(e: Expr, dicts: Dicts):
     if isinstance(e, ColumnRef):
         cb = _resolve_bounds(dicts.get(_BOUNDS_PREFIX + e.name))
         if cb is not None:
-            lo, hi = int(cb[0]), int(cb[1])
-            step = (hi - lo + 1).bit_length()
-            lo = (lo >> step) << step
-            w = (hi - lo + 1).bit_length()
+            lo, hi = _grid(int(cb[0]), int(cb[1]))
+            w = (hi - lo).bit_length()
             if w <= 40:
                 return (w, -lo)
     if kind == Kind.DATE:
