@@ -17,12 +17,16 @@ factor) for each seed, under the labels `q95:<seed>`: the proof that
 one program serves every data set (PR 33). Two seeds' programs are the
 same where their lists are equal. `--q18 <sf> <seed> [<seed> ...]` does
 the same for TPC-H Q18 over `tpch_sf1`'s population, under `q18:<seed>`,
-and `--q51 <sf> <seed> [<seed> ...]` for TPC-DS Q51 over
-`tpcds_sf1_q51`'s population, under `q51:<seed>`."""
+`--q51 <sf> <seed> [<seed> ...]` for TPC-DS Q51 over
+`tpcds_sf1_q51`'s population, under `q51:<seed>`, and `--q21 <sf>
+<seed> [<seed> ...]` for TPC-H Q21 over `tpch_sf1`'s population, under
+`q21:<seed>`."""
 import hashlib, json, os, sys
 
 repo = os.path.abspath(sys.argv[1])
-PER_SEED = sys.argv[2][2:] if sys.argv[2:3] in (["--q95"], ["--q18"], ["--q51"]) else None
+PER_SEED = (
+    sys.argv[2][2:] if sys.argv[2:3] in (["--q95"], ["--q18"], ["--q51"], ["--q21"]) else None
+)
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 os.chdir(repo)

@@ -20,7 +20,9 @@ the devices that ran an op: the chips of a mesh run one program):
   ``/compact``, ``/lookup`` and ``/expand/search | gather``,
   executor/join.py; a sorted aggregate's ``/group/sort | reduce``,
   executor/sortops.py; a window's ``/window/sort | scan``,
-  executor/window.py; on a mesh an exchange's
+  executor/window.py; a semi or anti join with a residual's
+  ``/residual/expand | filter | rejoin``, planner/physical.py; on a
+  mesh an exchange's
   ``/exchange/sort | pack | all-to-all`` and ``/broadcast/all-gather``,
   parallel/exchange.py), and who owns the custom
   fusions (``hlo_category`` "custom fusion": XLA's scatter-shaped
@@ -76,6 +78,11 @@ SUB_SCOPES = (
     # a window operator: the sort, the boundaries and the inverse
     # permutation; the scans and the gather back (executor/window.py)
     "window", "window/sort", "window/scan",
+    # a semi or anti join with a residual: the build's sort and the
+    # expansion of (probe row, build row) pairs, the residual over the
+    # pairs, the semi or anti join back on the probe's row id
+    # (planner/physical.py)
+    "residual", "residual/expand", "residual/filter", "residual/rejoin",
     # a repartition and its stages, a broadcast (parallel/exchange.py)
     "exchange", "exchange/sort", "exchange/pack", "exchange/all-to-all",
     "broadcast/all-gather",

@@ -79,6 +79,40 @@ def _note_expansion(total: jax.Array, slots: int) -> None:
         ledger.append((total, int(slots)))
 
 
+@contextlib.contextmanager
+def residual_ledger():
+    """What the semi and anti joins with a residual (a correlated
+    EXISTS or NOT EXISTS: planner/physical.py) traced inside tested:
+    one (pairs, kept) a join, the scalars of the valid pairs the
+    residual was evaluated on and of those it passed. Opened and
+    returned as expansion_ledger is."""
+    prev = getattr(_TRACING, "residuals", None)
+    _TRACING.residuals = out = []
+    try:
+        yield out
+    finally:
+        _TRACING.residuals = prev
+        if prev is not None:
+            prev.extend(out)
+
+
+def note_residual(kind: str, pairs: jax.Array, kept: jax.Array) -> None:
+    """Count one traced semi or anti join with a residual (once per
+    join per traced program) and put its pairs on the open ledger."""
+    from tidb_tpu.utils.metrics import REGISTRY
+
+    REGISTRY.counter(
+        "tidbtpu_executor_residual_joins_total",
+        "semi/anti joins traced with a residual: an inner expansion "
+        "carrying the probe's row id, the residual over its pairs, a "
+        "semi or anti join back on the row id",
+        labels=("kind",),
+    ).labels(kind=kind).inc()
+    ledger = getattr(_TRACING, "residuals", None)
+    if ledger is not None:
+        ledger.append((pairs, kept))
+
+
 def _use_merge_probe(m: int) -> bool:
     """Replace per-row binary search with sortops.merge_searchsorted
     for large probe sides: searchsorted's log N rounds of random gather

@@ -223,6 +223,7 @@ class QueryFlight:
         "join_expansions", "join_expand_rows", "join_expand_slots",
         "sorted_groupings", "sorted_group_rows", "sorted_groups",
         "sorted_group_slots", "windows", "window_rows", "window_slots",
+        "residual_joins", "residual_pairs", "residual_kept",
         "gc_s", "gc_passes",
     )
 
@@ -278,6 +279,13 @@ class QueryFlight:
         self.windows = 0
         self.window_rows = 0
         self.window_slots = 0
+        #: what the semi and anti joins with a residual (a correlated
+        #: EXISTS / NOT EXISTS: planner/physical.py) of the programs
+        #: this statement ran tested: joins executed, the valid pairs
+        #: the residual was evaluated on and those it passed
+        self.residual_joins = 0
+        self.residual_pairs = 0
+        self.residual_kept = 0
         self.device_mem_peak_bytes = 0
         # XLA cost analysis summed over this statement's compiles
         # (obs/engine_watch.py per-signature harvest)
@@ -725,6 +733,16 @@ class FlightRecorder:
             rec.window_rows += int(rows)
             rec.window_slots += int(slots)
 
+    def note_residuals(self, count: int, pairs: int, kept: int) -> None:
+        """One executed program's semi/anti joins with a residual
+        (planner/physical.py reads them beside the program's
+        cardinality scalars)."""
+        rec = self.current()
+        if rec is not None:
+            rec.residual_joins += int(count)
+            rec.residual_pairs += int(pairs)
+            rec.residual_kept += int(kept)
+
     def note_groupings(self, count: int, rows: int, groups: int, slots: int) -> None:
         """One executed program's sorted group-bys (planner/physical.py
         reads them beside the program's cardinality scalars)."""
@@ -825,6 +843,9 @@ class FlightRecorder:
                 "windows": r.windows,
                 "window_rows": r.window_rows,
                 "window_slots": r.window_slots,
+                "residual_joins": r.residual_joins,
+                "residual_pairs": r.residual_pairs,
+                "residual_kept": r.residual_kept,
                 "device_mem_peak_bytes": r.device_mem_peak_bytes,
                 "compile_flops": r.compile_flops,
                 "compile_bytes_accessed": r.compile_bytes_accessed,

@@ -191,6 +191,10 @@ def selectivity(e, smap: StatsMap) -> float:
                 n1, n2 = smap.ndv_of(e.args[0]), smap.ndv_of(e.args[1])
                 n = max(n1 or 0, n2 or 0)
                 return 1.0 / n if n else SEL_EQ_DEFAULT
+            if op in ("lt", "le", "gt", "ge"):
+                p = _col_above_col(e.args[0], e.args[1], smap)
+                if p is not None:
+                    return p if op in ("gt", "ge") else 1.0 - p
             return SEL_RANGE_DEFAULT
         got = smap.stats_of(col)
         if got is None or got[0] is None:
@@ -250,6 +254,29 @@ def selectivity(e, smap: StatsMap) -> float:
     if op in ("isnotnull",):
         return 1.0 - selectivity(Func(e.type, "isnull", e.args), smap)
     return SEL_DEFAULT
+
+
+def _col_above_col(a, b, smap: StatsMap) -> Optional[float]:
+    """P(a > b) for two columns of one type that both have histograms
+    (`l_receiptdate > l_commitdate`), the columns taken as independent:
+    b's histogram read at the two edges of each of a's equal-depth
+    buckets. None where either has none."""
+    sa, sb = smap.stats_of(a), smap.stats_of(b)
+    if sa is None or sb is None or sa[0] is None or sb[0] is None:
+        return None
+    if sa[1] != sb[1] or sa[1] is None or sa[1].kind not in (
+        Kind.INT, Kind.DATE, Kind.DECIMAL
+    ):
+        return None
+    hi = np.asarray(sa[0].bounds)
+    if hi.size == 0 or np.asarray(sb[0].bounds).size == 0:
+        return None
+    lo = np.concatenate([hi[:1], hi[:-1]])
+    below = [
+        (_hist_le_frac(sb[0], x) + _hist_le_frac(sb[0], y)) / 2
+        for x, y in zip(lo, hi)
+    ]
+    return float(np.mean(below))
 
 
 def _flatten_and(e):

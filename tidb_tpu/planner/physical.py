@@ -44,7 +44,7 @@ from tidb_tpu.executor import (
 )
 from tidb_tpu.executor.aggregate import WIDTH_STALE as _WIDTH_STALE
 from tidb_tpu.executor.aggregate import _pack_keys as _pack_limbs
-from tidb_tpu.executor.join import expansion_ledger
+from tidb_tpu.executor.join import expansion_ledger, note_residual, residual_ledger
 from tidb_tpu.executor.sortops import grouping_ledger
 from tidb_tpu.executor.window import window_ledger
 from tidb_tpu.expression import compile_expr
@@ -1118,18 +1118,34 @@ class PlanCompiler:
         build side reports its stale bounds through a sentinel node."""
         return None if props[0] is None else self._sentinel_node()
 
-    def _first_tile(self, plan: L.LogicalPlan, parts: int = 1) -> int:
+    def _first_tile(self, plan: L.LogicalPlan, parts: int = 1, rows=None) -> int:
         """A knob's first tile from the planner's estimate of `plan`'s
         rows (exact table counts and ANALYZE's statistics,
-        planner/cardinality.py), a `parts`-th of them, with a quarter
-        of room for the estimate's error and a partition's unevenness,
-        to the next tile. Where the estimates hold, the first program a
-        statement compiles is already its steady one
+        planner/cardinality.py), or of `rows` where the knob holds
+        other rows than the node's own, a `parts`-th of them, with a
+        quarter of room for the estimate's error and a partition's
+        unevenness, to the next tile. Where the estimates hold, the
+        first program a statement compiles is already its steady one
         (PhysicalExecutor._steady_first); a tile too small retries at
         the exact need like any other."""
         from tidb_tpu.planner import cardinality as C
 
-        return _cap_tile(int(1.25 * C.est_rows(plan, self.catalog)) // parts + 1)
+        if rows is None:
+            rows = C.est_rows(plan, self.catalog)
+        return _cap_tile(int(1.25 * rows) // parts + 1)
+
+    def _pairs_first_tile(self, plan: L.JoinPlan) -> int:
+        """The first tile of the pairs a semi or anti join with a
+        residual expands: from the planner's estimate of the INNER join
+        of its two sides on its keys."""
+        from tidb_tpu.planner import cardinality as C
+
+        smap = C.gather_stats(plan, self.catalog)
+        return self._first_tile(plan, rows=C.est_join(
+            C.est_rows(plan.left, self.catalog, smap),
+            C.est_rows(plan.right, self.catalog, smap),
+            plan.equi_keys, "inner", smap,
+        ))
 
     def _exchange_knob(self, plan: L.LogicalPlan, sides) -> int:
         """The sized node of an exchange of `sides` (a join's pair, a
@@ -2219,7 +2235,9 @@ class PlanCompiler:
             if mesh:
                 # row-id re-join must see both sides whole: run replicated
                 _gather_both()
-            nid = self._join_knob(plan)
+            # the pairs' tile starts from the planner's estimate, so that
+            # the plan's first program can be its steady one
+            nid = self._join_knob(plan, self._pairs_first_tile(plan))
             lks_rks = verify
 
             def fn_semi_multi(inputs, caps):
@@ -2231,22 +2249,31 @@ class PlanCompiler:
                     lb.row_valid,
                 )
                 cap = caps[nid] or pad_capacity(max(lb.capacity, 1024))
-                j, total = equi_join(rb, lb2, rkey, lkey, cap, "inner")
-                if lks_rks is not None:
-                    lks, rks = lks_rks
+                with jax.named_scope("residual"):
+                    with jax.named_scope("expand"), expansion_ledger() as expanded:
+                        j, total = equi_join(rb, lb2, rkey, lkey, cap, "inner")
+                    if expanded:
+                        # an overflow of this tile retries the program
+                        self.expand_nids.add(nid)
+                    with jax.named_scope("filter"):
+                        if lks_rks is not None:
+                            lks, rks = lks_rks
 
-                    def vf(bb):
-                        ok = jnp.ones(bb.capacity, dtype=bool)
-                        for lf2, rf2 in zip(lks, rks):
-                            a, c = lf2(bb), rf2(bb)
-                            ok = ok & (a.data == c.data) & a.valid & c.valid
-                        return DevCol(ok, jnp.ones(bb.capacity, dtype=bool))
+                            def vf(bb):
+                                ok = jnp.ones(bb.capacity, dtype=bool)
+                                for lf2, rf2 in zip(lks, rks):
+                                    a, c = lf2(bb), rf2(bb)
+                                    ok = ok & (a.data == c.data) & a.valid & c.valid
+                                return DevCol(ok, jnp.ones(bb.capacity, dtype=bool))
 
-                    j = filter_batch(j, vf)
-                if res is not None:
-                    j = filter_batch(j, res)
-                ridc = lambda b: b.cols["_srowid"]
-                out, _t = equi_join(j, lb2, ridc, ridc, 0, kind)
+                            j = filter_batch(j, vf)
+                        pairs = _count_valid(j.row_valid)
+                        if res is not None:
+                            j = filter_batch(j, res)
+                        note_residual(kind, pairs, _count_valid(j.row_valid))
+                    with jax.named_scope("rejoin"):
+                        ridc = lambda b: b.cols["_srowid"]
+                        out, _t = equi_join(j, lb2, ridc, ridc, 0, kind)
                 out = Batch(
                     {k: v for k, v in out.cols.items() if k != "_srowid"},
                     out.row_valid,
@@ -2731,11 +2758,15 @@ class PhysicalExecutor:
             def prog(i, p, _f=fn, _c=frozen_caps):
                 with param_scope(p), expansion_ledger() as expanded, \
                         grouping_ledger() as grouped, \
-                        window_ledger() as windowed:
+                        window_ledger() as windowed, \
+                        residual_ledger() as tested:
                     b, needs = _f(i, _c)
-                return b, _with_windows(
-                    _with_groupings(_with_expansions(needs, expanded), grouped),
-                    windowed,
+                return b, _with_residuals(
+                    _with_windows(
+                        _with_groupings(_with_expansions(needs, expanded), grouped),
+                        windowed,
+                    ),
+                    tested,
                 )
 
             return prog
@@ -2749,7 +2780,8 @@ class PhysicalExecutor:
 
         def local(i, _f=fn, _c=frozen_caps):
             with sent_ledger() as sent, expansion_ledger() as expanded, \
-                    grouping_ledger() as grouped, window_ledger() as windowed:
+                    grouping_ledger() as grouped, window_ledger() as windowed, \
+                    residual_ledger() as tested:
                 b, needs = _f(i, _c)
             # pmax proves replication of the cardinality scalars to
             # shard_map AND takes the per-shard max for sizing knobs
@@ -2770,6 +2802,10 @@ class PhysicalExecutor:
             # gathered rows, each shard a whole copy: one shard's count)
             needs = _with_windows(
                 needs, [(pmax(r, "d"), s) for r, s in windowed]
+            )
+            # and its residual semi/anti joins (replicated, as windows)
+            needs = _with_residuals(
+                needs, [(pmax(p, "d"), pmax(k, "d")) for p, k in tested]
             )
             # what the program's exchanges sent, beside them: how many
             # there are, their rows and their cross-chip bytes
@@ -3113,11 +3149,15 @@ class PhysicalExecutor:
         each, PERF.md PR 29). A looser tile: the tight tiles are known,
         the steady program is compiled at them, and discovery is
         skipped all the same. An overflow, which cuts short what lies
-        downstream of it: the discover loop, as before.
+        downstream of it: the steady program is compiled again at the
+        tiles the run saw (exact above the overflow, a lower bound below
+        it), with no discover program between; should that overflow
+        too, or not be admitted, the discover loop runs.
 
         Returns (output, None, 0) when the first program was kept,
-        (None, tight caps, tight output tile) when it ran clean and is
-        to be tightened, (None, None, 0) when discovery has to run."""
+        (None, caps, output tile) when the steady program is to be
+        compiled at the tight tiles the run saw, (None, None, 0) when
+        discovery has to run."""
         first = cq.default_caps
         first_out = cq.first_out_cap
         if cq.caps or not first_out or not all(first.values()):
@@ -3128,7 +3168,8 @@ class PhysicalExecutor:
             "tidbtpu_executor_steady_first_total",
             "plans first compiled as their steady program at "
             "estimated tiles: kept, tightened (recompiled at the tight "
-            "tiles, no discovery), overflowed (discovery ran)",
+            "tiles, no discovery), overflowed (recompiled at the tiles "
+            "the run saw)",
             labels=("outcome",),
         )
         from tidb_tpu.utils import failpoint
@@ -3152,6 +3193,11 @@ class PhysicalExecutor:
             return None, None, 0
         jitted, out, needs_host = self._steady_at(cq, first, first_out, inputs)
         full = {**first, _OUT_NODE: first_out}
+        tight = {
+            nid: max(_cap_tile(int(needs_host[nid])), cq.floors.get(nid, 0))
+            if nid in needs_host else cap
+            for nid, cap in full.items()
+        }
         if _overflowed(needs_host, full):
             if any(int(n) >= _WIDTH_STALE for n in needs_host.values()):
                 # baked key bounds no longer cover the data: no tile fixes that
@@ -3160,18 +3206,15 @@ class PhysicalExecutor:
             for nid in cq.expand_nids:
                 if int(needs_host.get(nid, 0)) > full.get(nid, 0):
                     _expand_overflow_retries().inc()
-            # discovery starts from what this run saw (a lower bound
-            # downstream of the overflow), not from the estimates again
-            cq.caps = {
-                nid: max(cap, _cap_tile(int(needs_host.get(nid, 0))))
-                for nid, cap in first.items()
-            }
-            return None, None, 0
-        tight = {
-            nid: max(_cap_tile(int(needs_host[nid])), cq.floors.get(nid, 0))
-            if nid in needs_host else cap
-            for nid, cap in full.items()
-        }
+            out_cap = tight.pop(_OUT_NODE)
+            try:
+                self._admit(cq, inputs, tight)
+            except ExecError:
+                # discovery starts from what this run saw, not from the
+                # estimates again
+                cq.caps = {nid: max(cap, tight[nid]) for nid, cap in first.items()}
+                return None, None, 0
+            return None, tight, out_cap
         if all(cap <= 2 * tight[nid] for nid, cap in full.items()):
             outcomes.labels(outcome="kept").inc()
             cq.caps = dict(full)
@@ -3500,6 +3543,9 @@ _GROUPINGS, _GROUP_ROWS, _GROUPS, _GROUP_SLOTS = -8, -9, -10, -11
 # and of what its window operators did: how many there are, the valid
 # rows that entered their sorts, their tiles' slots
 _WINDOWS, _WINDOW_ROWS, _WINDOW_SLOTS = -12, -13, -14
+# and of what its semi/anti joins with a residual tested: how many
+# there are, the valid pairs the residual saw, the pairs it passed
+_RESIDUALS, _RESIDUAL_PAIRS, _RESIDUAL_KEPT = -15, -16, -17
 
 
 def _expand_overflow_retries():
@@ -3552,13 +3598,28 @@ def _with_windows(needs: dict, windowed: list) -> dict:
     return needs
 
 
+def _with_residuals(needs: dict, tested: list) -> dict:
+    """The program's cardinality scalars with what its semi/anti joins
+    with a residual (executor/join.residual_ledger: one (pairs, kept) a
+    join) tested beside them: the same fetch brings both."""
+    if not tested:
+        return needs
+    needs = dict(needs)
+    needs[_RESIDUALS] = jnp.int64(len(tested))
+    needs[_RESIDUAL_PAIRS] = sum((p for p, _k in tested), jnp.int64(0))
+    needs[_RESIDUAL_KEPT] = sum((k for _p, k in tested), jnp.int64(0))
+    return needs
+
+
 def _take_program_stats(needs_host: dict) -> dict:
-    """Take what the exchanges sent, what the expanding joins emitted
-    and what the sorted group-bys did out of a program's fetched
+    """Take what the exchanges sent, what the expanding joins emitted,
+    what the sorted group-bys and window operators did and what the
+    semi/anti joins with a residual tested out of a program's fetched
     scalars, onto the statement's flight and the registry; what is
     left are the cardinalities of the knobs."""
     if not any(
-        k in needs_host for k in (_EXCHANGES, _EXPANSIONS, _GROUPINGS, _WINDOWS)
+        k in needs_host
+        for k in (_EXCHANGES, _EXPANSIONS, _GROUPINGS, _WINDOWS, _RESIDUALS)
     ):
         return needs_host
     needs_host = dict(needs_host)
@@ -3614,6 +3675,17 @@ def _take_program_stats(needs_host: dict) -> dict:
             "operators' sorts",
         ).inc(rows)
         FLIGHT.note_windows(count, rows, slots)
+    if _RESIDUALS in needs_host:
+        count, pairs, kept = (
+            int(needs_host.pop(k))
+            for k in (_RESIDUALS, _RESIDUAL_PAIRS, _RESIDUAL_KEPT)
+        )
+        REGISTRY.counter(
+            "tidbtpu_executor_residual_pairs_total",
+            "valid pairs the executed programs' semi/anti joins with a "
+            "residual tested it on",
+        ).inc(pairs)
+        FLIGHT.note_residuals(count, pairs, kept)
     return needs_host
 
 
